@@ -16,41 +16,6 @@ Top-level namespaces mirror the reference's user surface
 (python/paddle/{tensor,nn,optimizer,amp,autograd,io,static,distributed}).
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under experimental only; the framework
-    # targets the stable `jax.shard_map` spelling, including the renamed
-    # replication-check kwarg (check_vma, formerly check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _shard_map_compat(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    # jax < 0.5: the static bound-axis size lives on the axis frame
-    def _axis_size(axis_name):
-        import math as _math
-
-        if isinstance(axis_name, (tuple, list)):
-            return _math.prod(_jax.core.axis_frame(a) for a in axis_name)
-        return _jax.core.axis_frame(axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-
-    if not hasattr(_pltpu, "CompilerParams"):
-        # pre-0.5 spelling: TPUCompilerParams
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except ImportError:                      # pallas not present in this build
-    pass
-
 from paddle_tpu.version import __version__
 from paddle_tpu import flags
 from paddle_tpu.flags import get_flags, set_flags

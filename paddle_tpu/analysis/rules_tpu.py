@@ -16,8 +16,9 @@ gate is unchanged.
 - **PT007** tiling alignment: a CHOSEN tile (block dim strictly inside
   the array dim) must keep the trailing dim a multiple of 128 lanes
   and the second-minor a multiple of the dtype sublane (8 f32 /
-  16 bf16 / 32 int8) — a misaligned block silently pads on chip and
-  inflates both VMEM residency and HBM bytes.
+  16 bf16 / 32 int8) — a misaligned block pads on chip, inflating
+  both VMEM residency and HBM bytes, or is refused by the Pallas TPU
+  lowering outright (a 1-row block of a many-row array is).
 - **PT008** aliasing contracts: a whole-array ``ANY``-space pool that
   matches an output must be input_output_aliased (else the kernel pays
   a full HBM pool copy per launch), and an aliased pair whose block
@@ -106,11 +107,15 @@ class TilingAlignmentRule(Rule):
                     if bs and 0 < bs[-1] < shp[-1] and bs[-1] % 128:
                         checks.append((len(bs) - 1, bs[-1], 128,
                                        "lane"))
-                    # bs[-2] == 1 is degenerate row-streaming (one
-                    # layer/row slab per grid step): the sublane pad is
-                    # inherent to indexing a single row, not a fixable
-                    # tiling choice, so it stays quiet.
-                    if len(bs) >= 2 and 1 < bs[-2] < shp[-2]:
+                    # a 1-row block of a many-row array is NOT exempt:
+                    # the Pallas TPU lowering refuses it outright (PR 21:
+                    # block (1, 2048) of an (L, 2048) array, "last two
+                    # dimensions ... divisible by 8 and 128 ... or equal
+                    # to the respective dimensions of the overall
+                    # array"). Row-streaming rides a (1, 1, n) block of
+                    # an (L, 1, n) array, whose last two dims ARE the
+                    # array's.
+                    if len(bs) >= 2 and 0 < bs[-2] < shp[-2]:
                         sub = kernelmodel.sublane(op.dtype)
                         if bs[-2] % sub:
                             checks.append((len(bs) - 2, bs[-2], sub,

@@ -13,10 +13,15 @@ healthy one. This module:
   ``prof/compile_cache_disabled`` gauge), printing only the FIRST
   occurrence; every other warning passes through untouched. Installed
   idempotently by both decode engines at construction.
-- ``enable(cache_dir)`` — points jax at a persistent cache dir with a
-  fallback: a missing config knob (older jax) or a broken dir counts
-  into the same counter and returns False instead of raising — cold
-  compiles are a slowdown, not an outage.
+- ``enable()`` — turns jax's persistent compilation cache on. WHERE it
+  lives is decided outside the code: with ``JAX_COMPILATION_CACHE_DIR``
+  set, jax's own setting stands and no directory is set here;
+  otherwise it is ONE fixed directory inside the checkout
+  (``.pt_cache/xla``, git-ignored) — the path is part of the cache key,
+  so a directory that moves (tempfile, pid, time) never hits. A broken
+  directory surfaces as jax's per-entry warnings, which ``guard()``
+  counts: cold compiles are a slowdown, not an outage. Both decode
+  engines and ``chip_smoke.py`` call it.
 - ``status()`` — {"disabled", "errors", "last_error_class"} for bench
   provenance: the r05 RESOURCE_EXHAUSTED that silently killed the
   bert/resnet/ppyoloe rows is now a stamped field on every BENCH
@@ -30,7 +35,7 @@ import re
 import threading
 import warnings
 
-__all__ = ["guard", "enable", "status"]
+__all__ = ["guard", "enable", "status", "CHECKOUT_CACHE_DIR"]
 
 # matches jax's "Error reading persistent compilation cache entry ..."
 # and "Error writing persistent compilation cache entry ..." warnings
@@ -39,6 +44,11 @@ _MATCH = re.compile(r"persistent compilation cache", re.IGNORECASE)
 # RESOURCE_EXHAUSTED: ..."); the class name is the triage key (a flaky
 # read vs a full disk vs a permission wall are different runbooks)
 _EXC_CLASS = re.compile(r"\b([A-Za-z_][A-Za-z0-9_]*(?:Error|Exception))\b")
+#: the one in-checkout cache directory (used when the environment does
+#: not place the cache itself)
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".pt_cache", "xla")
 _lock = threading.Lock()
 _hook = None
 _printed = False
@@ -108,19 +118,13 @@ def guard() -> None:
             "always", message=".*persistent compilation cache.*")
 
 
-def enable(cache_dir, min_compile_secs: float = 1.0) -> bool:
-    """Enable jax's persistent compilation cache at ``cache_dir``,
-    tolerating failure (counter + one warning instead of an abort).
-    Returns True when the cache was configured."""
+def enable() -> str:
+    """Turn on jax's persistent compilation cache and return the
+    directory it uses. ``JAX_COMPILATION_CACHE_DIR`` set: jax's own
+    setting is left alone and no directory is set in code. Unset: the
+    fixed in-checkout ``CHECKOUT_CACHE_DIR``."""
     guard()
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_secs))
-        return True
-    except Exception as e:  # older jax without the knob / unusable dir
-        _record_failure(type(e).__name__)
-        warnings.warn(f"compile cache unavailable ({e}); continuing "
-                      f"with cold compiles")
-        return False
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir

@@ -434,8 +434,26 @@ def _master_wait_members(store, table, version, reform_seen,
     return ("done", reform_seen)
 
 
+def _refuse_shared_chips(args):
+    """One host is ONE process driving its chips: a chip belongs to one
+    process at a time, and nothing here hands worker i a chip of its
+    own. Several local workers are therefore CPU workers by contract —
+    unless the environment they inherit pins JAX to the CPU, every one
+    of them would reach for every chip and all but the first fail or
+    hang. The launcher itself never touches JAX."""
+    if (args.nproc_per_node > 1
+            and os.environ.get("JAX_PLATFORMS", "") != "cpu"):
+        raise SystemExit(
+            f"[launch] --nproc_per_node {args.nproc_per_node} refused: "
+            "one host is one process driving all its chips, and local "
+            "workers are given no chip of their own. Run one process "
+            "per host (--nproc_per_node 1), or set JAX_PLATFORMS=cpu "
+            "for a multi-process CPU job.")
+
+
 def launch(argv):
     args = _parse(argv)
+    _refuse_shared_chips(args)
     tdir = os.environ.get("PT_TRACE_DIR")
     if tdir:
         # the launcher itself has no PT_PROCESS_ID: its atexit export

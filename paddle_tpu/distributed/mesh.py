@@ -242,6 +242,21 @@ def get_mesh() -> Optional[Mesh]:
     return _global_topology.mesh if _global_topology else None
 
 
+def gspmd_partitioned() -> bool:
+    """True when the code being traced will be partitioned by GSPMD
+    over more than one device: a multi-device global mesh is set and
+    the trace is not inside a shard_map body that has taken every
+    nontrivial axis manual. A Pallas (Mosaic) custom call has no
+    partitioning rule, so kernels are legal exactly where this is
+    False."""
+    mesh = get_mesh()
+    if mesh is None or mesh.size == 1:
+        return False
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    return any(mesh.shape[a] > 1 and a not in manual
+               for a in mesh.axis_names)
+
+
 def set_topology(topo: HybridTopology):
     global _global_topology
     _global_topology = topo

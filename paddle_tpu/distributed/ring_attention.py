@@ -156,18 +156,8 @@ def sequence_parallel_attention(q, k, v, mesh: Mesh, axis: str = "sp",
     fn = {"ring": ring_attention, "ulysses": ulysses_attention}[mode]
     spec = P(batch_axes, axis, head_axis, None)
     body = functools.partial(fn, axis_name=axis, causal=causal, scale=scale)
-    # check_vma=False: jax 0.4.37's replication checker rejects the
-    # causal ring's lax.cond ("mismatched replication types") — a false
-    # positive, every branch output is device-varying (both branches
-    # return the per-shard online-softmax carry). The grad path hits it
-    # too, so with the check on, training through ring attention fails
-    # on this build. Replication is asserted EXPLICITLY instead:
-    # tests/test_ring_attention.py::test_replication_explicit checks
-    # every device's copy of a replicated (out_specs P()) reduction is
-    # bit-identical — the property the checker would have proven.
     mapped = jax.shard_map(
         lambda q, k, v: body(q, k, v),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False,
     )
     return mapped(q, k, v)

@@ -1,12 +1,13 @@
 """Default-engine factory for the serving surface (ISSUE 19).
 
-Every hardware number says short-length decode is launch-bound, and the
-paged engine now owns the single-dispatch megakernel step — so PAGED is
-the default serving engine for the front-end and the bench ladder. The
+PAGED is the default serving engine for the front-end and the bench
+ladder (its decode step is the per-layer fused append+attend path; the
+single-dispatch megakernel is opt-in, see docs/serving.md). The
 slot-contiguous `DecodeEngine` stays available behind
 ``PT_SERVE_ENGINE=contiguous`` (or ``engine="contiguous"``): it still
 serves prompts longer than the paged prefill's largest bucket, and it
-is the sampling-policy surface (temperature/top-k live there).
+is the sampling-policy surface (temperature/top-k live there). Which
+engine is faster on the chip: not measured since PR 6.
 
 ``make_engine(model)`` is the one construction path the serving
 front-end, the smoke tools and the bench ladder share — flipping the

@@ -113,18 +113,16 @@ class PagedDecodeEngine(ResilientScheduler):
         r = eng.submit(prompt, max_new_tokens=64, eos_id=2)
         eng.run()                                  # r.tokens
 
-    Status (r5 hardware): output is bit-identical to ``gpt.generate``
-    across page/chunk geometries and serving HBM scales with live
-    tokens. The first on-chip exercise of the write-first form (pools
-    as layer-scan carry, one scatter per layer per token) measured
-    ~0.05x of the HBM roofline; the current read-only-pool
-    formulation (analytic fresh-row fold + one scatter per token)
-    measured 0.17x on the same workload, vs 0.53x for the contiguous
-    DecodeEngine. The remaining known gap: one pallas launch per
-    layer per token over a mostly-masked fixed-width table is
-    dispatch-heavy at short cache lengths — a table-width-bucketed
-    kernel or a dense fallback below ~page_size tokens is the next
-    optimization."""
+    Status: greedy output is bit-identical to ``gpt.generate`` across
+    page/chunk geometries in interpret mode (f32), and serving HBM
+    scales with live tokens. First run of THIS code on a chip (one
+    v5e, GPT-3 1.3B, PR 21, ``chip_smoke.py``): the default per-layer
+    fused step compiles and serves, agreeing with ``gpt.generate`` up
+    to bf16 ties. It is slow (PERF.md has the step times) and what
+    bounds it has not been traced. One candidate stands from the r5
+    notes: one pallas launch per layer per token whose grid walks the
+    FULL fixed-width page table (ceil(max_seq_len/page) columns,
+    mostly masked at short lengths)."""
 
     def __init__(self, model, n_pages: int, max_slots: int = 8,
                  page_size: int = 128, steps_per_call: int = 1,
@@ -138,7 +136,7 @@ class PagedDecodeEngine(ResilientScheduler):
         from paddle_tpu import compile_cache
         from paddle_tpu.inference.decode_engine import (
             resolve_engine_weights)
-        compile_cache.guard()
+        compile_cache.enable()
         cfg, head, stacked = resolve_engine_weights(model,
                                                     share_weights_with)
         if page_size % 128:
@@ -175,14 +173,25 @@ class PagedDecodeEngine(ResilientScheduler):
         # parity reference the fused path is tested against)
         self.fused = (os.environ.get("PT_PAGED_FUSED", "1") != "0"
                       if fused is None else bool(fused))
-        # single-dispatch decode (docs/serving.md "Single-dispatch
-        # decode"): the layer-folded megakernel + fused sampling
-        # epilogue collapse each decode step to TWO kernel launches
-        # (vs one paged launch per layer). Requires the fused path —
-        # the per-layer fused kernel stays as the bit-parity reference
-        # (PT_PAGED_MEGA=0 or mega=False falls back to it).
-        self.mega = ((os.environ.get("PT_PAGED_MEGA", "1") != "0"
-                      if mega is None else bool(mega)) and self.fused)
+        # THE decode-step choice (docs/serving.md "Single-dispatch
+        # decode"). Default: the per-layer fused path — one
+        # `paged_append_attend` launch per layer inside a lax.scan —
+        # because it is the one the v5e compiler accepts
+        # (tests/test_chip_compile.py; PR 21 chip run). The layer-folded
+        # megakernel is opt-in (mega=True or PT_PAGED_MEGA=1): it
+        # streams a layer's whole weight slab per grid step, 192 MiB
+        # double-buffered at 1.3B widths against 128 MiB of VMEM, and
+        # its row indexing uses a dynamic_slice Mosaic does not lower,
+        # so today it runs in interpret mode only. Asking for it where
+        # the compiler refuses raises the compiler's error at first
+        # dispatch — it is never swapped for another path.
+        if mega is None:
+            mega = os.environ.get("PT_PAGED_MEGA", "0") == "1"
+        if mega and not self.fused:
+            raise ValueError("mega=True needs the fused append+attend "
+                             "path (fused=False / PT_PAGED_FUSED=0 "
+                             "excludes it)")
+        self.mega = bool(mega)
         # speculative decode rides the paged step (r05 retired the
         # contiguous-only row): drafts come from the shared on-device
         # prompt-lookup helper, and with mega on, verify/accept run as

@@ -194,16 +194,9 @@ class Bert(Module):
                 keep == (jnp.arange(s)[None, :] < lens[:, None]), axis=-1)
             kv_lens = jnp.where(is_prefix, lens, s)
         from paddle_tpu import flags as _flags
-        from paddle_tpu.models.gpt import scan_partition_hazard
         prestacked = getattr(self, "_stacked_layers", None)
         use_scan = prestacked is not None or (
             self.cfg.n_layers > 1 and _flags.get_flag("scan_layers"))
-        if use_scan and scan_partition_hazard():
-            # ≥3-axis mesh on this CPU build miscompiles the scanned
-            # backward (gpt.scan_partition_hazard has the bisect) —
-            # unroll; a pre-stacked state's per-layer views were bound
-            # onto self.layers by merge_params, so both forms serve.
-            use_scan = False
         if use_scan:
             # one compiled encoder-layer body instead of L unrolled
             # copies (L-fold faster XLA compile — same rationale and

@@ -629,12 +629,6 @@ class GPT(Module):
         prestacked = getattr(self, "_stacked_blocks", None)
         use_scan = prestacked is not None or (dense and L > 1
                                               and _flag("scan_layers"))
-        if use_scan and scan_partition_hazard():
-            # ≥3-axis mesh on this CPU build: the scanned backward
-            # miscompiles (see scan_partition_hazard) — unroll instead.
-            # merge_params bound per-layer views of a pre-stacked state
-            # onto self.blocks, so the unrolled loop serves both forms.
-            use_scan = False
         if use_scan:
             # in-trace stacking copies every block weight (and its grad
             # transpose un-stacks) — ~2x block-param HBM the unrolled
@@ -908,33 +902,6 @@ def stacked_partition_specs(stacked, template_blk, spec_fn=None):
     _, _, specs = stacked_block_specs(template_blk, spec_fn)
     sleaves, streedef = jax.tree_util.tree_flatten(stacked)
     return sleaves, streedef, specs
-
-
-def scan_partition_hazard() -> bool:
-    """True when the scan-over-stacked-layers forward must NOT be used
-    under the current global mesh: on this CPU XLA build (jax 0.4.37),
-    GSPMD partitioning of a ``lax.scan`` whose xs carry the stacked
-    block weights MISCOMPILES the backward once the mesh has three or
-    more nontrivial axes (dp×tp×fsdp). Bisect evidence (tracked as the
-    former standing tier-1 reds, test_gpt_model tp_fsdp /
-    test_bert tp_sharded parity): every 1- and 2-axis mesh is
-    BIT-exact against the single-device step, the 3-axis mesh is off
-    by ~1e-3 in the loss and ~0.1 absolute in the wte gradient, and
-    float64 ground truth sides with the dense program (grad error
-    2.7e-8 dense vs 0.117 sharded) — wrong math, not reduction-order
-    noise. Unrolling the layer loop restores bit-exactness; activation
-    constraints, the vocab-parallel embedding, and `_gathered_table`
-    were all ruled out. TPU backends keep the scan (the 1.3B compile
-    time depends on it, and the bug reproduces only on this CPU
-    build's partitioner)."""
-    import jax as _jax
-    if _jax.default_backend() != "cpu":
-        return False
-    from paddle_tpu.distributed.mesh import get_mesh
-    mesh = get_mesh()
-    if mesh is None:
-        return False
-    return sum(1 for v in mesh.shape.values() if v > 1) >= 3
 
 
 def _shard_stacked(stacked, template_blk, mesh, spec_fn=None):
@@ -1217,6 +1184,26 @@ def register_stacked_decay_mask(optimizer, template_blk, n_layers: int,
     set_mask(entry, jax.tree_util.tree_unflatten(treedef, masks))
 
 
+def _init_opt_state_sharded(optimizer, params, mesh):
+    """``optimizer.init`` with every slot placed like the param it
+    belongs to. The slots are zeros with no data dependence on the
+    params, so a bare ``jit(optimizer.init)`` leaves ALL of them whole
+    on the first device (4.9 GiB of bf16 moments at 1.3B): the first
+    step then runs on a re-placed copy and the second step, whose
+    inputs are the first's sharded outputs, compiles the program
+    again. Entries that are not per-param (the step counter) are
+    replicated."""
+    shapes = jax.eval_shape(optimizer.init, params)
+    rep = NamedSharding(mesh, P())
+    out = {k: jax.tree_util.tree_map(lambda _: rep, v)
+           for k, v in shapes.items() if k != "slots"}
+    out["slots"] = jax.tree_util.tree_map(
+        lambda p, slot: jax.tree_util.tree_map(
+            lambda s: p.sharding if s.shape == p.shape else rep, slot),
+        params, shapes["slots"])
+    return jax.jit(optimizer.init, out_shardings=out)(params)
+
+
 def init_train_state(model: GPT, optimizer, mesh: Optional[Mesh] = None,
                      stacked: bool = False):
     """Params + optimizer state, sharded onto the mesh if given.
@@ -1264,7 +1251,7 @@ def init_train_state(model: GPT, optimizer, mesh: Optional[Mesh] = None,
             # module's own arrays
             params["_stacked_blocks"] = jax.jit(
                 stack_block_weights, out_shardings=sh_tree)(blocks)
-            opt_state = jax.jit(optimizer.init)(params)
+            opt_state = _init_opt_state_sharded(optimizer, params, mesh)
         else:
             # jnp.stack allocates fresh buffers, so donation in the train
             # step never frees the module's own arrays
@@ -1275,7 +1262,7 @@ def init_train_state(model: GPT, optimizer, mesh: Optional[Mesh] = None,
     params, _ = model.split_params()
     if mesh is not None and mesh.size > 1:
         params = shard_params(params, mesh)
-        opt_state = jax.jit(optimizer.init)(params)
+        opt_state = _init_opt_state_sharded(optimizer, params, mesh)
     else:
         # copy: the jitted step donates its inputs, and split_params aliases
         # the module's own arrays — donation must not delete those.

@@ -20,19 +20,53 @@ _LIB = None
 _BUILD_ERR = None
 
 
+_SRC_FILES = ("ringbuffer.cc", "tcp_store.cc", "p2p.cc")
+
+
 def _lib_path():
     return os.path.join(os.path.dirname(__file__), "libptnative.so")
 
 
-def _build():
-    root = os.path.join(os.path.dirname(__file__), "..", "..", "native")
-    root = os.path.abspath(root)
+def _src_dir():
+    return os.path.abspath(os.path.join(
+        os.path.dirname(__file__), "..", "..", "native", "src"))
+
+
+def _build_cmd(out):
+    srcs = [os.path.join(_src_dir(), f) for f in _SRC_FILES]
+    return ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-Wall",
+            *srcs, "-o", out, "-lpthread", "-lrt"]
+
+
+def _src_digest():
+    """sha256 over the build command's shape and every file under
+    native/src — what decides whether an existing library is current.
+    (mtimes do not: a copied or freshly checked-out tree does not
+    preserve them, so a stale .so could look newer than its sources.)"""
+    import hashlib
+    h = hashlib.sha256(" ".join(_build_cmd("")).encode())
+    for f in sorted(os.listdir(_src_dir())):
+        h.update(f.encode())
+        with open(os.path.join(_src_dir(), f), "rb") as fh:
+            h.update(fh.read())
+    return h.hexdigest()
+
+
+def _build(digest):
+    """Compile to a temp name and rename, library first and its digest
+    stamp last, so a concurrent importer never loads a half-written
+    library or trusts a stamp whose library is not there yet."""
     out = _lib_path()
-    srcs = [os.path.join(root, "src", f)
-            for f in ("ringbuffer.cc", "tcp_store.cc", "p2p.cc")]
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-Wall",
-           *srcs, "-o", out, "-lpthread", "-lrt"]
-    subprocess.run(cmd, check=True, capture_output=True)
+    tmp = f"{out}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(_build_cmd(tmp), check=True, capture_output=True)
+        os.replace(tmp, out)
+        with open(tmp, "w") as f:
+            f.write(digest)
+        os.replace(tmp, out + ".sha256")
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _load():
@@ -42,13 +76,14 @@ def _load():
     import ctypes
     path = _lib_path()
     try:
-        srcs_dir = os.path.join(os.path.dirname(__file__), "..", "..",
-                                "native", "src")
-        if not os.path.exists(path) or any(
-                os.path.getmtime(os.path.join(srcs_dir, f)) >
-                os.path.getmtime(path)
-                for f in os.listdir(srcs_dir)):
-            _build()
+        digest = _src_digest()
+        try:
+            with open(path + ".sha256") as f:
+                current = f.read().strip() == digest
+        except OSError:
+            current = False
+        if not current or not os.path.exists(path):
+            _build(digest)
         _LIB = ctypes.CDLL(path)
         _configure(_LIB, ctypes)
     except Exception as e:  # no toolchain / unsupported platform

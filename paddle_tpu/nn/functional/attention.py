@@ -51,19 +51,48 @@ def attention_reference(q, k, v, mask=None, is_causal=False, scale=None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _use_flash(q, k, attn_mask, is_causal, dropout_p, rng_key) -> bool:
+    """Route through the Pallas flash kernel. Every condition is one the
+    code can state up front — once the kernel is chosen, a failure in
+    it propagates (there is no quiet XLA route behind it):
+
+    - the kernel flag is on and the backend is a TPU;
+    - (B, S, H, D) layout with S >= 128 (below one lane-width block the
+      kernel would only pad) and D % 8 == 0 (Mosaic's sublane tiling);
+    - what the kernel does not implement: a causal mask over
+      Sq != Sk (a cached prefix) and masks of more than 4 dims;
+    - dropout, if any, has an rng key to seed the kernel's PRF;
+    - the trace is not being partitioned by GSPMD over several devices:
+      the compiler refuses ("Mosaic kernels cannot be automatically
+      partitioned"), so sharded steps take the XLA path, which the
+      partitioner can split; inside a shard_map body the kernel runs
+      per shard as usual."""
+    from paddle_tpu.distributed.mesh import gspmd_partitioned
+    return (flags.get_flag("use_pallas_kernels")
+            and jax.default_backend() == "tpu"
+            and q.ndim == 4
+            and q.shape[1] >= 128
+            and q.shape[-1] % 8 == 0
+            and (not is_causal or q.shape[1] == k.shape[1])
+            and (attn_mask is None or jnp.ndim(attn_mask) <= 4)
+            and (dropout_p == 0.0 or rng_key is not None)
+            and not gspmd_partitioned())
+
+
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, scale=None,
                                  rng_key: Optional[jax.Array] = None,
                                  kv_lens: Optional[jax.Array] = None):
-    """Flash attention on TPU (Pallas) or XLA fallback.
+    """Flash attention on TPU (Pallas) where `_use_flash` selects it,
+    the XLA reference path otherwise.
 
     Layout (B, S, H, D) matching paddle.nn.functional.scaled_dot_product_attention.
     ``kv_lens`` (B,) declares a contiguous key-padding mask (keys at
     positions >= kv_lens[b] are invisible); when given it routes the
     Pallas kernel instead of falling back to the XLA path, which is the
     BERT fast path (VERDICT r2 item 3). ``attn_mask`` is still honored by
-    the fallback; callers passing ``kv_lens`` must ensure the two agree.
+    the XLA path; callers passing ``kv_lens`` must ensure the two agree.
     Dropout on the TPU path uses a deterministic counter-based PRF seeded
     from ``rng_key``. k/v may carry fewer heads than q (GQA).
     """
@@ -73,41 +102,30 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     key = amp_cast(jnp.asarray(key))
     value = amp_cast(jnp.asarray(value))
     eff_dropout = dropout_p if training else 0.0
-    # head_dim % 8: Mosaic-lowerable without a sublane-misaligned layout
-    # (failures there surface at jit-compile time, outside the try/except)
-    use_pallas = (flags.get_flag("use_pallas_kernels")
-                  and q.ndim == 4
-                  and (eff_dropout == 0.0 or rng_key is not None)
-                  and jax.default_backend() == "tpu"
-                  and q.shape[1] >= 128
-                  and q.shape[-1] % 8 == 0)
-    if use_pallas:
-        try:
-            from paddle_tpu.ops.pallas.flash_attention import flash_attention
-            seed = None
-            if eff_dropout > 0.0:
-                seed = jax.random.bits(rng_key, (), jnp.uint32).astype(
-                    jnp.int32)
-            bias = None
-            if attn_mask is not None:
-                # any mask shape is honored via the kernel's blocked bias
-                # (a size-1 Sq dim is never materialized to (..,Sq,Sk));
-                # kv_lens remains a pure block-skip accelerator on top
-                mask = jnp.asarray(attn_mask)
-                bias = (jnp.where(mask, 0.0, -1e30).astype(jnp.float32)
-                        if mask.dtype == jnp.bool_ else mask)
-                while bias.ndim < 4:
-                    bias = bias[None]
-            return flash_attention(q, jnp.asarray(key), jnp.asarray(value),
-                                   causal=is_causal, scale=scale,
-                                   kv_lens=kv_lens, bias=bias,
-                                   dropout_p=eff_dropout,
-                                   dropout_seed=seed)
-        except Exception:
-            pass
+    if _use_flash(q, key, attn_mask, is_causal, eff_dropout, rng_key):
+        from paddle_tpu.ops.pallas.flash_attention import flash_attention
+        seed = None
+        if eff_dropout > 0.0:
+            seed = jax.random.bits(rng_key, (), jnp.uint32).astype(
+                jnp.int32)
+        bias = None
+        if attn_mask is not None:
+            # any mask shape is honored via the kernel's blocked bias
+            # (a size-1 Sq dim is never materialized to (..,Sq,Sk));
+            # kv_lens remains a pure block-skip accelerator on top
+            mask = jnp.asarray(attn_mask)
+            bias = (jnp.where(mask, 0.0, -1e30).astype(jnp.float32)
+                    if mask.dtype == jnp.bool_ else mask)
+            while bias.ndim < 4:
+                bias = bias[None]
+        return flash_attention(q, jnp.asarray(key), jnp.asarray(value),
+                               causal=is_causal, scale=scale,
+                               kv_lens=kv_lens, bias=bias,
+                               dropout_p=eff_dropout,
+                               dropout_seed=seed)
     if attn_mask is None and kv_lens is not None:
-        # fallback must honor the padding mask too (kv_lens is not a
-        # Pallas-only hint): build the additive key mask it declares.
+        # the XLA path must honor the padding mask too (kv_lens is not
+        # a Pallas-only hint): build the additive key mask it declares.
         # Finite fill (-1e30, the attention_reference convention): an
         # example with kv_lens == 0 must yield zeros, not NaN softmax.
         sk = key.shape[1]

@@ -54,7 +54,7 @@ def _prom_value(v) -> str:
 
 def prometheus_text(registry) -> str:
     """Render a StatRegistry as Prometheus text exposition format
-    (0.0.4). Typed from the registry's own taxonomy — counters are
+    (0.0.4). Typed from the registry's own metric kinds — counters are
     Prometheus counters (``_total``), gauges gauges, and the
     log-bucketed histograms and timers summaries (quantile samples are
     the registry's p50/p90/p99 estimates; a scraper averages

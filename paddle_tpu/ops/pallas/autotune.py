@@ -9,9 +9,14 @@ are trace-time constants, so tuning must happen EAGERLY (outside jit) —
 cache at trace time (a pure Python dict read) when no explicit block
 size is passed.
 
-The cache persists to ``~/.cache/paddle_tpu/autotune.json`` (override:
-``PT_AUTOTUNE_CACHE``): the second process run hits the cache instead of
-re-measuring, matching the reference's serialized cache behavior.
+The cache persists to ``.pt_cache/autotune.json`` inside the checkout
+(git-ignored; override: ``PT_AUTOTUNE_CACHE``): the second process run
+hits the cache instead of re-measuring, matching the reference's
+serialized cache behavior. The file is a RUN-TIME artifact — it is not
+in what git commits, so a fresh checkout runs every kernel at its code
+default until something calls ``tune``; a run that must not depend on
+whatever an earlier sweep left on this disk (``chip_smoke.py``) starts
+from ``get_cache().clear()``.
 """
 
 import json
@@ -23,9 +28,11 @@ __all__ = ["AutotuneCache", "get_cache", "tune"]
 
 
 def _default_path() -> str:
+    # beside the XLA compile cache, in the checkout's one cache dir
+    from paddle_tpu.compile_cache import CHECKOUT_CACHE_DIR
     return os.environ.get(
         "PT_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
+        os.path.join(os.path.dirname(CHECKOUT_CACHE_DIR),
                      "autotune.json"))
 
 

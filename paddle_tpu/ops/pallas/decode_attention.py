@@ -264,6 +264,7 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attention",
         interpret=interpret,
     )(lengths, qg, k_cache, v_cache)
     o = res[0][:, :group, :].reshape(b, hq, d)

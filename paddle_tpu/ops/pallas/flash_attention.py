@@ -229,6 +229,7 @@ def _fa_forward(q, k, v, kvlen, seed, bias, causal, scale, block_q, block_k,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_fwd",
         interpret=interpret,
     )(*args)
     return out, lse
@@ -407,6 +408,7 @@ def _fa_backward(q, k, v, kvlen, seed, bias, out, lse, do, causal, scale,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_bwd_dkdv",
         interpret=interpret,
     )(*args)
     if group > 1:
@@ -439,6 +441,7 @@ def _fa_backward(q, k, v, kvlen, seed, bias, out, lse, do, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(*args2)[0]
     return dq, dk, dv

@@ -164,6 +164,7 @@ def _fwd(x, w, lab2, block_n, block_v, interpret):
         scratch_shapes=[pltpu.VMEM((block_n, _LANES), jnp.float32)] * 3,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="fused_ce_fwd",
         interpret=interpret,
     )(lab2, x, w)
     return loss[:, 0], lse
@@ -189,6 +190,7 @@ def _bwd(x, w, lab2, lse, g2, block_n, block_v, interpret):
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="fused_ce_bwd_dx",
         interpret=interpret,
     )(lab2, g2, x, w, lse)
 
@@ -210,6 +212,7 @@ def _bwd(x, w, lab2, lse, g2, block_n, block_v, interpret):
         scratch_shapes=[pltpu.VMEM((block_v, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="fused_ce_bwd_dw",
         interpret=interpret,
     )(lab2, g2, x, w, lse)
     return dx, dw

@@ -162,7 +162,7 @@ def _kernel(*refs, scale, page, hkv, ppp, hb, with_stats, fused):
                 vw_ref[h] = jnp.where(sel, vrow_ref[h][:1], vwin_ref[h])
         for h in range(hb):
             # hb == 1 passes the block ref whole: a ``.at[0:1]`` view of
-            # a size-1 dim is a "trivial" transform that jax 0.4.37's
+            # a size-1 dim is a "trivial" transform that the
             # interpret-mode discharge mishandles when stacked under the
             # helper's integer write
             ov = o_ref if hb == 1 else o_ref.at[h:h + 1]
@@ -314,6 +314,7 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="paged_append_attend" if fused else "paged_decode_attention",
         interpret=interpret,
     )(*prefetch, *operands)
     o = res[0][:, :group, :].reshape(b, hq, d)

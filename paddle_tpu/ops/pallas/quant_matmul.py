@@ -101,6 +101,7 @@ def int8_matmul(x, q, scale, block_m: int = 256, block_n: int = 512,
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="int8_matmul",
         interpret=interpret,
     )(x2, q, scale)
     return out[:m, :n].reshape(*lead, n)

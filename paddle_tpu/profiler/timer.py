@@ -6,26 +6,13 @@ import time
 
 __all__ = ["Benchmark", "benchmark"]
 
-# Peak dense BF16 FLOP/s per chip by TPU generation (public figures).
-PEAK_BF16_FLOPS = {
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5 lite": 197e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
-}
-
-
-def detect_peak_flops(default=197e12):
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-        for name, peak in PEAK_BF16_FLOPS.items():
-            if name in kind:
-                return peak
-    except Exception:
-        pass
-    return default
+def detect_peak_flops():
+    """bf16 peak FLOP/s of the first attached device, from the one peak
+    table (``cost_model.peaks_for_kind``); a device the table does not
+    know raises instead of borrowing another chip's peak."""
+    import jax
+    from paddle_tpu.cost_model import peaks_for_kind
+    return peaks_for_kind(jax.devices()[0].device_kind)[0]
 
 
 class Benchmark:

@@ -86,17 +86,18 @@ class QuantTensor:
     def __rmatmul__(self, other):
         """``x @ qt`` — the serving hot path. On TPU this routes the
         Pallas int8 matmul (weights stream HBM→VMEM as int8, dequantized
-        per-tile at the MXU; ops/pallas/quant_matmul.py); elsewhere XLA
-        fuses the convert into the dot."""
+        per-tile at the MXU; ops/pallas/quant_matmul.py) for a 2-D
+        weight and a same-dtype activation outside a GSPMD-partitioned
+        trace (the compiler cannot split a Mosaic call); elsewhere XLA
+        fuses the convert into the dot. The kernel, once chosen, is the
+        path: its failure propagates."""
+        from paddle_tpu.distributed.mesh import gspmd_partitioned
         other = jnp.asarray(other)
         if (jax.default_backend() == "tpu" and self.q.ndim == 2
-                and other.ndim >= 2 and other.dtype == self._dtype):
-            try:
-                from paddle_tpu.ops.pallas.quant_matmul import int8_matmul
-                return int8_matmul(other, self.q,
-                                   self.scale.reshape(1, -1))
-            except Exception:
-                pass
+                and other.ndim >= 2 and other.dtype == self._dtype
+                and not gspmd_partitioned()):
+            from paddle_tpu.ops.pallas.quant_matmul import int8_matmul
+            return int8_matmul(other, self.q, self.scale.reshape(1, -1))
         return other @ self.dequantize()
 
     def __getitem__(self, idx):

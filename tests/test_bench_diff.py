@@ -2,7 +2,7 @@
 row-by-row comparison of BENCH snapshots — improvements pass,
 regressions fail by name, vanished rows fail (the r05
 RESOURCE_EXHAUSTED signature), schema mismatches refuse to compare,
-and the checked-in r05 snapshot self-diffs clean.
+and the checked-in synthetic snapshot self-diffs clean.
 """
 
 import copy
@@ -245,8 +245,14 @@ def test_driver_wrapper_shape_accepted(tmp_path):
     assert bd.main([a, b]) == 0
 
 
-def test_checked_in_r05_self_diff_clean(capsys):
-    path = os.path.join(REPO, "BENCH_r05.json")
+def test_checked_in_snapshot_self_diff_clean(capsys):
+    """The tracked SYNTHETIC snapshot tools/ci.sh self-diffs (no chip
+    record lives in the tree: the driver's ledger holds those). It is
+    `_doc()` in the driver's wrapper shape — asserted, so the fixture
+    and the test's own rows cannot drift apart."""
+    path = os.path.join(REPO, "tests", "fixtures", "bench_snapshot.json")
+    with open(path) as f:
+        assert json.load(f)["parsed"] == _doc()
     assert bd.main([path, path]) == 0
     assert "clean" in capsys.readouterr().out
 

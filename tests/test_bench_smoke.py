@@ -22,8 +22,12 @@ PEAK = 1e12  # nominal; only affects reported ratios, not execution
 
 
 def test_bench_gpt_cpu_path():
-    res = bench.bench_gpt(jax, jnp, PEAK)
-    assert res["metric"] != "bench_failed", res.get("error")
+    # on the CPU the flagship row is an ERROR unless the caller asks
+    # for the smoke size: a gpt_tiny CPU timing never stands in for it
+    with pytest.raises(RuntimeError, match="accelerator"):
+        bench.bench_gpt(jax, jnp, PEAK)
+    res = bench.bench_gpt(jax, jnp, PEAK, smoke=True)
+    assert res["metric"] == "gpt_tiny_tokens_per_sec_per_chip"
     assert res["value"] > 0
     # bench_decode depends on this attribute being set
     assert getattr(bench.bench_gpt, "model", None) is not None
@@ -31,7 +35,7 @@ def test_bench_gpt_cpu_path():
 
 def test_bench_decode_smoke():
     if getattr(bench.bench_gpt, "model", None) is None:
-        bench.bench_gpt(jax, jnp, PEAK)
+        bench.bench_gpt(jax, jnp, PEAK, smoke=True)
     out = bench.bench_decode(jax, jnp, PEAK, smoke=True)
     assert any(k.startswith("decode_") and k.endswith("_tokens_per_sec")
                for k in out), out
@@ -41,12 +45,13 @@ def test_bench_decode_smoke():
     # ...and so must the speculative path (its own try/except means a
     # regression would otherwise vanish silently)
     assert out.get("decode_spec_tokens_per_step", 0) > 0, out
-    # paged-spec row revived on the megakernel path (ISSUE 19) — the
-    # r05 row death must fail here first, and the verify program must
-    # hold the single-dispatch bound (2 pallas launches per step)
+    # paged-spec row (ISSUE 19) — the r05 row death must fail here
+    # first. It rides the engine's DEFAULT decode step (per-layer fused
+    # since PR 21), so launches scale with layers; the megakernel's
+    # 2-launch bound is asserted in test_paged_mega where mega=True is
+    # asked for by name
     assert out.get("decode_spec_paged_tokens_per_step", 0) > 0, out
-    assert 0 < out.get("decode_spec_paged_launches_per_step", 99) <= 2, \
-        out
+    assert out.get("decode_spec_paged_launches_per_step", 0) > 0, out
     # kernel-launch ladder row present on the engine path too
     assert "decode_engine_launches_per_token" in out, out
 

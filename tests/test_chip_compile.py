@@ -1,0 +1,156 @@
+"""AOT compiles of the main-path Pallas kernels at GPT-3 1.3B widths for a
+DESCRIBED TPU v5e (no chip attached): what interpret mode cannot see —
+tiling rules, accumulator types, VMEM limits — the chip's own compiler
+refuses here, at no chip time (on-chip-measurement guide, section 2).
+
+Every kernel a default path of ``chip_smoke.py`` reaches is here: the
+train step's flash attention and fused CE (forward and backward), the
+paged engine's ``paged_append_attend`` (default decode step) and
+``paged_decode_attention`` (suffix prefill), plus ``decode_attention``
+and ``int8_matmul`` (the contiguous engine) and ``mega_logits_sample``
+(opt-in, compiles since PR 21). ``mega_decode_layers`` is not: the
+compiler refuses it and no default path launches it
+(ops/pallas/decode_megakernel.py).
+
+The topology is described inside a module-scoped fixture of THIS file —
+only the xdist worker that is handed the file loads libtpu — and the
+compiles run in the test's own process with the persistent compilation
+cache off (a described-device executable is written to the cache but
+cannot be read back without a chip).
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+DM, LAYERS, HEADS, HEAD_DIM, VOCAB, SEQ = 2048, 24, 16, 128, 50304, 2048
+BATCH, SLOTS, PAGE, POOL_PAGES = 4, 8, 128, 40
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, shapes, sharding):
+    """Compile ``fn`` for the described chip from shapes alone and
+    return the names of the Pallas kernels in the compiled program."""
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        shapes)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+
+
+def _sds(shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _named(calls, name):
+    return sum(name in ln for ln in calls)
+
+
+def test_flash_attention_fwd_bwd(one_chip):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    q = _sds((BATCH, SEQ, HEADS, HEAD_DIM))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    calls = _compile(jax.grad(loss, argnums=(0, 1, 2)), (q, q, q),
+                     one_chip)
+    assert _named(calls, "flash_attention_fwd") == 1
+    assert _named(calls, "flash_attention_bwd_dkdv") == 1
+    assert _named(calls, "flash_attention_bwd_dq") == 1
+
+
+def test_fused_ce_fwd_bwd(one_chip):
+    from paddle_tpu.ops.pallas.fused_ce import fused_softmax_cross_entropy
+    n = BATCH * (SEQ - 1)            # the shifted-LM row count of the step
+    shapes = (_sds((n, DM)), _sds((VOCAB, DM)), _sds((n,), jnp.int32))
+
+    def loss(x, w, labels):
+        return fused_softmax_cross_entropy(x, w, labels,
+                                           interpret=False).sum()
+
+    calls = _compile(jax.grad(loss, argnums=(0, 1)), shapes, one_chip)
+    for name in ("fused_ce_fwd", "fused_ce_bwd_dx", "fused_ce_bwd_dw"):
+        assert _named(calls, name) == 1, name
+
+
+def _paged_shapes():
+    pool = _sds((LAYERS * POOL_PAGES + 1, HEADS, PAGE, HEAD_DIM))
+    q = _sds((SLOTS, HEADS, HEAD_DIM))
+    table = _sds((SLOTS, SEQ // PAGE), jnp.int32)
+    vec = _sds((SLOTS,), jnp.int32)
+    return pool, q, table, vec
+
+
+def test_paged_append_attend(one_chip):
+    from paddle_tpu.ops.pallas.paged_attention import paged_append_attend
+    pool, q, table, vec = _paged_shapes()
+    calls = _compile(
+        functools.partial(paged_append_attend, interpret=False),
+        (q, pool, pool, q, q, table, vec, vec), one_chip)
+    assert _named(calls, "paged_append_attend") == 1
+
+
+def test_paged_decode_attention(one_chip):
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention)
+    pool, q, table, vec = _paged_shapes()
+    calls = _compile(
+        functools.partial(paged_decode_attention, interpret=False,
+                          return_stats=True),
+        (q, pool, pool, table, vec), one_chip)
+    assert _named(calls, "paged_decode_attention") == 1
+
+
+def test_decode_attention(one_chip):
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+    cache = _sds((SLOTS, HEADS, 640, HEAD_DIM))
+    calls = _compile(
+        functools.partial(decode_attention, interpret=False,
+                          return_stats=True),
+        (_sds((SLOTS, HEADS, HEAD_DIM)), cache, cache,
+         _sds((SLOTS,), jnp.int32)), one_chip)
+    assert _named(calls, "decode_attention") == 1
+
+
+def test_int8_matmul(one_chip):
+    from paddle_tpu.ops.pallas.quant_matmul import int8_matmul
+    calls = _compile(
+        functools.partial(int8_matmul, interpret=False),
+        (_sds((SLOTS, DM)), _sds((DM, 4 * DM), jnp.int8),
+         _sds((1, 4 * DM), jnp.float32)), one_chip)
+    assert _named(calls, "int8_matmul") == 1
+
+
+def test_mega_logits_sample(one_chip):
+    """The fused norm -> logits -> argmax epilogue (opt-in with
+    mega=True): refused before PR 21 for a bf16 matmul accumulator."""
+    from paddle_tpu.ops.pallas.decode_megakernel import mega_logits_sample
+    calls = _compile(
+        functools.partial(mega_logits_sample, interpret=False),
+        (_sds((SLOTS, DM)), _sds((DM,)), _sds((DM,)), _sds((DM, VOCAB)),
+         _sds((SLOTS,), jnp.int32)), one_chip)
+    assert _named(calls, "mega_logits_sample") == 1

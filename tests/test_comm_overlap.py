@@ -16,7 +16,10 @@ The load-bearing assertions:
   overlapping, back-to-back).
 """
 
+import json
 import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -460,14 +463,39 @@ def test_prefetch_toggle_ulp_parity(fsdp_mesh):
         assert float(np.max(np.abs(a - b))) <= 1e-6, k
 
 
+def _converge_in_child(method):
+    """Body of test_overlap_step_converges, run in a child process (see
+    there): prints one JSON line with the first/last loss and the
+    error-feedback magnitude."""
+    topo = dist.init_mesh(fsdp=4, devices=jax.devices()[:4],
+                          set_global=False)
+    _, st, losses = _run(topo.mesh, steps=30, comm_quant=method)
+    ef_mag = max([float(jnp.max(jnp.abs(v)))
+                  for v in st.get("comm_ef", {}).values()] or [0.0])
+    print(json.dumps({"losses": losses, "ef_mag": ef_mag}))
+
+
 @pytest.mark.parametrize("method", [None, "bf16", "int8"])
-def test_overlap_step_converges(fsdp_mesh, method):
-    _, st, losses = _run(fsdp_mesh.mesh, steps=30, comm_quant=method)
+def test_overlap_step_converges(method):
+    """30 steps must converge on every wire. Run in a CHILD process:
+    XLA:CPU under jaxlib 0.9.0 runs independent collectives of one
+    program concurrently under one rendezvous key and then ABORTS the
+    process ("Unexpected number of participants", or a 40 s rendezvous
+    timeout); the bf16 wire's program hits it on every run seen, at the
+    seed of PR 21 too. In-process that kills the xdist worker and can
+    stall the whole session; in a child it is a failure this test
+    reports. A TPU orders its collectives and is not affected."""
+    code = (f"import sys; sys.path.insert(0, {os.path.dirname(__file__)!r}); "
+            f"import test_comm_overlap as t; "
+            f"t._converge_in_child({method!r})")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600)
+    assert r.returncode == 0, (r.returncode, r.stderr[-1500:])
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    losses = out["losses"]
     assert losses[-1] < 0.2 * losses[0], losses
     if method == "int8":
-        ef_mag = max(float(jnp.max(jnp.abs(v)))
-                     for v in st["comm_ef"].values())
-        assert ef_mag > 0.0, "error feedback never engaged"
+        assert out["ef_mag"] > 0.0, "error feedback never engaged"
 
 
 def test_loss_trajectory_parity_vs_quantized_step(fsdp_mesh):

@@ -65,19 +65,18 @@ def test_failure_records_class_and_disabled_gauge(fresh_guard):
     assert compile_cache.status()["errors"] == 2
 
 
-def test_enable_failure_latches_gauge(fresh_guard, monkeypatch):
+def test_enable_honours_env_cache_dir(fresh_guard, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is placed from outside
+    — enable() installs the guard and sets NO directory in code (jax
+    reads the variable itself)."""
     import jax
-    stats.reset("serve/compile_cache_errors")
-    stats.reset("prof/compile_cache_disabled")
-
-    def boom(*a, **k):
-        raise RuntimeError("cache backend unavailable")
-
-    monkeypatch.setattr(jax.config, "update", boom)
-    assert compile_cache.enable("/nonexistent/cache/dir") is False
-    assert stats.get("serve/compile_cache_errors/RuntimeError") == 1
-    assert stats.get("prof/compile_cache_disabled") == 1.0
-    assert compile_cache.status()["last_error_class"] == "RuntimeError"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda key, val: updates.append(key))
+    compile_cache.enable()
+    assert "jax_compilation_cache_dir" not in updates
+    assert warnings.showwarning is compile_cache._hook
 
 
 def test_guard_is_idempotent(fresh_guard):
@@ -112,17 +111,23 @@ def test_guard_env_opt_out(fresh_guard, monkeypatch):
     warnings.showwarning = hook
 
 
-def test_enable_falls_back_instead_of_raising(fresh_guard, monkeypatch):
+def test_enable_resolves_fixed_checkout_dir(fresh_guard, monkeypatch):
+    """Variable unset: every call from this checkout resolves to the
+    SAME git-ignored directory inside it (the path is part of jax's
+    cache key — a tempfile/pid/time-derived directory never hits)."""
+    import os
     import jax
-
-    stats.reset("serve/compile_cache_errors")
-
-    def boom(*a, **k):
-        raise RuntimeError("cache backend unavailable")
-
-    monkeypatch.setattr(jax.config, "update", boom)
-    assert compile_cache.enable("/nonexistent/cache/dir") is False
-    assert stats.get("serve/compile_cache_errors") == 1
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        first, second = compile_cache.enable(), compile_cache.enable()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert first == second == compile_cache.CHECKOUT_CACHE_DIR
+    assert first == os.path.join(repo, ".pt_cache", "xla")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".pt_cache/" in f.read().split()
 
 
 def test_engines_install_guard(monkeypatch):

@@ -90,6 +90,13 @@ for epoch in range(start_epoch, 6):
                              "epoch": epoch, "loss": float(loss)}}) + "\\n")
     ck.save({{"params": params, "opt": opt_state,
               "epoch": jnp.asarray(epoch, jnp.int32)}}, epoch)
+    # at world 4 the trainer idles once rank 2 has a checkpoint to die
+    # on, so the RE-FORM ends this round — not a race between six fast
+    # epochs and the launcher's reaction time (the step no longer
+    # recompiles after its first call, PR 21, which made rank 0 finish
+    # all six epochs before the re-form could land)
+    while world == 4 and epoch >= 1:
+        time.sleep(0.2)
 
 open(done_file, "w").close()
 """

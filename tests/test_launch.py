@@ -28,6 +28,24 @@ def _run_launch(args, script_body, tmp_path, name="train.py"):
         env=env, capture_output=True, text=True, timeout=120)
 
 
+def test_launch_refuses_local_workers_that_could_share_chips(tmp_path):
+    """One host is one process driving its chips: several local workers
+    are refused unless their inherited environment pins JAX to the CPU
+    (no worker is handed a chip of its own). In-process: the launcher
+    parses and refuses before it spawns anything."""
+    script = tmp_path / "train.py"
+    script.write_text("raise SystemExit('worker must not start')")
+    argv = ["--nproc_per_node", "2", str(script)]
+    saved = os.environ.pop("JAX_PLATFORMS")
+    try:
+        with pytest.raises(SystemExit) as exc:
+            launch_mod.launch(argv)
+    finally:
+        os.environ["JAX_PLATFORMS"] = saved
+    assert "refused" in str(exc.value.code)
+    assert "one process" in str(exc.value.code)
+
+
 def test_launch_sets_env_contract(tmp_path):
     body = f"""
     import os

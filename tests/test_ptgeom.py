@@ -111,6 +111,12 @@ def test_pt007_sublane_and_lane_misalignment(tmp_path):
     f = [f for f in _run(tmp_path, [spec]) if f.rule == "PT007"]
     assert len(f) == 1
     assert "sublane" in f[0].message and "lane" in f[0].message
+    # a 1-row block of a many-row array: the v5e lowering REFUSED
+    # exactly this on the megakernel's stacked LN/bias vectors (PR 21)
+    row = _spec(inputs=[_op(index=0, shape=(24, 2048), block=(1, 2048),
+                            dtype="bfloat16")])
+    f = [f for f in _run(tmp_path, [row]) if f.rule == "PT007"]
+    assert len(f) == 1 and "sublane" in f[0].message
 
 
 def test_pt007_aligned_and_full_dims_clean(tmp_path):
@@ -118,9 +124,9 @@ def test_pt007_aligned_and_full_dims_clean(tmp_path):
         _op(index=0, block=(128, 512)),
         # trailing dim == full array extent: not a chosen tile
         _op(index=1, shape=(24, 96), block=(8, 96)),
-        # block dim 1 = degenerate row-streaming: inherently padded,
-        # deliberately not flagged (megakernel per-layer slabs)
-        _op(index=2, shape=(24, 2048), block=(1, 2048),
+        # row-streaming done right: a (1, 1, n) block of an (L, 1, n)
+        # array — the last two block dims ARE the array's
+        _op(index=2, shape=(24, 1, 2048), block=(1, 1, 2048),
             dtype="bfloat16"),
     ])
     assert "PT007" not in _rules_hit(_run(tmp_path, [spec]))

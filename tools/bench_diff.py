@@ -3,7 +3,7 @@
 (ISSUE 15 regression sentinel).
 
     python tools/bench_diff.py BASELINE.json NEW.json [--rtol 0.10]
-    python tools/bench_diff.py --selftest BENCH_r05.json
+    python tools/bench_diff.py --selftest SNAPSHOT.json
 
 Until now every recapture verdict ("within ~1.5x of contiguous?", "did
 the fused kernel help?") was an eyeball diff of two JSON blobs; r05's
@@ -32,7 +32,7 @@ tok/s regression must be caught by name (wired as ``tools/ci.sh
 benchdiff`` in the default gate).
 
 Accepts both snapshot shapes: the driver wrapper ``{"parsed": {...}}``
-(BENCH_rNN.json) and bench.py's raw result line ``{"metric": ...,
+and bench.py's raw result line ``{"metric": ...,
 "extra": {...}}``.
 """
 

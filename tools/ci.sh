@@ -91,8 +91,9 @@
 #                          auto-dumped flight record holds the clean
 #                          pre-spike snapshots
 #   tools/ci.sh benchdiff  bench regression sentinel: the checked-in
-#                          BENCH_r05.json snapshot must self-diff
-#                          clean and bench_diff's synthetic 20% tok/s
+#                          synthetic snapshot
+#                          (tests/fixtures/bench_snapshot.json) must
+#                          self-diff clean and bench_diff's synthetic 20% tok/s
 #                          regression must be caught by row name
 #                          (seconds; also part of the default gate)
 #   tools/ci.sh geom       kernel-geometry gate (ISSUE 20): sweep every
@@ -127,6 +128,9 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS=cpu
+# the tracked synthetic snapshot bench_diff self-diffs (chip records live
+# in the driver's ledger, not in the tree)
+BENCH_SNAPSHOT=tests/fixtures/bench_snapshot.json
 export XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8"
 
 if [[ "${1:-}" == "lint" ]]; then
@@ -205,8 +209,8 @@ fi
 
 if [[ "${1:-}" == "benchdiff" ]]; then
     shift
-    python tools/bench_diff.py BENCH_r05.json BENCH_r05.json "$@"
-    exec python tools/bench_diff.py --selftest BENCH_r05.json
+    python tools/bench_diff.py "$BENCH_SNAPSHOT" "$BENCH_SNAPSHOT" "$@"
+    exec python tools/bench_diff.py --selftest "$BENCH_SNAPSHOT"
 fi
 
 if [[ "${1:-}" == "geom" ]]; then
@@ -227,8 +231,8 @@ fi
 if [[ "${1:-}" == "prof" ]]; then
     shift
     PD_SIZE=tiny PD_SECTIONS=prof python tools/profile_decode.py "$@"
-    python tools/bench_diff.py BENCH_r05.json BENCH_r05.json
-    exec python tools/bench_diff.py --selftest BENCH_r05.json
+    python tools/bench_diff.py "$BENCH_SNAPSHOT" "$BENCH_SNAPSHOT"
+    exec python tools/bench_diff.py --selftest "$BENCH_SNAPSHOT"
 fi
 
 if [[ "${1:-}" == "shard" ]]; then
@@ -247,6 +251,6 @@ python tools/ptlint.py paddle_tpu tools --error-on-new
 # bench regression sentinel (ISSUE 15): the checked-in baseline
 # snapshot must self-diff clean and the synthetic-regression detector
 # must fire — seconds, and it guards every future BENCH comparison
-python tools/bench_diff.py BENCH_r05.json BENCH_r05.json
-python tools/bench_diff.py --selftest BENCH_r05.json
+python tools/bench_diff.py "$BENCH_SNAPSHOT" "$BENCH_SNAPSHOT"
+python tools/bench_diff.py --selftest "$BENCH_SNAPSHOT"
 python -m pytest tests/ -q --durations=15 "$@"

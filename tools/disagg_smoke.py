@@ -22,7 +22,9 @@ import subprocess
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a multi-process CPU fleet smoke: one host is one process driving its
+# chips, so neither this parent nor its workers may reach for one
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["PT_KV_WIRE"] = "fp32"      # the bit-identity contract
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)

@@ -26,7 +26,9 @@ import tempfile
 import time
 import urllib.request
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a multi-process CPU fleet smoke: one host is one process driving its
+# chips, so neither this parent nor its workers may reach for one
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["PT_KV_WIRE"] = "fp32"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)

@@ -23,7 +23,9 @@ import subprocess
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a multi-process CPU fleet smoke: one host is one process driving its
+# chips, so neither this parent nor its workers may reach for one
+os.environ["JAX_PLATFORMS"] = "cpu"
 if "xla_force_host_platform_device_count" not in \
         os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
