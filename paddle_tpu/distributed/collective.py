@@ -107,7 +107,7 @@ def _issue_span(name, x, axis):
         if not getattr(_wire_ctx, "active", False):
             # ptlint: disable=PT003 -- per-compilation byte counters
             stats.add("comm/bytes_logical", nbytes)
-    if not trace.enabled():
+    if not trace.live():
         return contextlib.nullcontext()
     # ptlint: disable=PT003 -- issue-span semantics documented above
     return trace.span(f"collective/{name}", axis=str(axis),

@@ -469,9 +469,13 @@ class ResilientScheduler:
         rec = self._pending.popleft()
         with trace.span("serve/harvest", kind=rec.kind,
                         inflight=len(self._pending)) as sp:
-            # ptlint: disable=PT001 -- THE one deliberate sync: the lag-one
-            # harvest's single packed device→host transfer (docs/serving.md)
-            arr = np.asarray(rec.payload)
+            # the ONE place the host blocks on the device; the rest of
+            # serve/harvest is the host's replay of what came back
+            with trace.span("serve/device_wait", kind=rec.kind):
+                # ptlint: disable=PT001 -- THE one deliberate sync: the
+                # lag-one harvest's single packed device→host transfer
+                # (docs/serving.md)
+                arr = np.asarray(rec.payload)
             emitted = self._replay(rec, arr)
             sp.attrs["tokens"] = emitted
         stats.set_value("serve/inflight", len(self._pending))
@@ -1472,6 +1476,7 @@ class DecodeEngine(ResilientScheduler):
             n = self.tokens_emitted - base
             sp.attrs["active"] = live
             sp.attrs["tokens"] = n
+            sp.attrs["waiting"] = len(self._waiting)
         if live or n:
             self._obs_step(t0, n, live)
         return n

@@ -1193,9 +1193,7 @@ class PagedDecodeEngine(ResilientScheduler):
         enqueues behind in-flight decode dispatches instead of draining
         them. With the prefix cache on, the longest cached prefix's
         pages are mapped read-only and only the suffix is prefilled."""
-        import time
-        from paddle_tpu import stats
-        from paddle_tpu.observability import flight, trace
+        from paddle_tpu.observability import trace
         # ptlint: disable=PT001 -- req.prompt is a host int list
         # (submit coerced it); this is an upload, never a sync
         prompt = np.asarray(req.prompt, np.int32)
@@ -1204,6 +1202,26 @@ class PagedDecodeEngine(ResilientScheduler):
                               if self._prefix is not None
                               else (0, -1, None))
         self._reserve(slot, n)
+        bucket = next(b for b in self.buckets if b >= n - sp)
+        # the span opens once the reservation HELD (the MemoryError-
+        # retried admission re-runs this method and must leave no
+        # phantom span) and closes at the _pending.append: the host's
+        # preparation, the prefill enqueue, the slot-state updates
+        with trace.span("serve/admit", slot=slot, prompt=n,
+                        bucket=bucket, cached=sp, rid=req.rid):
+            self._admit_reserved(req, slot, prompt, bucket, sp, cow_src,
+                                 chain)
+
+    def _admit_reserved(self, req, slot, prompt, bucket, sp, cow_src,
+                        chain):
+        """The admission past its page reservation (the body of the
+        ``serve/admit`` span): prefill ``prompt`` in ``bucket``, its
+        first ``sp`` tokens served from the prefix cache
+        (``cow_src``/``chain`` as ``_match_prefix`` gave them)."""
+        import time
+        from paddle_tpu import stats
+        from paddle_tpu.observability import flight, trace
+        n = len(prompt)
         tab = self._tables[slot]
         if self._prefix is not None:
             if n >= self.page:
@@ -1218,12 +1236,10 @@ class PagedDecodeEngine(ResilientScheduler):
                 self._update_pool_gauges()
             # counters only once the reservation held — the
             # MemoryError-retry path re-runs this whole admission
-            from paddle_tpu import stats
             stats.add("serve/prefix_lookup")
             if sp:
                 stats.add("serve/prefix_hit_tokens", sp)
         self._corrupt_shared_pages(tab[:sp // self.page])
-        bucket = next(b for b in self.buckets if b >= n - sp)
         # observability lands only once the reservation HELD — the
         # MemoryError-retried admission re-runs this whole method, and
         # a duplicate serve/queue span would put phantom queue-wait
@@ -1253,8 +1269,8 @@ class PagedDecodeEngine(ResilientScheduler):
             mx = (self.cfg.max_seq_len + self.page - 1) // self.page
             row = np.zeros((mx,), np.int32)
             row[:len(tab)] = tab
-            with trace.span("serve/admit", slot=slot, prompt=n,
-                            bucket=bucket, cached=sp, rid=req.rid):
+            with trace.span("serve/dispatch", kind="prefill",
+                            bucket=bucket):
                 self.kp, self.vp, nxt = self._prefill_sfx_fn(
                     self._head, self._stacked, self.kp, self.vp,
                     jnp.asarray(suffix), jnp.int32(sp), jnp.int32(n),
@@ -1274,8 +1290,8 @@ class PagedDecodeEngine(ResilientScheduler):
                     segs[i, l] = (l * self.P + pid, t, run)
                 t += run
                 i += 1
-            with trace.span("serve/admit", slot=slot, prompt=n,
-                            bucket=bucket, cached=0, rid=req.rid):
+            with trace.span("serve/dispatch", kind="prefill",
+                            bucket=bucket):
                 self.kp, self.vp, nxt = self._prefill_fn(
                     self._head, self._stacked, self.kp, self.vp,
                     jnp.asarray(padded), jnp.int32(n),
@@ -1574,6 +1590,16 @@ class PagedDecodeEngine(ResilientScheduler):
             n_live = self._step_inner(sp)
             n = self.tokens_emitted - base
             sp.attrs["tokens"] = n
+            if sp.live:
+                sp.attrs["waiting"] = len(self._waiting)
+                # what the traffic holds of the pool, token by token
+                # and page by page, at the end of the step
+                sp.attrs["live_tokens"] = sum(
+                    int(self._host_len[s])
+                    for s, r in enumerate(self._slot_req)
+                    if r is not None)
+                sp.attrs["pages_used"] = self.P - self.free_pages
+                sp.attrs["pages"] = self.P
         if n_live or n:
             # idle polls record nothing (matching DecodeEngine): zero
             # occupancy/queue samples from an empty engine would read

@@ -1133,9 +1133,14 @@ def build_train_step(model: GPT, optimizer, mesh: Optional[Mesh] = None,
             # logits never hit HBM (falls back internally otherwise)
             return fused_lm_loss(m, tokens, rng_key=rng)
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        # scopes name the compiled operations' metadata (a profile can
+        # split the step by them); they cost nothing at run time
+        with jax.named_scope("train/loss_and_grad"):
+            loss, grads = jax.value_and_grad(loss_fn)(params)
         grads = _nm.poison_grads(grads, step_count=opt_state["step"])
-        new_params, new_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("train/optimizer"):
+            new_params, new_state = optimizer.update(grads, opt_state,
+                                                     params)
         if num_on:
             updates = jax.tree_util.tree_map(
                 lambda n, o: n - o, new_params, params)

@@ -17,8 +17,7 @@ subsystem stays dormant (one dict lookup per process).
 import os
 
 from paddle_tpu.observability import trace
-from paddle_tpu.observability.trace import (span, begin, end, complete,
-                                            instant)
+from paddle_tpu.observability.trace import span, complete
 from paddle_tpu.observability.statsz import (StatszServer, start_statsz,
                                              stop_statsz)
 from paddle_tpu.observability.merge import (merge_trace_files,
@@ -34,7 +33,7 @@ from paddle_tpu.observability import runtime
 from paddle_tpu.observability import devprof
 from paddle_tpu.observability import numerics
 
-__all__ = ["trace", "span", "begin", "end", "complete", "instant",
+__all__ = ["trace", "span", "complete",
            "StatszServer", "start_statsz", "stop_statsz",
            "merge_trace_files", "merge_rank_traces",
            "stitch_trace_files", "stitch_rank_traces",

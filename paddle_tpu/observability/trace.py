@@ -16,16 +16,24 @@ Design:
   one short lock around an index bump + slot write. When the ring wraps,
   the oldest events are overwritten and ``trace/dropped`` counts them —
   a tracer must never grow without bound inside a serving loop.
-- **Disabled = near-free**: ``span()`` checks one module-level flag and
-  returns without touching clocks or locks (the <1% overhead budget on
-  the decode benchmark). Enable via ``PT_TRACE_DIR`` env (the atexit
-  hook then exports ``trace_rank{N}.json`` there), ``PT_TRACE_FILE``
-  (exact path, wins over the dir), or ``enable()``.
+- **Two switches, one span**: a span is LIVE while the ring is enabled
+  — ``PT_TRACE_DIR`` env (the atexit hook then exports
+  ``trace_rank{N}.json`` there), ``PT_TRACE_FILE`` (exact path, wins
+  over the dir), or ``enable()`` — OR while a ``jax.profiler`` session
+  runs (``jax.profiler.start_trace`` ... ``stop_trace``). A live span
+  is recorded in the ring and enters a ``jax.profiler.TraceAnnotation``
+  of its own name, so a profile of a running program holds the
+  program's spans on the host plane of its ``.xplane.pb``, on the
+  clock of the device's operations: an idle gap of the device can be
+  put down to a phase of the program.
+- **Off = one shared object**: with neither switch on, ``span()``
+  returns the same no-op object every time — no allocation, no clock,
+  no lock. Attributes that cost anything to compute are computed under
+  ``if sp.live:``.
 - **Nesting**: a thread-local stack gives every span its parent id, so
   request → batch → kernel-dispatch timelines reconstruct in Perfetto.
-  Async work that crosses threads uses explicit ``begin()``/``end()``
-  tokens; after-the-fact intervals (e.g. a request's full lifetime,
-  only known at completion) use ``complete()``.
+  After-the-fact intervals (e.g. a request's full lifetime, only known
+  at completion) use ``complete()``; they go to the ring only.
 - **Clocks**: spans time with ``perf_counter_ns`` (monotonic); export
   rebases onto the wall clock via a process-start offset so ranks on
   one host (or NTP-synced hosts) land on a shared timeline.
@@ -40,16 +48,17 @@ duration — that lives in the XLA trace. Host-side ops (p2p, checkpoint
 IO, engine steps) time for real.
 """
 
-import functools
 import json
 import os
 import threading
 import time
 from typing import Optional
 
-__all__ = ["span", "begin", "end", "complete", "instant", "enable",
-           "disable", "enabled", "export", "events", "clear",
-           "trace_file_from_env", "start_flush"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["span", "complete", "enable", "disable", "enabled", "live",
+           "export", "events", "clear", "trace_file_from_env",
+           "start_flush"]
 
 _DEFAULT_RING = 65536
 
@@ -122,115 +131,112 @@ class _Tracer:
 _TRACER = _Tracer()
 
 
-class _Span:
-    """Context manager + decorator for one named range. Mutate ``attrs``
-    inside the ``with`` block to attach values only known mid-span
-    (payload bytes, token counts)."""
+# a jax.profiler session is running (a C++ flag: tens of nanoseconds)
+_profiling = TraceAnnotation.is_enabled
 
-    __slots__ = ("name", "attrs", "_t0", "_sid", "_parent", "_live")
+
+def live() -> bool:
+    """Spans are being recorded: the ring is enabled or a
+    ``jax.profiler`` session is running."""
+    return _TRACER.enabled or _profiling()
+
+
+class _Span:
+    """One live named range (context manager). Mutate ``attrs`` inside
+    the ``with`` block to attach values only known mid-span (payload
+    bytes, token counts)."""
+
+    __slots__ = ("name", "attrs", "_t0", "_sid", "_parent", "_ann")
+    live = True
 
     def __init__(self, name, attrs):
         self.name = name
         self.attrs = attrs
-        self._live = False
 
     def __enter__(self):
         tr = _TRACER
-        if not tr.enabled:
-            return self
-        self._live = True
         self._sid = tr.new_id()
         st = tr.stack()
         self._parent = st[-1] if st else 0
         st.append(self._sid)
+        # the same range in the profiler's own trace (a no-op while no
+        # session runs); entered first and left last, so the ring's
+        # interval lies inside it
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        if not self._live:
-            return False
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         tr = _TRACER
         st = tr.stack()
         if st and st[-1] == self._sid:
             st.pop()
         tr.record(self.name, self._t0, t1 - self._t0, self._sid,
                   self._parent, self.attrs or None)
-        self._live = False
         return False
 
-    def __call__(self, fn):
-        name, attrs = self.name, self.attrs
 
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            with _Span(name, dict(attrs) if attrs else {}):
-                return fn(*a, **kw)
+class _NoAttrs:
+    """``attrs`` of the off span: takes a write and keeps nothing."""
 
-        return wrapper
+    __slots__ = ()
 
-
-def span(name: str, **attrs) -> _Span:
-    """``with span("p2p/send", dst=3) as sp: ... sp.attrs["bytes"] = n``
-    — or ``@span("ckpt/save")`` as a decorator. Disabled tracing makes
-    __enter__/__exit__ no-ops (one flag check)."""
-    return _Span(name, attrs)
+    def __setitem__(self, key, value):
+        pass
 
 
-def begin(name: str, **attrs):
-    """Explicit async begin: returns a token for ``end()``. The span is
-    parentless unless ``parent=`` (a token/sid) is passed in attrs —
-    async work crosses threads, so the thread-local stack is not used."""
-    tr = _TRACER
-    if not tr.enabled:
-        return None
-    parent = attrs.pop("parent", None)
-    return (name, time.perf_counter_ns(), tr.new_id(),
-            parent[2] if isinstance(parent, tuple) else (parent or 0),
-            attrs)
+class _OffSpan:
+    """What ``span()`` returns while nothing records: one object for
+    every call."""
+
+    __slots__ = ()
+    live = False
+    attrs = _NoAttrs()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
 
 
-def end(token, **extra_attrs):
-    """Close a ``begin()`` token (no-op for None tokens)."""
-    tr = _TRACER
-    if token is None or not tr.enabled:
-        return
-    name, t0, sid, parent, attrs = token
-    if extra_attrs:
-        attrs = {**attrs, **extra_attrs}
-    tr.record(name, t0, time.perf_counter_ns() - t0, sid, parent,
-              attrs or None)
+_OFF = _OffSpan()
+
+
+def span(name: str, **attrs):
+    """``with span("p2p/send", dst=3) as sp: ... sp.attrs["bytes"] = n``.
+    Live while the ring is enabled or a ``jax.profiler`` session runs;
+    otherwise the one shared no-op span (``sp.live`` is False)."""
+    if _TRACER.enabled or _profiling():     # live(), inlined: the off path
+        return _Span(name, attrs)
+    return _OFF
 
 
 def complete(name: str, t0_s: float, t1_s: Optional[float] = None,
              **attrs):
     """Record an interval after the fact from ``time.perf_counter()``
     endpoints (seconds) — e.g. a serving request's submit→done lifetime,
-    only known at completion."""
-    tr = _TRACER
-    if not tr.enabled:
+    only known at completion. Ring only: the profiler's trace takes no
+    range that has already ended."""
+    if not live():
         return
+    tr = _TRACER
     t1_s = time.perf_counter() if t1_s is None else t1_s
     tr.record(name, int(t0_s * 1e9), int((t1_s - t0_s) * 1e9),
               tr.new_id(), 0, attrs or None)
-
-
-def instant(name: str, **attrs):
-    """Zero-duration marker event."""
-    tr = _TRACER
-    if not tr.enabled:
-        return
-    tr.record(name, time.perf_counter_ns(), 0, tr.new_id(), 0,
-              attrs or None)
 
 
 # -- lifecycle ---------------------------------------------------------------
 
 def enable(out_path: Optional[str] = None,
            capacity: Optional[int] = None):
-    """Turn recording on. ``out_path``: where the atexit/``export()``
-    default write goes (a .json file path, or a directory that gets
-    ``trace_rank{N}.json``)."""
+    """Turn the ring on (spans are also live, without this, for as long
+    as a ``jax.profiler`` session runs). ``out_path``: where the
+    atexit/``export()`` default write goes (a .json file path, or a
+    directory that gets ``trace_rank{N}.json``)."""
     if capacity is not None:
         _TRACER.clear(capacity)
     if out_path is not None:
@@ -243,6 +249,8 @@ def disable():
 
 
 def enabled() -> bool:
+    """The ring's own switch (``live()`` also counts a profiler
+    session)."""
     return _TRACER.enabled
 
 

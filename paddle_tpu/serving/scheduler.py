@@ -454,34 +454,38 @@ class FrontEnd:
         room (free slots plus ``admit_ahead`` staged). Returns how many
         were admitted."""
         from paddle_tpu import stats
+        from paddle_tpu.observability import trace
         eng = self.engine
-        if capacity is None:
-            capacity = (eng.free_slots + self.admit_ahead - eng.queued)
-        admitted = 0
-        # one sort per feed: the ordering keys (priority / absolute
-        # deadline / arrival seq) are immutable while queued
-        self._queue.sort(key=self._order_key)
-        while capacity > 0 and self._queue:
-            req = self._queue.pop(0)
-            if not self._admissible(req):
-                continue
-            ereq = eng.submit(
-                req.prompt, max_new_tokens=req.max_new_tokens,
-                eos_id=req.eos_id,
-                deadline_s=(None if req.deadline is None
-                            else req.deadline - time.monotonic()),
-                req_id=req.id)
-            # TTFT must count the front-end queue wait: re-anchor the
-            # engine request's clock to the front-end submission
-            ereq.t_submit = req.t_submit
-            req.engine_req = ereq
-            req.status = "admitted"
-            self._by_engine_req[id(ereq)] = req
-            stats.observe("serve/queue_wait_s",
-                          time.perf_counter() - req.t_submit)
-            capacity -= 1
-            admitted += 1
-        stats.set_value("serve/queue_len", len(self._queue))
+        with trace.span("serve/feed") as sp:
+            if capacity is None:
+                capacity = (eng.free_slots + self.admit_ahead
+                            - eng.queued)
+            admitted = 0
+            # one sort per feed: the ordering keys (priority / absolute
+            # deadline / arrival seq) are immutable while queued
+            self._queue.sort(key=self._order_key)
+            while capacity > 0 and self._queue:
+                req = self._queue.pop(0)
+                if not self._admissible(req):
+                    continue
+                ereq = eng.submit(
+                    req.prompt, max_new_tokens=req.max_new_tokens,
+                    eos_id=req.eos_id,
+                    deadline_s=(None if req.deadline is None
+                                else req.deadline - time.monotonic()),
+                    req_id=req.id)
+                # TTFT must count the front-end queue wait: re-anchor
+                # the engine request's clock to the front-end submission
+                ereq.t_submit = req.t_submit
+                req.engine_req = ereq
+                req.status = "admitted"
+                self._by_engine_req[id(ereq)] = req
+                stats.observe("serve/queue_wait_s",
+                              time.perf_counter() - req.t_submit)
+                capacity -= 1
+                admitted += 1
+            stats.set_value("serve/queue_len", len(self._queue))
+            sp.attrs["admitted"] = admitted
         return admitted
 
     def _sweep_expired(self):
@@ -508,14 +512,16 @@ class FrontEnd:
         admission) diverges from one that keeps the pipeline fed
         (stays near 1.0 minus the lag-one backfill step)."""
         from paddle_tpu import stats
-        self._sweep_expired()
-        self._feed()
-        eng = self.engine
-        backlogged = (len(self._queue) + eng.queued) > eng.free_slots
-        n = eng.step()
-        if backlogged:
-            stats.observe("serve/fed_occupancy",
-                          (eng.S - eng.free_slots) / eng.S)
+        from paddle_tpu.observability import trace
+        with trace.span("serve/frontend_step", queued=len(self._queue)):
+            self._sweep_expired()
+            self._feed()
+            eng = self.engine
+            backlogged = (len(self._queue) + eng.queued) > eng.free_slots
+            n = eng.step()
+            if backlogged:
+                stats.observe("serve/fed_occupancy",
+                              (eng.S - eng.free_slots) / eng.S)
         return n
 
     @property

@@ -68,6 +68,25 @@ class TestTrainStep:
             losses.append(float(loss))
         assert losses[-1] < losses[0] - 0.3, losses
 
+    def test_scopes_name_the_compiled_operations(self):
+        """The step's two halves are named in the operations' metadata,
+        which is where a profile reads them: every matrix product of the
+        forward and backward passes under ``train/loss_and_grad``, the
+        update under ``train/optimizer``."""
+        cfg = _tiny(n_layers=2)
+        model = gpt.GPT(cfg, seed=0)
+        opt = optim.AdamW(learning_rate=1e-3)
+        params, opt_state = gpt.init_train_state(model, opt)
+        step = gpt.build_train_step(model, opt, donate=False)
+        hlo = step.lower(params, opt_state, _tokens(cfg),
+                         jax.random.PRNGKey(0)).compile().as_text()
+        named = [ln for ln in hlo.splitlines() if "op_name=" in ln]
+        dots = [ln for ln in named if " dot(" in ln or "convolution(" in ln]
+        assert dots and all("train/loss_and_grad" in ln for ln in dots)
+        assert any("train/optimizer" in ln for ln in named)
+        assert not any("train/optimizer" in ln
+                       and "train/loss_and_grad" in ln for ln in named)
+
 
 class TestPipeline:
     def test_stack_unstack_roundtrip(self):

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu import stats
-from paddle_tpu.observability import (span, begin, end, complete, trace,
+from paddle_tpu.observability import (span, complete, trace,
                                       merge_trace_files,
                                       merge_rank_traces, start_statsz,
                                       stop_statsz)
@@ -68,43 +68,66 @@ def test_span_nesting_parent_ids(tmp_path):
                 <= by_name["outer"]["ts"] + by_name["outer"]["dur"] + 1)
 
 
-def test_span_decorator_and_disabled_noop(tmp_path):
+def test_span_disabled_noop(tmp_path):
     calls = []
 
-    @span("deco/fn", tag=1)
     def fn(v):
-        calls.append(v)
-        return v * 2
+        with span("wrapped/fn", tag=1) as sp:
+            sp.attrs["v"] = v           # an off span takes and drops it
+            calls.append(v)
+            return v * 2
 
     assert fn(3) == 6          # disabled: still runs, records nothing
     assert trace.events()[0] == []
     trace.enable(str(tmp_path))
     assert fn(4) == 8
     evs, dropped = trace.events()
-    assert [e[0] for e in evs] == ["deco/fn"] and dropped == 0
+    assert [e[0] for e in evs] == ["wrapped/fn"] and dropped == 0
+    assert evs[0][6] == {"tag": 1, "v": 4}
     assert calls == [3, 4]
 
 
+def test_off_span_allocates_nothing():
+    """With neither the ring nor a profiler session on, every ``span()``
+    is the one shared no-op object: nothing is allocated per call."""
+    assert not trace.live()
+    a, b = span("serve/step"), span("p2p/send", dst=3)
+    assert a is b and not a.live
+    with a as sp:
+        assert sp is a
+        sp.attrs["tokens"] = 7
+    assert trace.events()[0] == []
+    trace.enable()
+    assert trace.live()
+    c = span("serve/step")
+    assert c is not a and c.live and c is not span("serve/step")
+
+
 def test_async_begin_end_and_complete(tmp_path):
+    """``complete`` records an interval after the fact, from any thread
+    (``begin``/``end`` tokens are gone: nothing called them)."""
     trace.enable(str(tmp_path))
-    tok = begin("async/op", job=7)
+    import time
+    t0 = time.perf_counter() - 0.25
     done = threading.Event()
 
     def other_thread():
-        end(tok, ok=True)
+        complete("late/interval", t0, tokens=3)
         done.set()
 
     threading.Thread(target=other_thread).start()
-    done.wait(5)
-    import time
-    t0 = time.perf_counter() - 0.25
-    complete("late/interval", t0, tokens=3)
+    assert done.wait(5)
+    complete("fixed/interval", 1.0, 1.5, job=7)
     doc, evs = _export_events(tmp_path)
     by_name = {e["name"]: e for e in evs}
-    assert by_name["async/op"]["args"]["job"] == 7
-    assert by_name["async/op"]["args"]["ok"] is True
     assert by_name["late/interval"]["dur"] >= 0.2e6  # ~250ms in us
     assert by_name["late/interval"]["args"]["tokens"] == 3
+    assert by_name["late/interval"]["args"]["parent_id"] == 0
+    assert by_name["fixed/interval"]["dur"] == pytest.approx(0.5e6)
+    assert by_name["fixed/interval"]["args"]["job"] == 7
+    trace.disable()
+    complete("off/interval", t0)
+    assert "off/interval" not in [e[0] for e in trace.events()[0]]
 
 
 def test_ring_buffer_overflow_keeps_newest(tmp_path):
