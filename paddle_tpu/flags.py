@@ -457,7 +457,7 @@ declare_env("PT_PAGED_TUNE", "1 runs paged-kernel autotuning "
 declare_env("PT_PAGED_MEGA", "1 asks for the single-dispatch decode "
             "megakernel (layer-folded layers + fused sampling "
             "epilogue, 2 launches/step) in place of the default "
-            "per-layer fused path (one paged launch per layer). The "
+            "per-layer fused path (two paged launches per layer). The "
             "v5e compiler refuses the megakernel today (weight slab "
             "over VMEM at 1.3B; a dynamic_slice Mosaic does not "
             "lower), so it is interpret-mode only and its error "

@@ -46,7 +46,11 @@ def make_engine(model, engine: Optional[str] = None, *,
     full-length sequence (``max_slots * ceil(max_len / page_size)``) —
     the no-surprises envelope; real deployments size the pool to the
     LIVE-token budget instead (that over-commit is the engine's whole
-    point) and pass ``n_pages`` explicitly. Remaining kwargs pass
+    point) and pass ``n_pages`` explicitly. The decode step's cost does
+    not depend on the pool's size (the pools are updated in place; on
+    one v5e GPT-3 XL steps in 26.8 ms at 64 pages and at this default,
+    256 pages at 16 slots, whose pool is 6.4 GB: PERF.md section 4),
+    so the envelope costs memory only. Remaining kwargs pass
     through to the chosen engine's constructor (``speculative_k`` works
     on both)."""
     kind = engine if engine is not None else default_engine_kind()

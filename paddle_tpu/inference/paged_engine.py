@@ -17,19 +17,26 @@ TPU design decisions:
   pool (a lax.dynamic_slice there would copy the full layer pool every
   step).
 - **Fused append+attend decode step** (default; ``PT_PAGED_FUSED=0``
-  falls back): each layer calls `paged_append_attend`, which folds the
-  current token's fresh KV row into the online softmax AND writes it
-  into its pool page inside the same kernel launch
-  (``input_output_aliases`` on the layer-folded pools; the write target
-  is derived from the block table + per-slot length, inactive slots
-  write the scratch page). The separate one-batched-scatter-per-cache-
-  per-token the read-only formulation paid (`_write_token_rows`) is
-  gone from the dispatch path. History: the original write-first form
-  (per-layer scatter with the pools as layer-scan carry) measured
-  ~0.05x of the HBM roofline on hardware; the read-only-pool form
+  falls back): each layer calls `paged_append_attend`, two launches: a
+  small write kernel merges the current token's fresh KV row into its
+  pool page in place (the layer-folded pools are that call's only pool
+  operands, aliased to its outputs; the write target is derived from
+  the block table + per-slot length, inactive slots write the scratch
+  page), then the read-only attend runs over the pools the write
+  returned, at ``lengths + 1``. The separate one-batched-scatter-per-
+  cache-per-token the read-only formulation paid (`_write_token_rows`)
+  is gone from the dispatch path, and nothing copies the pools: they
+  stay in place through the layer scan and the chunk scan
+  (`tests/test_chip_compile.py` holds the compiled program to that).
+  History: the original write-first form (per-layer scatter with the
+  pools as layer-scan carry) measured ~0.05x of the HBM roofline on
+  hardware; the read-only-pool form
   (`paged_decode_attention(return_stats=True)` + `fold_fresh_row` +
-  one scatter per token) measured 0.17x; fusing the write removes the
-  remaining extra pool traffic per token (ISSUE 6).
+  one scatter per token) measured 0.17x; the single-launch fused kernel
+  (ISSUE 6) took each pool twice in one aliased call, which made XLA
+  copy both whole pools twice per layer — 84% of the GPT-3 XL step on
+  a v5e, a step that followed the pool's size and not the work
+  (PERF.md section 5, PR 26).
 - **Prefix/radix caching** (default; ``PT_PAGED_PREFIX=0`` disables):
   the page pool doubles as a shared radix store
   (`inference/prefix_cache.py`). ``submit``'s admission looks up the
@@ -115,14 +122,13 @@ class PagedDecodeEngine(ResilientScheduler):
 
     Status: greedy output is bit-identical to ``gpt.generate`` across
     page/chunk geometries in interpret mode (f32), and serving HBM
-    scales with live tokens. First run of THIS code on a chip (one
-    v5e, GPT-3 1.3B, PR 21, ``chip_smoke.py``): the default per-layer
-    fused step compiles and serves, agreeing with ``gpt.generate`` up
-    to bf16 ties. It is slow (PERF.md has the step times) and what
-    bounds it has not been traced. One candidate stands from the r5
-    notes: one pallas launch per layer per token whose grid walks the
-    FULL fixed-width page table (ceil(max_seq_len/page) columns,
-    mostly masked at short lengths)."""
+    scales with live tokens. On a chip (one v5e, GPT-3 XL; PERF.md has
+    the step times) the default per-layer fused step compiles and
+    serves, agreeing with ``gpt.generate`` up to bf16 ties. Since PR 26
+    the step no longer copies the pools; what bounds it now is the
+    paged attend itself: one attend launch per layer per token whose grid
+    walks the FULL fixed-width page table (ceil(max_seq_len/page)
+    columns, mostly masked at short lengths)."""
 
     def __init__(self, model, n_pages: int, max_slots: int = 8,
                  page_size: int = 128, steps_per_call: int = 1,
@@ -175,7 +181,8 @@ class PagedDecodeEngine(ResilientScheduler):
                       if fused is None else bool(fused))
         # THE decode-step choice (docs/serving.md "Single-dispatch
         # decode"). Default: the per-layer fused path — one
-        # `paged_append_attend` launch per layer inside a lax.scan —
+        # `paged_append_attend` (row write + attend, two launches) per
+        # layer inside a lax.scan —
         # because it is the one the v5e compiler accepts
         # (tests/test_chip_compile.py; PR 21 chip run). The layer-folded
         # megakernel is opt-in (mega=True or PT_PAGED_MEGA=1): it
@@ -456,11 +463,15 @@ class PagedDecodeEngine(ResilientScheduler):
         slot stops advancing and the host evicts only that request.
 
         FUSED path (default): each layer calls `paged_append_attend` —
-        the fresh KV row is folded into the online softmax AND written
-        into its pool page inside the kernel (input/output-aliased
-        pools carried through the layer scan; inactive slots' writes
-        target the scratch page). No per-token scatter remains in the
-        dispatch.
+        its write launch merges the fresh KV row into its pool page in
+        place (the pools, carried through the layer scan, are that
+        call's only pool operands; inactive slots' writes target the
+        scratch page), then its read-only attend runs over the
+        returned pools at ``lengths + 1``. No per-token scatter and no
+        pool copy remain in the dispatch. An inactive slot attends a
+        row nobody wrote (its own page's stale row at ``lengths``):
+        its output is finite garbage that ``nxt``/``bad`` below mask
+        by ``active``.
 
         Fallback (``PT_PAGED_FUSED=0``): the pools stay READ-ONLY
         inside the layer scan — `paged_decode_attention(return_stats)`
@@ -555,11 +566,10 @@ class PagedDecodeEngine(ResilientScheduler):
                         last, active, poison):
         """Single-dispatch variant of `_one_token` (``PT_PAGED_MEGA``):
         same signature, same greedy stream, ≤2 kernel launches. The
-        per-layer fused path above stays as the bit-parity reference
-        (token streams identical; pool rows agree to last-ulp — the
-        megakernel folds the fresh KV row in page order while the
-        per-layer kernel folds it after all pages, so layer>=1 rows
-        may differ in the final bit of the accumulation)."""
+        per-layer fused path above stays as the parity reference
+        (token streams identical; both fold the fresh KV row in page
+        order, but as differently shaped accumulations, so layer>=1
+        pool rows may differ in the final bit)."""
         kp, vp, tok, nf = self._mega_rows(
             head, stacked, kp, vp, table, lengths,
             jnp.arange(self.S, dtype=jnp.int32),

@@ -16,18 +16,25 @@ sequence owns — same online-softmax inner loop as decode_attention,
 same clamp trick (a repeated page index is not re-fetched) for rows
 shorter than the longest.
 
-Two kernel entry points share one body:
+Two entry points:
 
 - `paged_decode_attention` — read-only pools, optional (m, l) stats so
   the caller can fold extra columns analytically (the pre-fusion
   engine formulation).
-- `paged_append_attend` — the FUSED append+attend step: the current
-  token's fresh K/V row is folded into the online softmax *and*
-  written into its pool page inside the kernel, with
-  ``input_output_aliases`` on the pools so the write is in place. The
-  one batched scatter per cache per token the engine used to pay
-  disappears (ISSUE 6 / PAPERS "LLM Inference Acceleration via
-  Efficient Operation Fusion").
+- `paged_append_attend` — the decode step's append+attend, as TWO
+  launches: a small write kernel (`paged_append_attend_write`) merges
+  the current token's fresh K/V row into its pool page in place, then
+  the read-only attend runs over the pools the write RETURNED, with
+  ``lengths + 1``. Each pool reaches the write kernel as that call's
+  only use of it (one operand, aliased to its output), so XLA keeps the
+  pools in place through the engine's layer and token scans. Two
+  shortcuts each cost whole-pool copies per layer (PERF.md section 5):
+  one launch that takes a pool both as read streams and as the aliased
+  write view (XLA copies an operand that one instruction reads and
+  overwrites: 84% of the GPT-3 XL decode step), and an XLA scatter in
+  the write's place (it lays the pool out differently from what the
+  kernel reads, so the compiler converts it both ways). The data
+  dependence (attend reads what the write returned) orders the two.
 
 Both take an autotunable ``(pages_per_program, head_block)`` config
 (see `tune_paged_attention`): pages_per_program streams several pages
@@ -90,27 +97,18 @@ def _resolve_config(ppp, hb, page, hkv, d, dtype, group, max_pages,
     return ppp, hb
 
 
-def _kernel(*refs, scale, page, hkv, ppp, hb, with_stats, fused):
-    # Ref layout (the table/wpid prefetch refs are consumed by the
-    # BlockSpec index maps, not the body, but still appear in the ABI;
-    # the stats output exists only when requested, so trailing refs
-    # shift — same convention as the contiguous decode kernel):
-    #   plain: len, table, q, k*ppp, v*ppp | o, [ml] | acc, m, l
-    #   fused: len, table, wpid, q, k*ppp, v*ppp, krow, vrow, kwin,
-    #          vwin | o, kw, vw | acc, m, l
-    if fused:
-        len_ref, _table_ref, _wpid_ref, q_ref = refs[:4]
-        rest = refs[4:]
-    else:
-        len_ref, _table_ref, q_ref = refs[:3]
-        rest = refs[3:]
+def _kernel(*refs, scale, page, hkv, ppp, hb, with_stats):
+    # Ref layout (the table prefetch ref is consumed by the BlockSpec
+    # index maps, not the body, but still appears in the ABI; the stats
+    # output exists only when requested, so trailing refs shift — same
+    # convention as the contiguous decode kernel):
+    #   len, table, q, k*ppp, v*ppp | o, [ml] | acc, m, l
+    len_ref, _table_ref, q_ref = refs[:3]
+    rest = refs[3:]
     k_refs, v_refs = rest[:ppp], rest[ppp:2 * ppp]
     rest = rest[2 * ppp:]
     ml_ref = None
-    if fused:
-        (krow_ref, vrow_ref, kwin_ref, vwin_ref,
-         o_ref, kw_ref, vw_ref, acc_ref, m_ref, l_ref) = rest
-    elif with_stats:
+    if with_stats:
         o_ref, ml_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
@@ -143,23 +141,6 @@ def _kernel(*refs, scale, page, hkv, ppp, hb, with_stats, fused):
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        if fused:
-            # fold the fresh row as one more single-column online step
-            # (cols length..length+sub-1, only col==length unmasked —
-            # the sublane-pad rows of krow score -inf), then merge it
-            # into its pool page: the aliased write block is the page at
-            # position length, row offset length % page replaced. The
-            # write-back DMA lands after this grid row's last program —
-            # the attend stream only ever read rows < length, so order
-            # does not matter.
-            off = length % page
-            sel = jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0) == off
-            for h in range(hb):
-                online_softmax_step(q_ref[h], krow_ref[h], vrow_ref[h],
-                                    length, length + 1, acc_ref.at[h],
-                                    m_ref.at[h], l_ref.at[h], scale)
-                kw_ref[h] = jnp.where(sel, krow_ref[h][:1], kwin_ref[h])
-                vw_ref[h] = jnp.where(sel, vrow_ref[h][:1], vwin_ref[h])
         for h in range(hb):
             # hb == 1 passes the block ref whole: a ``.at[0:1]`` view of
             # a size-1 dim is a "trivial" transform that the
@@ -171,6 +152,18 @@ def _kernel(*refs, scale, page, hkv, ppp, hb, with_stats, fused):
                 mlv = ml_ref if hb == 1 else ml_ref.at[h:h + 1]
                 online_softmax_write_stats(mlv, m_ref.at[h],
                                            l_ref.at[h])
+
+
+def _write_kernel(len_ref, _wpid_ref, krow_ref, vrow_ref, kin_ref,
+                  vin_ref, kout_ref, vout_ref, *, page, nhb, hb):
+    # one program per (row, head block): the block is the row's write
+    # page (the index maps read wpid), and row ``length % page`` of it
+    # is replaced by the fresh row; every other row passes through
+    off = len_ref[pl.program_id(0) // nhb] % page
+    sel = jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0) == off
+    for h in range(hb):
+        kout_ref[h] = jnp.where(sel, krow_ref[h][:1], kin_ref[h])
+        vout_ref[h] = jnp.where(sel, vrow_ref[h][:1], vin_ref[h])
 
 
 def paged_decode_attention_reference(q, k_pages, v_pages, page_table,
@@ -199,12 +192,15 @@ def paged_decode_attention_reference(q, k_pages, v_pages, page_table,
     return o.reshape(b, hq, d).astype(q.dtype)
 
 
+def _sublanes(dtype):
+    return 16 if dtype in (jnp.bfloat16, jnp.float16) else 8
+
+
 def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
                 interpret, return_stats, pages_per_program, head_block,
-                k_row=None, v_row=None, write_pids=None):
-    """Shared call-site builder for the plain and fused paged kernels
-    (fused ⇔ ``k_row`` is given)."""
-    fused = k_row is not None
+                name="paged_decode_attention"):
+    """Call-site builder of the read-only paged attend; ``name`` is the
+    launch's name in a device trace."""
     q = jnp.asarray(q)
     k_pages, v_pages = jnp.asarray(k_pages), jnp.asarray(v_pages)
     b, hq, d = q.shape
@@ -221,11 +217,11 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     ppp, hb = _resolve_config(pages_per_program, head_block, page, hkv,
-                              d, q.dtype, group, max_pages, fused)
+                              d, q.dtype, group, max_pages, False)
     nhb = hkv // hb
     nj = (max_pages + ppp - 1) // ppp
 
-    sub = 16 if q.dtype in (jnp.bfloat16, jnp.float16) else 8
+    sub = _sublanes(q.dtype)
     gp = max(sub, (group + sub - 1) // sub * sub)
     qg = q.reshape(b * hkv, group, d)
     qg = jnp.pad(qg, ((0, 0), (0, gp - group), (0, 0)))
@@ -240,11 +236,11 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
     kp = k_pages.reshape(-1, page, d)
     vp = v_pages.reshape(-1, page, d)
 
-    def bh_index(bh, j, *pref):
+    def bh_index(bh, j, lens, table):
         return (bh, 0, 0)
 
     def kv_index(i):
-        def index(bh, j, lens, table, *maybe_wpid):
+        def index(bh, j, lens, table):
             bb = bh // nhb
             used = jnp.maximum((lens[bb] + page - 1) // page, 1)
             jj = jnp.minimum(j * ppp + i, used - 1)
@@ -258,42 +254,13 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
                  for i in range(ppp)]
     out_specs = [pl.BlockSpec((hb, gp, d), bh_index)]
     out_shape = [jax.ShapeDtypeStruct((b * hkv, gp, d), q.dtype)]
-    operands = [qg] + [kp] * ppp + [vp] * ppp
-
-    if fused:
-        def w_index(bh, j, lens, table, wpids):
-            return (wpids[bh // nhb] * nhb + bh % nhb, 0, 0)
-
-        krow = jnp.asarray(k_row).reshape(b * hkv, 1, d)
-        vrow = jnp.asarray(v_row).reshape(b * hkv, 1, d)
-        krow = jnp.pad(krow, ((0, 0), (0, sub - 1), (0, 0)))
-        vrow = jnp.pad(vrow, ((0, 0), (0, sub - 1), (0, 0)))
-        in_specs += [pl.BlockSpec((hb, sub, d), bh_index),
-                     pl.BlockSpec((hb, sub, d), bh_index),
-                     pl.BlockSpec((hb, page, d), w_index),
-                     pl.BlockSpec((hb, page, d), w_index)]
-        out_specs += [pl.BlockSpec((hb, page, d), w_index),
-                      pl.BlockSpec((hb, page, d), w_index)]
-        out_shape += [jax.ShapeDtypeStruct(kp.shape, kp.dtype),
-                      jax.ShapeDtypeStruct(vp.shape, vp.dtype)]
-        operands += [krow, vrow, kp, vp]
-        prefetch = (lengths, table_flat,
-                    jnp.asarray(write_pids, jnp.int32))
-        # the pool write-view operands alias the pool outputs: the
-        # kernel's page write is in place, untouched pages keep their
-        # input values. Operand numbering counts the scalar-prefetch
-        # refs: 3 prefetch + q + 2*ppp streams + krow/vrow.
-        aliases = {3 + 1 + 2 * ppp + 2: 1, 3 + 1 + 2 * ppp + 3: 2}
-    else:
-        if return_stats:  # stats output only exists when asked for
-            out_specs.append(pl.BlockSpec((hb, gp, _LANES), bh_index))
-            out_shape.append(
-                jax.ShapeDtypeStruct((b * hkv, gp, _LANES), jnp.float32))
-        prefetch = (lengths, table_flat)
-        aliases = {}
+    if return_stats:  # stats output only exists when asked for
+        out_specs.append(pl.BlockSpec((hb, gp, _LANES), bh_index))
+        out_shape.append(
+            jax.ShapeDtypeStruct((b * hkv, gp, _LANES), jnp.float32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
+        num_scalar_prefetch=2,
         grid=(b * nhb, nj),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -308,26 +275,70 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
         # (a tracer here would already fail partial-binding)
         functools.partial(_kernel, scale=float(scale), page=page,
                           hkv=hkv, ppp=ppp, hb=hb,
-                          with_stats=return_stats, fused=fused),
+                          with_stats=return_stats),
         grid_spec=grid_spec,
         out_shape=out_shape,
-        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        name="paged_append_attend" if fused else "paged_decode_attention",
+        name=name,
         interpret=interpret,
-    )(*prefetch, *operands)
+    )(lengths, table_flat, qg, *([kp] * ppp), *([vp] * ppp))
     o = res[0][:, :group, :].reshape(b, hq, d)
-    if fused:
-        kp_out = res[1].reshape(k_pages.shape)
-        vp_out = res[2].reshape(v_pages.shape)
-        return o, kp_out, vp_out
     if not return_stats:
         return o
     ml = res[1]
     m = ml[:, :group, 0].reshape(b, hq)
     l = ml[:, :group, 1].reshape(b, hq)
     return o, m, l
+
+
+def _write_rows(k_pages, v_pages, k_row, v_row, write_pids, lengths, hb,
+                interpret):
+    """The append half of `paged_append_attend`: row b's fresh K/V row
+    replaces row ``lengths[b] % page`` of pool page ``write_pids[b]``,
+    K and V in one launch. Each pool is passed ONCE, its write block
+    aliased in to out — the call's only use of the pool, so XLA leaves
+    the pool where it is."""
+    b, hkv, d = k_row.shape
+    page = k_pages.shape[2]
+    nhb = hkv // hb
+    sub = _sublanes(k_pages.dtype)
+
+    def rows(r):            # (B, Hkv, D) -> sublane-padded row blocks
+        r = jnp.asarray(r).reshape(b * hkv, 1, d)
+        return jnp.pad(r, ((0, 0), (0, sub - 1), (0, 0)))
+
+    def row_index(bh, lens, wpids):
+        return (bh, 0, 0)
+
+    def page_index(bh, lens, wpids):
+        return (wpids[bh // nhb] * nhb + bh % nhb, 0, 0)
+
+    row_spec = pl.BlockSpec((hb, sub, d), row_index)
+    page_spec = pl.BlockSpec((hb, page, d), page_index)
+    kp = k_pages.reshape(-1, page, d)
+    vp = v_pages.reshape(-1, page, d)
+    kp, vp = pl.pallas_call(
+        functools.partial(_write_kernel, page=page, nhb=nhb, hb=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b * nhb,),
+            in_specs=[row_spec, row_spec, page_spec, page_spec],
+            out_specs=[page_spec, page_spec],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(kp.shape, kp.dtype),
+                   jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
+        # operand numbering counts the two scalar-prefetch refs and the
+        # two row operands: pools 4 and 5 alias outputs 0 and 1, so
+        # untouched pages keep their input values
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        name="paged_append_attend_write",
+        interpret=interpret,
+    )(jnp.asarray(lengths, jnp.int32), jnp.asarray(write_pids, jnp.int32),
+      rows(k_row), rows(v_row), kp, vp)
+    return kp.reshape(k_pages.shape), vp.reshape(v_pages.shape)
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
@@ -368,16 +379,17 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
 def paged_append_attend(q, k_pages, v_pages, k_row, v_row, page_table,
                         write_pids, lengths, scale=None, interpret=None,
                         pages_per_program=None, head_block=None):
-    """FUSED append+attend decode step over a paged KV pool.
+    """Append+attend decode step over a paged KV pool, in two launches.
 
-    Attends each row over its prefix [0, lengths[b]) **plus** its fresh
-    KV row (``k_row``/``v_row``, the current token's key/value — folded
-    as one extra online-softmax column inside the kernel), and writes
-    that fresh row into pool page ``write_pids[b]`` at row offset
-    ``lengths[b] % page_size`` in the same kernel launch. The pools are
-    input/output-aliased, so the write touches exactly one page per
-    (row, KV-head) — the separate batched scatter per cache per token
-    the paged engine previously dispatched is gone.
+    First the write kernel (`paged_append_attend_write` in a trace)
+    merges each row's fresh KV row (``k_row``/``v_row``, the current
+    token's key/value) into pool page ``write_pids[b]`` at row offset
+    ``lengths[b] % page_size``, in place: each pool is that call's one
+    pool operand, aliased to its output, so the write touches exactly
+    one page per (row, KV-head) and nothing copies the pool. Then the
+    read-only attend (`paged_append_attend` in a trace) runs over the
+    pools the write returned, each row over ``lengths[b] + 1`` tokens:
+    its prefix plus the row just written, folded in page order.
 
     Args:
       q: (B, Hq, D) current-position queries.
@@ -391,12 +403,33 @@ def paged_append_attend(q, k_pages, v_pages, k_row, v_row, page_table,
       lengths: (B,) int32 prefix lengths; the fresh row lands at
         position lengths[b].
 
-    Returns (o, k_pages, v_pages): o (B, Hq, D) equals a softmax over
-    [prefix + fresh row] (the fused analog of `fold_fresh_row`).
+    Returns (o, k_pages, v_pages): o (B, Hq, D) is a softmax over
+    [prefix + fresh row] for every row whose ``write_pids[b]`` is the
+    table's page at position lengths[b]. A masked-out row (written to
+    a scratch page) attends over its prefix plus whatever its own page
+    holds at that position: finite for a finite pool, and the caller's
+    to discard.
     """
-    return _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
-                       interpret, False, pages_per_program, head_block,
-                       k_row=k_row, v_row=v_row, write_pids=write_pids)
+    q = jnp.asarray(q)
+    k_pages, v_pages = jnp.asarray(k_pages), jnp.asarray(v_pages)
+    hkv, page, d = k_pages.shape[1:]
+    max_pages = page_table.shape[1]
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    # one geometry for both launches (the write's block is the attend's),
+    # from this entry point's own autotune family
+    ppp, hb = _resolve_config(pages_per_program, head_block, page, hkv,
+                              d, q.dtype, q.shape[1] // hkv, max_pages,
+                              True)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    k_pages, v_pages = _write_rows(k_pages, v_pages, k_row, v_row,
+                                   write_pids, lengths, hb, interpret)
+    # a row that is full has no page to take a fresh row, so the clamp
+    # only keeps such a (masked-out) row's page walk inside its table
+    o = _paged_call(q, k_pages, v_pages, page_table,
+                    jnp.minimum(lengths + 1, max_pages * page), scale,
+                    interpret, False, ppp, hb, name="paged_append_attend")
+    return o, k_pages, v_pages
 
 
 def tune_paged_attention(q, k_pages, v_pages, page_table, lengths,

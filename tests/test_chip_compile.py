@@ -21,6 +21,7 @@ cannot be read back without a chip).
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -50,14 +51,20 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, shapes, sharding):
+def _compiled_text(fn, shapes, sharding, donate=()):
     """Compile ``fn`` for the described chip from shapes alone and
-    return the names of the Pallas kernels in the compiled program."""
+    return the compiled program's text."""
     args = jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
         shapes)
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    return jax.jit(fn, donate_argnums=donate).lower(
+        *args).compile().as_text()
+
+
+def _compile(fn, shapes, sharding):
+    """The lines of the Pallas kernels in ``fn``'s compiled program."""
+    return [ln for ln in _compiled_text(fn, shapes, sharding).splitlines()
+            if "tpu_custom_call" in ln]
 
 
 def _sds(shape, dtype=BF16):
@@ -97,8 +104,8 @@ def test_fused_ce_fwd_bwd(one_chip):
         assert _named(calls, name) == 1, name
 
 
-def _paged_shapes():
-    pool = _sds((LAYERS * POOL_PAGES + 1, HEADS, PAGE, HEAD_DIM))
+def _paged_shapes(pool_pages=POOL_PAGES):
+    pool = _sds((LAYERS * pool_pages + 1, HEADS, PAGE, HEAD_DIM))
     q = _sds((SLOTS, HEADS, HEAD_DIM))
     table = _sds((SLOTS, SEQ // PAGE), jnp.int32)
     vec = _sds((SLOTS,), jnp.int32)
@@ -106,12 +113,60 @@ def _paged_shapes():
 
 
 def test_paged_append_attend(one_chip):
+    """Two launches: the in-place row write, then the read-only attend
+    (both carry the ``paged_append_attend`` family name that the
+    benchmark's roofline share sums)."""
     from paddle_tpu.ops.pallas.paged_attention import paged_append_attend
     pool, q, table, vec = _paged_shapes()
     calls = _compile(
         functools.partial(paged_append_attend, interpret=False),
         (q, pool, pool, q, q, table, vec, vec), one_chip)
-    assert _named(calls, "paged_append_attend") == 1
+    assert _named(calls, "paged_append_attend_write") == 1
+    assert _named(calls, "paged_append_attend") == 2
+
+
+@pytest.mark.parametrize("pool_pages", [40, 256])
+def test_decode_dataflow_copies_no_pool(one_chip, pool_pages):
+    """The engine's decode dataflow (`PagedDecodeEngine._multi_impl`
+    over `_one_token`: a scan over layers of `paged_append_attend` with
+    the donated pools as carry, inside a scan over tokens) at XL widths:
+    the compiled program may hold no ``copy`` whose result is a whole
+    pool, in the 4-D shape or the kernels' (N*Hkv, page, d) view. Each
+    such copy cost 2.45 ms a layer at 64 pages (PERF.md section 5)."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_append_attend
+    pool, q, table, vec = _paged_shapes(pool_pages)
+
+    def step(kp, vp, q, k_row, v_row, table, lengths):
+        base = table[:, 0]
+
+        def layer(carry, i):
+            h, kp, vp = carry
+            o, kp, vp = paged_append_attend(
+                q + h, kp, vp, k_row + h, v_row + h,
+                i * pool_pages + table, i * pool_pages + base, lengths,
+                interpret=False)
+            return (o, kp, vp), None
+
+        def token(carry, _):
+            h, kp, vp, lengths = carry
+            (h, kp, vp), _ = jax.lax.scan(layer, (h, kp, vp),
+                                          jnp.arange(LAYERS))
+            return (h, kp, vp, lengths + 1), None
+
+        (h, kp, vp, _), _ = jax.lax.scan(
+            token, (jnp.zeros_like(q), kp, vp, lengths), None, length=2)
+        return h, kp, vp
+
+    text = _compiled_text(step, (pool, pool, q, q, q, table, vec),
+                          one_chip, donate=(0, 1))
+    assert "paged_append_attend_write" in text
+    n = pool.shape[0]
+    pool_shapes = (f"bf16[{n},{HEADS},{PAGE},{HEAD_DIM}]",
+                   f"bf16[{n * HEADS},{PAGE},{HEAD_DIM}]")
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(r"= \S+ copy\(", ln)
+              and any(f"= {sh}" in ln for sh in pool_shapes)]
+    assert not copies, copies
 
 
 def test_paged_decode_attention(one_chip):

@@ -95,12 +95,12 @@ def _scatter_oracle(k, v, k_row, v_row, table, write_pids, lengths,
                                        (1, (2, 2))])
 def test_fused_append_attend_matches_scatter_then_attend(group, cfg):
     """ISSUE 6 tentpole parity: `paged_append_attend` (fresh KV row
-    folded into the online softmax AND written into its pool page
-    inside the kernel) must be bit-compatible with the scatter-then-
-    attend formulation it replaces — both the attention output and the
-    ENTIRE pool (the fused in-kernel write lands exactly one row;
-    untouched pages identical). Covers page-edge lengths (write lands
-    in a fresh page), an empty row (length 0), GQA, and a non-default
+    merged into its pool page by the write launch, then attended with
+    the prefix) must be bit-compatible with the scatter-then-attend
+    formulation it replaces — both the attention output and the ENTIRE
+    pool (the in-place write lands exactly one row; untouched pages
+    identical). Covers page-edge lengths (write lands in a fresh page),
+    an empty row (length 0), GQA, and a non-default
     (pages_per_program, head_block) geometry."""
     rs = np.random.RandomState(11)
     P, hkv, page, d = 10, 2, 128, 32
@@ -152,25 +152,71 @@ def test_fused_append_attend_jit_and_scratch_page():
                                    lengths)
 
     o, k_out, v_out = f(q, k, v, k_row, v_row, table, wpids, lengths)
-    # the kernel ALWAYS folds the fresh row into the softmax (a masked
-    # slot's output is discarded by the engine, but must still be
-    # well-defined): the attention oracle writes each row at its TRUE
-    # position; the pool oracle honors wpids (row 1's write → scratch)
-    tpids = jnp.asarray(
-        [int(table[i, int(lengths[i]) // page]) for i in range(b)],
-        jnp.int32)
-    k3, v3 = _scatter_oracle(k, v, k_row, v_row, table, tpids, lengths,
-                             page)
-    want = paged_decode_attention(q, k3, v3, table, lengths + 1)
-    np.testing.assert_allclose(np.asarray(o), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
     k2, v2 = _scatter_oracle(k, v, k_row, v_row, table, wpids, lengths,
                              page)
     np.testing.assert_array_equal(np.asarray(k_out), np.asarray(k2))
+    np.testing.assert_array_equal(np.asarray(v_out), np.asarray(v2))
+    # the attend reads the pools the write returned, over lengths + 1:
+    # the live row sees its fresh row; the masked row (whose write went
+    # to scratch) sees its own page's stale row at that position — the
+    # engine discards it, but it must still be well-defined
+    want = paged_decode_attention(q, k2, v2, table, lengths + 1)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+    assert np.isfinite(np.asarray(o)).all()
     # row 1's own pages untouched (its write went to scratch)
     for pid in (2, 4):
         np.testing.assert_array_equal(np.asarray(k_out[pid]),
                                       np.asarray(k[pid]))
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("rem", [0, 1, 127])
+def test_append_attend_page_offsets_and_idle_slot(rem, group, d):
+    """The write launch at the offsets that matter (``lengths % page``
+    0: the row opens a new page; 1; page - 1: the page's last row),
+    beside an IDLE slot as the engine presents one: its table row is
+    zeros (pool page 0, which the first live row owns) and its write is
+    pointed at the scratch page. Pools bit for bit against the scatter;
+    page 0 unchanged; the two write pages and the scratch page the only
+    pages touched; the live rows' outputs equal scatter-then-attend."""
+    rs = np.random.RandomState(100 + rem + group + d)
+    P, hkv, page = 9, 2, 128
+    scratch = P - 1
+    k, v = _pool(rs, P, hkv, page, d)
+    b = 3
+    q = jnp.asarray(rs.randn(b, hkv * group, d), jnp.float32)
+    table = jnp.asarray([[0, 5], [7, 1], [0, 0]], jnp.int32)
+    # row 0: a full page 0, then ``rem`` rows of page 5; row 1: ``rem``
+    # rows of page 7 (rem 0: an empty row); row 2 idle
+    lengths = jnp.asarray([page + rem, rem, page + rem], jnp.int32)
+    k_row = jnp.asarray(rs.randn(b, hkv, d), jnp.float32)
+    v_row = jnp.asarray(rs.randn(b, hkv, d), jnp.float32)
+    wpids = jnp.asarray([5, 7, scratch], jnp.int32)
+
+    o, k_out, v_out = jax.jit(paged_append_attend)(
+        q, k, v, k_row, v_row, table, wpids, lengths)
+
+    k2, v2 = _scatter_oracle(k, v, k_row, v_row, table, wpids, lengths,
+                             page)
+    np.testing.assert_array_equal(np.asarray(k_out), np.asarray(k2))
+    np.testing.assert_array_equal(np.asarray(v_out), np.asarray(v2))
+    for got, was in ((k_out, k), (v_out, v)):
+        touched = {pid for pid in range(P)
+                   if not np.array_equal(np.asarray(got[pid]),
+                                         np.asarray(was[pid]))}
+        assert touched == {5, 7, scratch}
+        # exactly one row of each written page changed
+        for pid in (5, 7, scratch):
+            diff = np.any(np.asarray(got[pid]) != np.asarray(was[pid]),
+                          axis=(0, 2))
+            assert np.flatnonzero(diff).tolist() == [rem]
+    want = paged_decode_attention(q, k2, v2, table, lengths + 1)
+    np.testing.assert_allclose(np.asarray(o[:2]), np.asarray(want[:2]),
+                               atol=1e-5, rtol=1e-5)
+    # the idle slot's output is thrown away by the engine, and finite
+    assert np.isfinite(np.asarray(o)).all()
 
 
 def test_paged_autotune_cache_roundtrip(tmp_path, monkeypatch):

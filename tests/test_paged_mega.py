@@ -7,15 +7,16 @@ The invariants:
   rope) — the megakernel is an execution-plan change, not a math
   change;
 - within one step the KV pools match the reference bit-exactly at
-  layer 0 and to float-ulp order at layers >= 1 (the mega kernel folds
-  the fresh KV row in page order, the per-layer kernel folds it last —
-  same set of numbers, different fold order);
+  layer 0 and to float-ulp order at layers >= 1 (both fold the fresh
+  KV row in page order, the per-layer path after writing it into its
+  page, but the two accumulate in differently shaped tiles — same set
+  of numbers, different float-addition order);
 - an INACTIVE slot's writes land in the scratch page only: its mapped
   pages stay bit-identical;
 - the dispatch program lowers to <= 2 pallas launches per decode step
   (layer-folded kernel + sampling epilogue) on the plain AND
-  speculative paths, while the per-layer reference pays one per layer
-  — counted from the AOT jaxpr, so the assert is backend-independent;
+  speculative paths, while the per-layer reference pays two per layer
+  (the row write, then the attend) — counted from the AOT jaxpr, so the assert is backend-independent;
 - warm prefix admission, poison eviction and pipelined depth-2 all
   behave identically to the per-layer path.
 """
@@ -199,7 +200,7 @@ def test_mega_inactive_slot_writes_scratch_only():
 def test_mega_launch_counts():
     """Acceptance: the fused paged decode step lowers to <= 2 kernel
     launches per step (megakernel + epilogue) — plain AND speculative —
-    vs one per layer on the reference path. Counted from the dispatch
+    vs two per layer (row write + attend) on the reference path. Counted from the dispatch
     program's jaxpr (scan-trip weighted), so the assert holds on any
     backend; the model has 3 layers so the counts cannot coincide."""
     from paddle_tpu.observability import devprof
@@ -213,7 +214,7 @@ def test_mega_launch_counts():
 
     assert per_step(mega=True) == 2
     assert per_step(mega=True, speculative_k=3) == 2
-    assert per_step(mega=False) == model.cfg.n_layers
+    assert per_step(mega=False) == 2 * model.cfg.n_layers
 
 
 def test_mega_hlo_custom_call_count_is_countable():
