@@ -103,7 +103,8 @@ def resolve_engine_weights(model, share_weights_with):
     """The ONE donor-or-build protocol shared by the contiguous and the
     paged engines: returns (cfg, head dict, scan-stacked blocks). With a
     donor, weights alias the donor's (no second copy); otherwise they
-    are built from ``model`` (which must be a dense stack)."""
+    are built from ``model`` (which must be a dense stack; its blocks
+    are stacked here unless the model carries them stacked)."""
     if model is None:
         if share_weights_with is None:
             raise ValueError(
@@ -127,8 +128,13 @@ def resolve_engine_weights(model, share_weights_with):
             "lnf_scale": model.lnf_scale,
             "lnf_bias": model.lnf_bias,
             "lm_head": model.lm_head}
-    stacked = gpt_lib.stack_block_weights(
-        [model.blocks[i] for i in range(cfg.n_layers)])
+    # a model whose blocks are already stacked (a state built by
+    # init_train_state(stacked=True), or weights loaded that way) is
+    # served from that stack: no second copy of every block weight
+    stacked = getattr(model, "_stacked_blocks", None)
+    if stacked is None:
+        stacked = gpt_lib.stack_block_weights(
+            [model.blocks[i] for i in range(cfg.n_layers)])
     return cfg, head, stacked
 
 

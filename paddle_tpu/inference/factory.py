@@ -4,10 +4,10 @@ PAGED is the default serving engine for the front-end and the bench
 ladder (its decode step is the per-layer fused append+attend path; the
 single-dispatch megakernel is opt-in, see docs/serving.md). The
 slot-contiguous `DecodeEngine` stays available behind
-``PT_SERVE_ENGINE=contiguous`` (or ``engine="contiguous"``): it still
-serves prompts longer than the paged prefill's largest bucket, and it
-is the sampling-policy surface (temperature/top-k live there). Which
-engine is faster on the chip: not measured since PR 6.
+``PT_SERVE_ENGINE=contiguous`` (or ``engine="contiguous"``): it is
+the sampling-policy surface (temperature/top-k live there), for
+models whose layers keep keys and values. Which engine is faster on
+the chip: not measured since PR 6.
 
 ``make_engine(model)`` is the one construction path the serving
 front-end, the smoke tools and the bench ladder share — flipping the
@@ -20,6 +20,7 @@ from typing import Optional
 
 from paddle_tpu.inference.decode_engine import DecodeEngine
 from paddle_tpu.inference.paged_engine import PagedDecodeEngine
+from paddle_tpu.models import layer_kinds
 
 __all__ = ["make_engine", "default_engine_kind"]
 
@@ -42,8 +43,12 @@ def make_engine(model, engine: Optional[str] = None, *,
     """Build the serving engine for ``model``: ``engine`` (explicit)
     beats ``PT_SERVE_ENGINE`` beats the paged default.
 
-    Paged sizing default: enough pages for every slot to hold a
-    full-length sequence (``max_slots * ceil(max_len / page_size)``) —
+    Paged sizing default, from the layers that keep pages
+    (`models/layer_kinds.py`): enough pages for every slot to hold a
+    full-length sequence (``max_slots * ceil(max_len / page_size)``),
+    and none for a model whose layers keep a state per sequence
+    instead (the engine then holds a state pool of ``max_slots``
+    slots and prefills prompts of any length in chunks) —
     the no-surprises envelope; real deployments size the pool to the
     LIVE-token budget instead (that over-commit is the engine's whole
     point) and pass ``n_pages`` explicitly. The decode step's cost does
@@ -60,7 +65,8 @@ def make_engine(model, engine: Optional[str] = None, *,
     cap = max_len or model.cfg.max_seq_len
     if kind == "paged":
         if n_pages is None:
-            n_pages = max_slots * math.ceil(cap / page_size)
+            n_pages = (max_slots * math.ceil(cap / page_size)
+                       if layer_kinds.kind_of(model.cfg).pages else 0)
         return PagedDecodeEngine(
             model, n_pages=n_pages, max_slots=max_slots,
             page_size=page_size, steps_per_call=steps_per_call, **kw)

@@ -52,9 +52,19 @@ TPU design decisions:
 - **One-pass bucketed prefill**: a prompt attends only to itself
   (causal), so prefill needs NO cache reads — the whole prompt runs
   through the dense forward at a power-of-two bucket and the valid KV
-  rows bulk-write into the sequence's pages per page-run. Prompts are
-  therefore capped at the largest bucket (512) — longer prompts belong
-  to the slot-contiguous `DecodeEngine`, which chunk-prefills.
+  rows bulk-write into the sequence's pages per page-run. Prompts of
+  key/value layers are therefore capped at the largest bucket (512).
+- **Layer kinds** (`models/layer_kinds.py`): the engine reads from the
+  model's kind what state a layer keeps. Softmax attention keeps pages;
+  a kind that keeps a state of fixed size per SEQUENCE (power
+  retention) gets a per-slot state pool beside the page pool, or in its
+  place (``n_pages`` 0 is legal when no layer keeps pages): bound with
+  the slot, zeroed by the first prefill chunk, freed with the slot.
+  Such a kind prefills prompts of ANY length up to the context in
+  chunks of ``prefill_chunk`` tokens that carry the state — one
+  compiled program, the last chunk padded and masked, at most one
+  chunk between two decode steps; a slot still in prefill is not
+  active in the decode step and its state is not touched by it.
 - **Chunked device-side stepping**: like `DecodeEngine`, ``chunk``
   tokens per dispatch with per-slot eos/budget early-stop; pages for
   the whole chunk are reserved up front so the table is static inside
@@ -85,6 +95,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.models import gpt as gpt_lib
+from paddle_tpu.models import layer_kinds
 from paddle_tpu.inference.decode_engine import (Request,
                                                 ResilientScheduler,
                                                 _Inflight,
@@ -96,8 +107,7 @@ from paddle_tpu.ops.pallas.decode_attention import fold_fresh_row
 from paddle_tpu.ops.pallas.decode_megakernel import (_WEIGHT_ORDER,
                                                      mega_decode_layers,
                                                      mega_logits_sample)
-from paddle_tpu.ops.pallas.paged_attention import (paged_append_attend,
-                                                   paged_decode_attention)
+from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
 
 __all__ = ["PagedDecodeEngine"]
 
@@ -138,7 +148,8 @@ class PagedDecodeEngine(ResilientScheduler):
                  prefix: Optional[bool] = None,
                  prefill_only: bool = False,
                  mega: Optional[bool] = None,
-                 speculative_k: int = 0):
+                 speculative_k: int = 0,
+                 prefill_chunk: int = 512):
         from paddle_tpu import compile_cache
         from paddle_tpu.inference.decode_engine import (
             resolve_engine_weights)
@@ -148,6 +159,11 @@ class PagedDecodeEngine(ResilientScheduler):
         if page_size % 128:
             raise ValueError("page_size must be a multiple of 128")
         self.cfg = cfg
+        self.kind = layer_kinds.kind_of(cfg)
+        if not self.kind.pages and n_pages:
+            raise ValueError(
+                f"no layer of this model keeps pages ({self.kind.name} "
+                f"layers): n_pages must be 0, got {n_pages}")
         self.S = int(max_slots)
         self.page = int(page_size)
         self.P = int(n_pages)
@@ -172,6 +188,16 @@ class PagedDecodeEngine(ResilientScheduler):
         self.kp = jnp.zeros(shape, cfg.dtype)
         self.vp = jnp.zeros(shape, cfg.dtype)
         self._scratch = L * self.P
+        # per-sequence state pools of the kind (none for softmax
+        # attention), every layer and slot in one array each; the
+        # chunked prefill and the decode step update them in place
+        self.state = {name: jnp.zeros(sds.shape, sds.dtype) for name, sds
+                      in self.kind.slot_state(cfg, self.S).items()}
+        self.prefill_chunk = int(prefill_chunk)
+        # slots mid-prompt, oldest admission first: slot -> [prompt,
+        # tokens prefilled so far]
+        self._prefilling: dict = {}
+        self._step_prefill_tokens = self._step_decode_tokens = 0
         from paddle_tpu.ops.pallas.paged_attention import PageAllocator
         self._alloc = PageAllocator(self.P, self.page)
         # fused append+attend is the default; PT_PAGED_FUSED=0 restores
@@ -199,6 +225,11 @@ class PagedDecodeEngine(ResilientScheduler):
                              "path (fused=False / PT_PAGED_FUSED=0 "
                              "excludes it)")
         self.mega = bool(mega)
+        if self.state and (self.mega or not self.fused or speculative_k):
+            raise NotImplementedError(
+                f"{self.kind.name} layers are served by the per-layer "
+                f"fused step only (no megakernel, unfused or "
+                f"speculative path)")
         # speculative decode rides the paged step (r05 retired the
         # contiguous-only row): drafts come from the shared on-device
         # prompt-lookup helper, and with mega on, verify/accept run as
@@ -209,8 +240,9 @@ class PagedDecodeEngine(ResilientScheduler):
                              "token + at least one candidate)")
         prefix_on = (os.environ.get("PT_PAGED_PREFIX", "1") != "0"
                      if prefix is None else bool(prefix))
+        # (a prefix of per-sequence state is not shared: ROADMAP M4)
         self._prefix = (PrefixCache(self._alloc, self.page)
-                        if prefix_on else None)
+                        if prefix_on and self.kind.pages else None)
         # disaggregated serving (docs/serving.md): a prefill-only
         # engine admits + prefills but never activates decode — the
         # finished pages leave via detach_handoff; fleet is an optional
@@ -253,7 +285,10 @@ class PagedDecodeEngine(ResilientScheduler):
                                    donate_argnums=(2, 3))
         self._prefill_sfx_fn = jax.jit(self._prefill_suffix_impl,
                                        donate_argnums=(2, 3))
-        self._multi_fn = jax.jit(self._multi_impl, donate_argnums=(2, 3))
+        self._multi_fn = jax.jit(self._multi_impl,
+                                 donate_argnums=(2, 3, 4))
+        self._chunk_fn = jax.jit(self._prefill_chunk_impl,
+                                 donate_argnums=(2, 3, 4, 5, 6, 7))
         # table (arg 4) is NEVER donated: the cached device copy
         # (_table_dev) is reused across dispatches
         self._verify_fn = jax.jit(self._spec_multi_impl,
@@ -290,6 +325,19 @@ class PagedDecodeEngine(ResilientScheduler):
                     * self.page * self.cfg.head_dim
                     * np.dtype(self.kp.dtype).itemsize)
         return sum(len(t) for t in self._tables) * per_page
+
+    @property
+    def state_slots(self) -> int:
+        """Sequences holding per-sequence state (bound slots, where
+        the layers keep any)."""
+        return (sum(r is not None for r in self._slot_req)
+                if self.state else 0)
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of per-sequence state those sequences hold."""
+        per_slot = sum(a.nbytes for a in self.state.values()) // self.S
+        return self.state_slots * per_slot
 
     def _update_pool_gauges(self):
         from paddle_tpu import stats
@@ -425,7 +473,8 @@ class PagedDecodeEngine(ResilientScheduler):
     # -- jitted bodies ------------------------------------------------------
 
     def _lm_head(self, head, x):
-        x = gpt_lib.final_ln(x, head["lnf_scale"], head["lnf_bias"])
+        x = gpt_lib.final_ln(x, head["lnf_scale"], head["lnf_bias"],
+                             self.cfg.norm_eps, self.cfg.rms_norm)
         w = (head["wte"].T if head["lm_head"] is None
              else head["lm_head"])
         return x @ w
@@ -456,22 +505,26 @@ class PagedDecodeEngine(ResilientScheduler):
             v_rows.reshape(L * S, *v_rows.shape[2:]))
         return kp, vp
 
-    def _one_token(self, head, stacked, kp, vp, table, lengths, last,
-                   active, poison):
+    def _one_token(self, head, stacked, kp, vp, state, table, lengths,
+                   last, active, poison):
         """Advance every active slot one token. Per-slot ``bad`` flags
         non-finite logits (numerical blowup or injected poison) — the
         slot stops advancing and the host evicts only that request.
 
-        FUSED path (default): each layer calls `paged_append_attend` —
-        its write launch merges the fresh KV row into its pool page in
-        place (the pools, carried through the layer scan, are that
+        FUSED path (default): each layer runs its kind's ``step``
+        (`models/layer_kinds.py`) with the pools as the layer scan's
+        carry. Softmax layers call `paged_append_attend` — its write
+        launch merges the fresh KV row into its pool page in
+        place (the pools are that
         call's only pool operands; inactive slots' writes target the
         scratch page), then its read-only attend runs over the
         returned pools at ``lengths + 1``. No per-token scatter and no
         pool copy remain in the dispatch. An inactive slot attends a
         row nobody wrote (its own page's stale row at ``lengths``):
         its output is finite garbage that ``nxt``/``bad`` below mask
-        by ``active``.
+        by ``active``. Retention layers call `retention_step`, which
+        updates and reads the per-slot state pools the same way (each
+        handed in once, aliased in to out) and skips inactive slots.
 
         Fallback (``PT_PAGED_FUSED=0``): the pools stay READ-ONLY
         inside the layer scan — `paged_decode_attention(return_stats)`
@@ -488,26 +541,23 @@ class PagedDecodeEngine(ResilientScheduler):
 
         if self.fused:
             pidx = jnp.minimum(lengths // self.page, table.shape[1] - 1)
-            base = jnp.take_along_axis(table, pidx[:, None],
-                                       axis=1)[:, 0]
+            view = {"table": table, "n_pages": self.P,
+                    "scratch": self._scratch,
+                    "base": jnp.take_along_axis(table, pidx[:, None],
+                                                axis=1)[:, 0]}
 
             def layer_body_fused(carry, blk_i):
-                h, kp, vp = carry
+                h, pools = carry
                 blk, i = blk_i
-                q, k, v = blk._qkv(h, lengths)
-                k_row = k[:, 0].astype(kp.dtype)
-                v_row = v[:, 0].astype(vp.dtype)
-                wpids = jnp.where(active, i * self.P + base,
-                                  self._scratch)
-                o, kp, vp = paged_append_attend(
-                    q[:, 0].astype(kp.dtype), kp, vp, k_row, v_row,
-                    i * self.P + table, wpids, lengths, scale=scale)
-                attn = o.astype(h.dtype).reshape(h.shape)
-                h = blk._block_tail(h, attn)
-                return (h, kp, vp), None
+                attn, pools = self.kind.step(blk, i, h, lengths, active,
+                                             pools, view)
+                return (blk._block_tail(h, attn), pools), None
 
-            (x, kp, vp), _ = lax.scan(layer_body_fused, (x, kp, vp),
-                                      (stacked, jnp.arange(L)))
+            (x, pools), _ = lax.scan(
+                layer_body_fused, (x, dict(state, kp=kp, vp=vp)),
+                (stacked, jnp.arange(L)))
+            kp, vp = pools.pop("kp"), pools.pop("vp")
+            state = pools
         else:
             def layer_body(h, blk_i):
                 blk, i = blk_i
@@ -533,7 +583,7 @@ class PagedDecodeEngine(ResilientScheduler):
         nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(jnp.int32)
         nxt = jnp.where(active & ~bad, nxt, last)
         lengths = lengths + (active & ~bad).astype(jnp.int32)
-        return kp, vp, lengths, nxt, bad
+        return kp, vp, state, lengths, nxt, bad
 
     def _mega_rows(self, head, stacked, kp, vp, table, pos, row_slot,
                    row_write, tokens, poison_rows):
@@ -562,8 +612,8 @@ class PagedDecodeEngine(ResilientScheduler):
             layers=cfg.n_layers, page=self.page)
         return kp, vp, tok, nf
 
-    def _one_token_mega(self, head, stacked, kp, vp, table, lengths,
-                        last, active, poison):
+    def _one_token_mega(self, head, stacked, kp, vp, state, table,
+                        lengths, last, active, poison):
         """Single-dispatch variant of `_one_token` (``PT_PAGED_MEGA``):
         same signature, same greedy stream, ≤2 kernel launches. The
         per-layer fused path above stays as the parity reference
@@ -577,10 +627,10 @@ class PagedDecodeEngine(ResilientScheduler):
         bad = active & (nf > 0)
         nxt = jnp.where(active & ~bad, tok, last)
         lengths = lengths + (active & ~bad).astype(jnp.int32)
-        return kp, vp, lengths, nxt, bad
+        return kp, vp, state, lengths, nxt, bad
 
-    def _multi_impl(self, head, stacked, kp, vp, table, lengths, last,
-                    active, remaining, eos, poison):
+    def _multi_impl(self, head, stacked, kp, vp, state, table, lengths,
+                    last, active, remaining, eos, poison):
         """``chunk`` decode steps in one dispatch, per-slot eos/budget/
         non-finite early-stop device-side (pages for the whole chunk are
         reserved before the dispatch, so ``table`` is static here).
@@ -591,23 +641,24 @@ class PagedDecodeEngine(ResilientScheduler):
         one_tok = self._one_token_mega if self.mega else self._one_token
 
         def one(carry, _):
-            kp, vp, lengths, last, active, remaining = carry
-            kp, vp, lengths, nxt, bad = one_tok(
-                head, stacked, kp, vp, table, lengths, last, active,
-                poison)
+            kp, vp, state, lengths, last, active, remaining = carry
+            kp, vp, state, lengths, nxt, bad = one_tok(
+                head, stacked, kp, vp, state, table, lengths, last,
+                active, poison)
             emit = active & ~bad
             remaining = remaining - emit.astype(jnp.int32)
             hit_eos = (nxt == eos) & (eos >= 0)
             active = active & ~bad & ~hit_eos & (remaining > 0)
-            return (kp, vp, lengths, nxt, active, remaining), \
+            return (kp, vp, state, lengths, nxt, active, remaining), \
                 (nxt, emit, bad)
 
-        (kp, vp, lengths, last, active, remaining), (toks, flags, bads) = \
-            lax.scan(one, (kp, vp, lengths, last, active, remaining),
-                     None, length=self.chunk)
+        (kp, vp, state, lengths, last, active, remaining), \
+            (toks, flags, bads) = lax.scan(
+                one, (kp, vp, state, lengths, last, active, remaining),
+                None, length=self.chunk)
         packed = jnp.stack([toks, flags.astype(jnp.int32),
                             bads.astype(jnp.int32)])
-        return kp, vp, lengths, last, active, remaining, packed
+        return kp, vp, state, lengths, last, active, remaining, packed
 
     def _verify_paged(self, head, stacked, kp, vp, table, lengths,
                       cand, active, poison):
@@ -735,11 +786,8 @@ class PagedDecodeEngine(ResilientScheduler):
         rows = []
 
         def layer_body(h, blk):
-            q, k, v = blk._qkv(h, jnp.zeros((1,), jnp.int32))
-            attn = gpt_lib.F.scaled_dot_product_attention(
-                q, k, v, is_causal=True, dropout_p=0.0)
-            attn = attn.reshape(h.shape).astype(h.dtype)
-            return blk._block_tail(h, attn), (k[0], v[0])
+            attn, rows = self.kind.prefill(blk, h)
+            return blk._block_tail(h, attn), rows
 
         x, (ks, vs) = lax.scan(layer_body, x, stacked)
         # ks: (L, bucket, Hkv, D) -> (L, Hkv, bucket, D); pad the token
@@ -917,17 +965,63 @@ class PagedDecodeEngine(ResilientScheduler):
             jnp.int32)[0]
         return kp, vp, nxt
 
+    def _prefill_chunk_impl(self, head, stacked, state, lengths, last,
+                            active, remaining, eos_ids, tokens, pos0,
+                            n_valid, slot, final, rem0, eos0):
+        """One chunk (1, prefill_chunk) of slot ``slot``'s prompt
+        through layers that keep per-sequence state: positions from
+        ``pos0``, the first ``n_valid`` tokens real. The chunk at
+        position 0 starts from a zero state (that is how admission
+        zeroes the slot), every other from the slot's own. ONE program
+        for every chunk of every prompt: the scalars are traced. The
+        ``final`` chunk also samples the first token and flips the
+        slot's decode state on device (length, pending token, budget,
+        eos, active), so admission runs no eager program of its own."""
+        _note_retrace("paged_prefill_chunk")
+        x = jnp.take(head["wte"], tokens, axis=0)
+        if head["wpe"] is not None:
+            pos = jnp.clip(pos0 + jnp.arange(tokens.shape[1]), 0,
+                           head["wpe"].shape[0] - 1)
+            x = x + jnp.take(head["wpe"], pos, axis=0)[None]
+
+        def layer_body(carry, blk_i):
+            h, pools = carry
+            blk, i = blk_i
+            attn, pools = self.kind.prefill(blk, i, h, pos0, n_valid,
+                                            slot, pos0 == 0, pools)
+            return (blk._block_tail(h, attn), pools), None
+
+        (x, state), _ = lax.scan(
+            layer_body, (x, state),
+            (stacked, jnp.arange(self.cfg.n_layers)))
+        idx = jnp.clip(n_valid - 1, 0, tokens.shape[1] - 1)
+        logits = self._lm_head(head, lax.dynamic_slice_in_dim(
+            x, idx, 1, axis=1))[:, 0]
+        nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(
+            jnp.int32)[0]
+        # a budget-of-one request (or one whose first token is eos)
+        # never activates; a prefill-only engine never decodes
+        alive = (final & (rem0 > 0) & ((eos0 < 0) | (nxt != eos0))
+                 & (not self.prefill_only))
+        put = lambda arr, new: arr.at[slot].set(
+            jnp.where(final, new, arr[slot]))
+        return (state, put(lengths, pos0 + n_valid), put(last, nxt),
+                active.at[slot].set(alive), put(remaining, rem0),
+                put(eos_ids, eos0), nxt)
+
     # -- scheduler ----------------------------------------------------------
 
     def check_request(self, prompt_len: int, max_new_tokens: int):
         """Admission feasibility (see DecodeEngine.check_request)."""
         if prompt_len < 1:
             raise ValueError("empty prompt")
-        if prompt_len > self.buckets[-1]:
+        if (not self.kind.chunked_prefill
+                and prompt_len > self.buckets[-1]):
             raise ValueError(
-                f"paged prefill caps prompts at {self.buckets[-1]} "
-                f"tokens (got {prompt_len}); use DecodeEngine for "
-                f"longer prompts")
+                f"{self.kind.name} layers prefill a prompt in one pass "
+                f"of at most {self.buckets[-1]} tokens (got "
+                f"{prompt_len}); only layers that keep per-sequence "
+                f"state prefill in chunks")
         if prompt_len + max_new_tokens > self.cfg.max_seq_len:
             raise ValueError("prompt + new tokens exceed max_seq_len")
         if self.spec_k and (prompt_len + max_new_tokens
@@ -961,8 +1055,11 @@ class PagedDecodeEngine(ResilientScheduler):
 
     def _on_evict(self, slot: int):
         """Eviction also returns the slot's pages to the pool (the dead
-        sequence's memory is reclaimable at once)."""
+        sequence's memory is reclaimable at once) and stops a prompt
+        mid-prefill; the slot's state is zeroed by whoever is admitted
+        into it next."""
         self._release(slot)
+        self._prefilling.pop(slot, None)
         super()._on_evict(slot)
 
     def _fail(self, req, reason, slot=None,
@@ -1208,6 +1305,12 @@ class PagedDecodeEngine(ResilientScheduler):
         # (submit coerced it); this is an upload, never a sync
         prompt = np.asarray(req.prompt, np.int32)
         n = len(prompt)
+        if self.kind.chunked_prefill:
+            with trace.span("serve/admit", slot=slot, prompt=n,
+                            bucket=self.prefill_chunk, cached=0,
+                            rid=req.rid):
+                self._admit_chunked(req, slot, prompt)
+            return
         sp, cow_src, chain = (self._match_prefix(prompt, slot)
                               if self._prefix is not None
                               else (0, -1, None))
@@ -1221,6 +1324,64 @@ class PagedDecodeEngine(ResilientScheduler):
                         bucket=bucket, cached=sp, rid=req.rid):
             self._admit_reserved(req, slot, prompt, bucket, sp, cow_src,
                                  chain)
+
+    def _admit_chunked(self, req, slot, prompt):
+        """Bind ``req`` to ``slot`` and queue its prompt for the chunked
+        prefill: no page is reserved and nothing is dispatched here.
+        The slot holds state from now on; its first chunk starts from
+        zero, and until its last chunk it is not active on the device,
+        so no decode step touches it."""
+        from paddle_tpu.observability import flight, trace
+        trace.complete("serve/queue", req.t_submit, rid=req.rid,
+                       slot=slot)
+        flight.record(req.rid, "admit", slot=slot, prompt=len(prompt),
+                      bucket=self.prefill_chunk, cached=0)
+        self._slot_req[slot] = req
+        self._host_len[slot] = self._proj_len[slot] = 0
+        self._disp_rem[slot] = 0
+        self._prefilling[slot] = [prompt, 0]
+
+    def _prefill_one_chunk(self):
+        """Dispatch the next chunk of the oldest prompt still in
+        prefill (at most one chunk between two decode steps, so that a
+        long prompt does not stall the slots that decode). The final
+        chunk's sampled token rides the harvest queue as a 'prefill'
+        record, like a one-pass prefill's."""
+        import time
+        from paddle_tpu import stats
+        from paddle_tpu.observability import trace
+        self._step_prefill_tokens = 0
+        if not self._prefilling:
+            return
+        slot, (prompt, done) = next(iter(self._prefilling.items()))
+        req = self._slot_req[slot]
+        C, n = self.prefill_chunk, len(prompt)
+        take = min(C, n - done)
+        final = done + take == n
+        tokens = np.zeros((1, C), np.int32)
+        tokens[0, :take] = prompt[done:done + take]
+        rem0 = req.max_new_tokens - 1
+        eos0 = -1 if req.eos_id is None else int(req.eos_id)
+        stats.add("serve/dispatch_launches")
+        stats.add("serve/dispatches/prefill_chunk")
+        with trace.span("serve/prefill_chunk", slot=slot, rid=req.rid,
+                        tokens=take, index=done // C, last=final):
+            (self.state, self.lengths, self.last, self.active,
+             self.remaining, self.eos_ids, nxt) = self._chunk_fn(
+                self._head, self._stacked, self.state, self.lengths,
+                self.last, self.active, self.remaining, self.eos_ids,
+                jnp.asarray(tokens), jnp.int32(done), jnp.int32(take),
+                jnp.int32(slot), jnp.bool_(final), jnp.int32(rem0),
+                jnp.int32(eos0))
+        self._step_prefill_tokens = take
+        if not final:
+            self._prefilling[slot][1] = done + take
+            return
+        del self._prefilling[slot]
+        self._host_len[slot] = self._proj_len[slot] = n
+        self._disp_rem[slot] = 0 if self.prefill_only else rem0
+        self._pending.append(_Inflight("prefill", [(slot, req)], nxt,
+                                       time.perf_counter()))
 
     def _admit_reserved(self, req, slot, prompt, bucket, sp, cow_src,
                         chain):
@@ -1357,6 +1518,12 @@ class PagedDecodeEngine(ResilientScheduler):
 
     # -- disaggregated handoff (docs/serving.md "Disaggregated serving") ----
 
+    def _refuse_state_handoff(self):
+        if self.state:
+            raise NotImplementedError(
+                f"a {self.kind.name} layer's per-sequence state has no "
+                f"wire form yet (ROADMAP M4: snapshots of state)")
+
     def detach_handoff(self, req: Request):
         """Extract a request's KV pages + decode state and retire it
         locally WITHOUT finishing — the sending half of both handoff
@@ -1376,6 +1543,7 @@ class PagedDecodeEngine(ResilientScheduler):
         Hkv, page, D) host arrays of the slot's pages (tail rows past
         ``n_tokens`` are recycled-pool garbage — the wire codec zeroes
         them; decode overwrites before reading either way)."""
+        self._refuse_state_handoff()
         if req.failed:
             raise ValueError(f"request failed before detach: {req.error}")
         if not req.tokens:
@@ -1443,6 +1611,7 @@ class PagedDecodeEngine(ResilientScheduler):
         bit-identity contract); the last sender-emitted token rides
         the harvest queue like any local prefill's first token."""
         import time
+        self._refuse_state_handoff()
         req = _HandoffRequest(
             meta["prompt"], meta["max_new_tokens"], meta["eos_id"],
             deadline=(None if deadline_s is None
@@ -1610,6 +1779,13 @@ class PagedDecodeEngine(ResilientScheduler):
                     if r is not None)
                 sp.attrs["pages_used"] = self.P - self.free_pages
                 sp.attrs["pages"] = self.P
+                # sequences holding per-sequence state and its bytes;
+                # slots mid-prompt; what this step dispatched
+                sp.attrs["state_slots"] = self.state_slots
+                sp.attrs["state_bytes"] = self.state_bytes
+                sp.attrs["prefilling"] = len(self._prefilling)
+                sp.attrs["prefill_tokens"] = self._step_prefill_tokens
+                sp.attrs["decode_tokens"] = self._step_decode_tokens
         if n_live or n:
             # idle polls record nothing (matching DecodeEngine): zero
             # occupancy/queue samples from an empty engine would read
@@ -1624,6 +1800,7 @@ class PagedDecodeEngine(ResilientScheduler):
         the obs hooks."""
         self._evict_expired()
         self._admit_waiting()
+        self._prefill_one_chunk()
         self._pump(self._dispatch_decode())
         live = sum(r is not None for r in self._slot_req)
         sp.attrs["active"] = live
@@ -1692,10 +1869,12 @@ class PagedDecodeEngine(ResilientScheduler):
                     if r is not None and self._disp_rem[s] > 0]
 
         live = _live()
+        self._step_decode_tokens = len(live) * self.chunk
         if not live:
             return False
         try:
-            self._reserve_chunk(live)
+            if self.kind.pages:
+                self._reserve_chunk(live)
         except MemoryError:
             # pool pressure: retired pages may sit in unharvested
             # dispatches — drain, re-anchor the shadows, retry once
@@ -1723,11 +1902,12 @@ class PagedDecodeEngine(ResilientScheduler):
             with trace.span("serve/dispatch", kind="paged",
                             chunk=self.chunk,
                             inflight=len(self._pending)):
-                (self.kp, self.vp, self.lengths, self.last, self.active,
-                 self.remaining, packed) = self._multi_fn(
+                (self.kp, self.vp, self.state, self.lengths, self.last,
+                 self.active, self.remaining, packed) = self._multi_fn(
                     self._head, self._stacked, self.kp, self.vp,
-                    self._table(), self.lengths, self.last, self.active,
-                    self.remaining, self.eos_ids, self._poison_mask())
+                    self.state, self._table(), self.lengths, self.last,
+                    self.active, self.remaining, self.eos_ids,
+                    self._poison_mask())
             kind = "decode"
         for s, _ in live:
             self._proj_len[s] += self._disp_span
@@ -1761,8 +1941,19 @@ class PagedDecodeEngine(ResilientScheduler):
         from paddle_tpu import stats
         t0 = time.perf_counter()
         kp, vp = jnp.zeros_like(self.kp), jnp.zeros_like(self.vp)
+        state = jax.tree_util.tree_map(jnp.zeros_like, self.state)
         mx = (self.cfg.max_seq_len + self.page - 1) // self.page
-        for b in self.buckets:
+        if self.kind.chunked_prefill:
+            # donated: copies of the slot vectors, and the mirror pools
+            copy = lambda a: a + 0
+            state = self._chunk_fn(
+                self._head, self._stacked, state, copy(self.lengths),
+                copy(self.last), self.active & False,
+                copy(self.remaining), copy(self.eos_ids),
+                jnp.zeros((1, self.prefill_chunk), jnp.int32),
+                jnp.int32(0), jnp.int32(1), jnp.int32(0),
+                jnp.bool_(False), jnp.int32(0), jnp.int32(-1))[0]
+        for b in self.buckets if self.kind.pages else ():
             segs = np.zeros((b // self.page + 1, self.cfg.n_layers, 3),
                             np.int32)
             kp, vp, _ = self._prefill_fn(
@@ -1786,7 +1977,7 @@ class PagedDecodeEngine(ResilientScheduler):
                 jnp.zeros((self.S,), bool))
         else:
             out = self._multi_fn(
-                self._head, self._stacked, kp, vp, self._table(),
+                self._head, self._stacked, kp, vp, state, self._table(),
                 self.lengths, self.last, self.active, self.remaining,
                 self.eos_ids, jnp.zeros((self.S,), bool))
         jax.block_until_ready(out)
@@ -1812,7 +2003,7 @@ class PagedDecodeEngine(ResilientScheduler):
                 self._poison_mask(), name=name or "paged_spec")
         return devprof.capture_jit(
             self._multi_fn, self._head, self._stacked, self.kp,
-            self.vp, self._table(), self.lengths, self.last,
+            self.vp, self.state, self._table(), self.lengths, self.last,
             self.active, self.remaining, self.eos_ids,
             self._poison_mask(), name=name or "paged")
 
@@ -1828,5 +2019,6 @@ class PagedDecodeEngine(ResilientScheduler):
                      self._poison_mask()))
         return (self._multi_fn,
                 (self._head, self._stacked, self.kp, self.vp,
-                 self._table(), self.lengths, self.last, self.active,
-                 self.remaining, self.eos_ids, self._poison_mask()))
+                 self.state, self._table(), self.lengths, self.last,
+                 self.active, self.remaining, self.eos_ids,
+                 self._poison_mask()))

@@ -68,6 +68,34 @@ class GPTConfig:
     # learned wpe table; max_seq_len still caps the cache length.
     rope: bool = False
     rope_theta: float = 10000.0
+    # Norms: "layernorm" (scale and offset) or "rmsnorm" (scale only),
+    # with ``norm_eps`` under the root
+    norm: str = "layernorm"
+    norm_eps: float = 1e-5
+    # Feed-forward: "gelu" (up, GELU, down) or "swiglu" (a SiLU gate
+    # times up, then down); ``ffn_width`` sets a width that is no whole
+    # multiple of d_model (else d_model * ffn_mult)
+    ffn: str = "gelu"
+    ffn_width: Optional[int] = None
+    # per-head RMSNorm of q and k before the rotary positions
+    qk_norm: bool = False
+    # What mixes the tokens of a layer (`models/layer_kinds.py`):
+    # "softmax" attention over keys and values kept per token, or
+    # "retention" (power retention: a state kept per sequence)
+    mixer: str = "softmax"
+
+    def __post_init__(self):
+        for name, value, allowed in (
+                ("norm", self.norm, ("layernorm", "rmsnorm")),
+                ("ffn", self.ffn, ("gelu", "swiglu")),
+                ("mixer", self.mixer, ("softmax", "retention"))):
+            if value not in allowed:
+                raise ValueError(f"{name} must be one of {allowed}, "
+                                 f"got {value!r}")
+
+    @property
+    def rms_norm(self) -> bool:
+        return self.norm == "rmsnorm"
 
     @property
     def head_dim(self):
@@ -83,7 +111,7 @@ class GPTConfig:
 
     @property
     def d_ffn(self):
-        return self.d_model * self.ffn_mult
+        return self.ffn_width or self.d_model * self.ffn_mult
 
     def flops_per_token(self) -> float:
         """Model FLOPs per token (fwd+bwd), 6*N + attention term."""
@@ -97,6 +125,14 @@ class GPTConfig:
         per_layer = (d * (d + 2 * kv_dim)   # wqkv (GQA-sized kv)
                      + d * d                # wo
                      + 2 * d * self.d_ffn + 4 * d)
+        if self.ffn == "swiglu":
+            per_layer += d * self.d_ffn     # wgate
+        if self.rms_norm:
+            per_layer -= 2 * d              # no offsets
+        if self.qk_norm:
+            per_layer += 2 * self.head_dim
+        if self.mixer == "retention":
+            per_layer += d * self.kv_heads  # wg
         if self.use_bias:
             # bqkv(d+2kv) + bo(d) + bup(ffn) + bdown(d)
             per_layer += (d + 2 * kv_dim) + 2 * d + self.d_ffn
@@ -170,10 +206,31 @@ class GPTBlock(Module):
         resid_std = std / math.sqrt(2 * cfg.n_layers)
         dt = cfg.dtype
         self.use_moe = use_moe
+        self.norm_eps = cfg.norm_eps
+        self.rms = cfg.rms_norm
+        self.mixer = cfg.mixer
+        if use_moe and cfg.ffn != "gelu":
+            raise NotImplementedError("expert blocks have a GELU "
+                                      "feed-forward only")
         self.ln1_scale = Parameter(jnp.ones((d,), jnp.float32))
-        self.ln1_bias = Parameter(jnp.zeros((d,), jnp.float32))
         self.ln2_scale = Parameter(jnp.ones((d,), jnp.float32))
-        self.ln2_bias = Parameter(jnp.zeros((d,), jnp.float32))
+        if self.rms:
+            self.ln1_bias = self.ln2_bias = None
+        else:
+            self.ln1_bias = Parameter(jnp.zeros((d,), jnp.float32))
+            self.ln2_bias = Parameter(jnp.zeros((d,), jnp.float32))
+        if cfg.qk_norm:
+            self.q_norm = Parameter(jnp.ones((cfg.head_dim,), jnp.float32))
+            self.k_norm = Parameter(jnp.ones((cfg.head_dim,), jnp.float32))
+        else:
+            self.q_norm = self.k_norm = None
+        # retention's gate: one logit per key/value head and token
+        self.wg = (Parameter(_normal(jax.random.fold_in(key, 5),
+                                     (d, self.kv_heads), std, dt))
+                   if cfg.mixer == "retention" else None)
+        self.wgate = (Parameter(_normal(jax.random.fold_in(key, 6),
+                                        (d, cfg.d_ffn), std, dt))
+                      if cfg.ffn == "swiglu" and not use_moe else None)
         self.wqkv = Parameter(_normal(ks[0], (d, d + 2 * kv_dim), std, dt))
         self.wo = Parameter(_normal(ks[1], (d, d), resid_std, dt))
         if use_moe:
@@ -256,11 +313,22 @@ class GPTBlock(Module):
                                               dropout_p=0.0)
 
     def _ln(self, x, scale, bias):
-        x32 = x.astype(jnp.float32)
-        mu = jnp.mean(x32, -1, keepdims=True)
-        var = jnp.var(x32, -1, keepdims=True)
-        y = (x32 - mu) * lax.rsqrt(var + 1e-5) * scale + bias
-        return y.astype(x.dtype)
+        return final_ln(x, scale, bias, self.norm_eps, self.rms)
+
+    def _ffn(self, h, shard=False):
+        """The dense feed-forward on normed ``h``: up, GELU, down — or,
+        gated, ``silu(h @ wgate) * (h @ wup)`` then down."""
+        up = h @ self.wup
+        if self.bup is not None:
+            up = up + self.bup
+        h = (jax.nn.silu(h @ self.wgate) * up if self.wgate is not None
+             else jax.nn.gelu(up))
+        if shard:
+            h = _shard_act(h, LAYOUT.activation("sp", "tp"))
+        h = h @ self.wdown
+        if self.bdown is not None:
+            h = h + self.bdown
+        return h
 
     def _block_tail(self, x, attn):
         """Post-attention half of the block (out-proj + MLP), shared by
@@ -273,28 +341,34 @@ class GPTBlock(Module):
         if self.moe is not None:
             h, _ = self.moe(h, None)
         else:
-            h = jax.nn.gelu(h @ self.wup + (self.bup if self.bup is not None
-                                            else 0.0))
-            h = h @ self.wdown
-            if self.bdown is not None:
-                h = h + self.bdown
+            h = self._ffn(h)
         return x + h
 
-    def _qkv(self, x, positions):
-        """LN1 + fused QKV (+ rope at ``positions``) — the shared front
-        half of every cached-decode variant (ONE definition).
-        x: (B, K, d) → q (B,K,H,D), k/v (B,K,Hkv,D)."""
+    def _mix_inputs(self, x, positions):
+        """Norm 1 + fused QKV (+ per-head q/k norm, + rope at
+        ``positions``) — the shared front half of every layer kind's
+        prefill and step (ONE definition). x: (B, K, d) → q (B,K,H,D),
+        k/v (B,K,Hkv,D), and g (B,K,Hkv) float32: the log of a retention
+        layer's gate, ``None`` for softmax attention."""
         K = x.shape[1]
         h = self._ln(x, self.ln1_scale, self.ln1_bias)
         qkv = h @ self.wqkv
         if self.bqkv is not None:
             qkv = qkv + self.bqkv
         q, k, v = self._split_qkv(qkv)
+        if self.q_norm is not None:
+            q = final_ln(q, self.q_norm, None, self.norm_eps, True)
+            k = final_ln(k, self.k_norm, None, self.norm_eps, True)
         if self.rope:
             pos2 = positions[:, None] + jnp.arange(K)[None, :]
             q = self._apply_rope(q, pos2)
             k = self._apply_rope(k, pos2)
-        return q, k, v
+        g = (None if self.wg is None else
+             jax.nn.log_sigmoid((h @ self.wg).astype(jnp.float32)))
+        return q, k, v, g
+
+    def _qkv(self, x, positions):
+        return self._mix_inputs(x, positions)[:3]
 
     def _write_kv_rows(self, kv, k, v, positions):
         """Write each row's K new KV entries ((B, K, Hkv, D), any dtype)
@@ -343,6 +417,11 @@ class GPTBlock(Module):
         Returns (y, k_rows, v_rows) with rows (B, K, Hkv, D) in cache
         dtype.
         """
+        if self.mixer != "softmax":
+            raise NotImplementedError(
+                f"{self.mixer} layers keep no key/value cache: serve "
+                f"them through inference.make_engine (the paged engine "
+                f"holds their per-sequence state)")
         b, K, d = x.shape
         k_cache, v_cache = kv
         T = k_cache.shape[2]
@@ -444,18 +523,15 @@ class GPTBlock(Module):
 
     def forward(self, x, rng_key=None, aux_acc=None):
         b, s, d = x.shape
-        h = self._ln(x, self.ln1_scale, self.ln1_bias)
-        qkv = h @ self.wqkv
-        if self.bqkv is not None:
-            qkv = qkv + self.bqkv
-        q, k, v = self._split_qkv(qkv)
+        q, k, v, g = self._mix_inputs(x, jnp.zeros((b,), jnp.int32))
         q = _shard_act(q, LAYOUT.activation("sp", "tp", None))
         k = _shard_act(k, LAYOUT.activation("sp", "tp", None))
         v = _shard_act(v, LAYOUT.activation("sp", "tp", None))
-        if self.rope:
-            q = self._apply_rope(q, jnp.arange(s))
-            k = self._apply_rope(k, jnp.arange(s))
-        attn = self._attention(q, k, v, s)
+        if g is None:
+            attn = self._attention(q, k, v, s)
+        else:
+            from paddle_tpu.ops.pallas.retention import retention_sequence
+            attn = jax.vmap(retention_sequence)(q, k, v, g).astype(x.dtype)
         attn = attn.reshape(b, s, d)
         o = attn @ self.wo
         if self.bo is not None:
@@ -467,12 +543,7 @@ class GPTBlock(Module):
             if aux_acc is not None:
                 aux_acc.append(aux)
         else:
-            h = jax.nn.gelu(h @ self.wup + (self.bup if self.bup is not None
-                                            else 0.0))
-            h = _shard_act(h, LAYOUT.activation("sp", "tp"))
-            h = h @ self.wdown
-            if self.bdown is not None:
-                h = h + self.bdown
+            h = self._ffn(h, shard=True)
         x = x + _maybe_dropout(h, self.dropout, rng_key, 2)
         return _shard_act(x, LAYOUT.activation("sp", None))
 
@@ -507,10 +578,14 @@ def _shard_act(x, spec: P):
         return x
 
 
-def final_ln(x, scale, bias, eps: float = 1e-5):
-    """The head's pre-projection LayerNorm (fp32 statistics) — the single
-    definition shared by GPT.head, fused_lm_loss, and the decode engine."""
+def final_ln(x, scale, bias, eps: float = 1e-5, rms: bool = False):
+    """LayerNorm over the last axis, or with ``rms`` RMSNorm (no mean
+    taken off, no offset), fp32 statistics — the single definition
+    shared by the blocks, GPT.head, fused_lm_loss and the engines."""
     x32 = x.astype(jnp.float32)
+    if rms:
+        y = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+        return (y * scale).astype(x.dtype)
     mu = jnp.mean(x32, -1, keepdims=True)
     var = jnp.var(x32, -1, keepdims=True)
     return ((x32 - mu) * lax.rsqrt(var + eps) * scale
@@ -564,7 +639,8 @@ class GPT(Module):
                               and (i + 1) % cfg.moe_every == 0))
             for i in range(cfg.n_layers)])
         self.lnf_scale = Parameter(jnp.ones((cfg.d_model,), jnp.float32))
-        self.lnf_bias = Parameter(jnp.zeros((cfg.d_model,), jnp.float32))
+        self.lnf_bias = (None if cfg.rms_norm else Parameter(
+            jnp.zeros((cfg.d_model,), jnp.float32)))
         if not cfg.tie_embeddings:
             self.lm_head = Parameter(_normal(kh, (cfg.d_model,
                                                   cfg.vocab_size), 0.02, dt))
@@ -605,7 +681,8 @@ class GPT(Module):
         return _shard_act(x, LAYOUT.activation("sp", None))
 
     def head(self, x):
-        x = final_ln(x, self.lnf_scale, self.lnf_bias)
+        x = final_ln(x, self.lnf_scale, self.lnf_bias, self.cfg.norm_eps,
+                     self.cfg.rms_norm)
         w = self.wte.T if self.lm_head is None else self.lm_head
         logits = x @ w
         return _shard_act(logits, LAYOUT.activation("sp", "tp"))
@@ -1049,7 +1126,8 @@ def fused_lm_loss(m: GPT, tokens, rng_key=None, force: bool = False):
     from paddle_tpu.ops.pallas.fused_ce import fused_softmax_cross_entropy
     x = m.hidden_states(tokens, rng_key)
     b, s, d = x.shape
-    xn = final_ln(x, m.lnf_scale, m.lnf_bias)
+    xn = final_ln(x, m.lnf_scale, m.lnf_bias, m.cfg.norm_eps,
+                  m.cfg.rms_norm)
     w = m.wte if m.lm_head is None else m.lm_head.T   # (V, d)
     rows = xn[:, :-1].reshape(b * (s - 1), d)
     labels = tokens[:, 1:].reshape(-1)

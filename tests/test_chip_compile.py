@@ -7,8 +7,10 @@ Every kernel a default path of ``chip_smoke.py`` reaches is here: the
 train step's flash attention and fused CE (forward and backward), the
 paged engine's ``paged_append_attend`` (default decode step) and
 ``paged_decode_attention`` (suffix prefill), plus ``decode_attention``
-and ``int8_matmul`` (the contiguous engine) and ``mega_logits_sample``
-(opt-in, compiles since PR 21). ``mega_decode_layers`` is not: the
+and ``int8_matmul`` (the contiguous engine), ``mega_logits_sample``
+(opt-in, compiles since PR 21) and, at Brumby-14B's widths,
+``retention_step`` (the decode step of retention layers) with the
+dataflow that keeps its 4.4 GB state pool in place. ``mega_decode_layers`` is not: the
 compiler refuses it and no default path launches it
 (ops/pallas/decode_megakernel.py).
 
@@ -209,3 +211,62 @@ def test_mega_logits_sample(one_chip):
         (_sds((SLOTS, DM)), _sds((DM,)), _sds((DM,)), _sds((DM, VOCAB)),
          _sds((SLOTS,), jnp.int32)), one_chip)
     assert _named(calls, "mega_logits_sample") == 1
+
+
+# Brumby-14B-Base's retention layers at their published widths (40 query
+# heads and 8 key/value heads of 128), 16 slots, the benchmark's 8 layers
+R_LAYERS, R_SLOTS, R_HEADS, R_KV_HEADS = 8, 16, 40, 8
+
+
+def _retention_shapes():
+    from paddle_tpu.ops.pallas.retention import state_shapes
+    pools = state_shapes(R_LAYERS, R_SLOTS, R_KV_HEADS, HEAD_DIM)
+    return (pools["S"], pools["z"], _sds((R_SLOTS, R_HEADS, HEAD_DIM)),
+            _sds((R_SLOTS, R_KV_HEADS, HEAD_DIM)),
+            _sds((R_SLOTS, R_KV_HEADS), jnp.float32),
+            _sds((R_SLOTS,), jnp.bool_))
+
+
+def test_retention_step(one_chip):
+    from paddle_tpu.ops.pallas.retention import retention_step
+    S, z, q, k, g, active = _retention_shapes()
+
+    def step(S, z, q, k, g, active):
+        return retention_step(q, k, k, g, S, z, 3, active, interpret=False)
+
+    calls = _compile(step, (S, z, q, k, g, active), one_chip)
+    assert _named(calls, "retention_step") == 1
+
+
+def test_retention_decode_dataflow_copies_no_pool(one_chip):
+    """The engine's decode dataflow over retention layers (a scan over
+    layers of `retention_step` with the donated state pools as carry,
+    inside a scan over tokens) at Brumby's widths: the compiled program
+    may hold no ``copy`` whose result is a whole state pool. The ``S``
+    pool is 4.36 GB: a second copy does not fit beside the weights."""
+    from paddle_tpu.ops.pallas.retention import retention_step
+    S, z, q, k, g, active = _retention_shapes()
+
+    def step(S, z, q, k, g, active):
+        def layer(carry, i):
+            h, S, z = carry
+            o, S, z = retention_step(q + h, k, k, g, S, z, i, active,
+                                     interpret=False)
+            return (o.astype(q.dtype), S, z), None
+
+        def token(carry, _):
+            (h, S, z), _ = jax.lax.scan(layer, carry, jnp.arange(R_LAYERS))
+            return (h, S, z), None
+
+        return jax.lax.scan(token, (jnp.zeros_like(q), S, z), None,
+                            length=2)[0]
+
+    text = _compiled_text(step, (S, z, q, k, g, active), one_chip,
+                          donate=(0, 1))
+    assert "retention_step" in text
+    pools = tuple("f32[" + ",".join(map(str, p.shape)) + "]"
+                  for p in (S, z))
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(r"= \S+ copy\(", ln)
+              and any(f"= {sh}" in ln for sh in pools)]
+    assert not copies, copies
