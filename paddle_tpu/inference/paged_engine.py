@@ -1773,10 +1773,16 @@ class PagedDecodeEngine(ResilientScheduler):
                 sp.attrs["waiting"] = len(self._waiting)
                 # what the traffic holds of the pool, token by token
                 # and page by page, at the end of the step
-                sp.attrs["live_tokens"] = sum(
-                    int(self._host_len[s])
-                    for s, r in enumerate(self._slot_req)
-                    if r is not None)
+                live = [int(self._host_len[s])
+                        for s, r in enumerate(self._slot_req)
+                        if r is not None]
+                sp.attrs["live_tokens"] = sum(live)
+                # the pages those tokens lie on: what the paged attend
+                # walks a layer (the table's slots x columns it no
+                # longer walks is a constant of the engine)
+                sp.attrs["live_pages"] = (
+                    sum(-(-n // self.page) for n in live) if self.P
+                    else 0)
                 sp.attrs["pages_used"] = self.P - self.free_pages
                 sp.attrs["pages"] = self.P
                 # sequences holding per-sequence state and its bytes;

@@ -47,22 +47,29 @@ def online_softmax_step(q, k, v, col0, length, acc_ref, m_ref, l_ref,
     """One KV-block update of the online softmax: masked scores against
     columns [col0, col0+block) valid below ``length``, then the running
     (m, l, acc) rescale-and-accumulate. Shared by the contiguous and
-    the paged decode kernels — ONE numerics definition."""
+    the paged decode kernels — ONE numerics definition. ``q`` (rows, d)
+    against ``k``/``v`` (block, d) is one head; a leading axis on all
+    of them (and on the three refs) is a block of heads folded at once,
+    each exactly as it would be alone."""
+    heads = tuple(range(q.ndim - 2))
+    last = q.ndim - 1
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        q, k, (((last,), (last,)), (heads, heads)),
         preferred_element_type=jnp.float32) * scale
-    col = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, last)
     s = jnp.where(col < length, s, _NEG_INF)
 
     m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     m_cur = jnp.maximum(m_cur, -1e30)  # fully-masked block → p = 0
     alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, :1])
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = (acc_ref[...] * alpha[:, :1]
-                    + jax.lax.dot(p.astype(v.dtype), v,
-                                  preferred_element_type=jnp.float32))
+    p = jnp.exp(s - m_cur[..., :1])
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = (acc_ref[...] * alpha[..., :1]
+                    + jax.lax.dot_general(
+                        p.astype(v.dtype), v,
+                        (((last,), (last - 1,)), (heads, heads)),
+                        preferred_element_type=jnp.float32))
     m_ref[...] = m_cur
 
 
@@ -73,9 +80,11 @@ def online_softmax_init(acc_ref, m_ref, l_ref):
 
 
 def online_softmax_finalize(o_ref, acc_ref, l_ref):
-    l = l_ref[:, :1]
-    o_ref[0] = (acc_ref[...]
-                / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    """``o_ref`` is one head's (1, rows, d) block, or a block of heads
+    (heads, rows, d) over refs with that leading axis."""
+    l = l_ref[..., :1]
+    o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype).reshape(o_ref.shape)
 
 
 def online_softmax_write_stats(ml_ref, m_ref, l_ref):
@@ -83,9 +92,10 @@ def online_softmax_write_stats(ml_ref, m_ref, l_ref):
     = running max, column 1 = softmax denominator (columns 2+ are
     don't-care). ONE packing definition shared by the contiguous and
     paged decode kernels — the host-side unpack in both callers reads
-    exactly these two columns."""
-    l = l_ref[:, :1]
-    ml_ref[0] = jnp.concatenate([m_ref[:, :1], l, l_ref[:, 2:]], axis=1)
+    exactly these two columns. Shapes as in `online_softmax_finalize`."""
+    l = l_ref[..., :1]
+    ml_ref[...] = jnp.concatenate(
+        [m_ref[..., :1], l, l_ref[..., 2:]], axis=-1).reshape(ml_ref.shape)
 
 
 def fold_fresh_row(o, m, l, q, k_row, v_row, scale, group):

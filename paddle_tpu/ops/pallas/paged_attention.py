@@ -9,12 +9,17 @@ shares fixed-size pages across sequences; a sequence holds
 ceil(len/page) pages and frees them at retirement — memory scales with
 the sum of actual lengths, not slots x max_len.
 
-TPU mapping: the page table rides as a scalar-prefetch operand and the
-KV BlockSpec index maps translate (sequence, block j) -> pool page id
-at DMA-schedule time, so the kernel streams exactly the pages a
-sequence owns — same online-softmax inner loop as decode_attention,
-same clamp trick (a repeated page index is not re-fetched) for rows
-shorter than the longest.
+TPU mapping: one program a (sequence, head block). The pools stay in
+HBM (memory space ANY); lengths and the page table ride as
+scalar-prefetch operands, and the program walks ITS row of the table
+for ``ceil(length / page)`` pages and no further: each page's heads are
+copied into VMEM (`pltpu.make_async_copy`), the next page's copy in
+flight while the current page is folded, eight heads at a time, with
+the same online-softmax step as decode_attention; while a program
+folds its last page it starts the copy of the NEXT program's first, so
+the copies are one pipeline across the grid (which runs in order). A
+call's device time follows the pages the sequences hold, not the
+table's width: the grid does not know it.
 
 Two entry points:
 
@@ -23,7 +28,8 @@ Two entry points:
   engine formulation).
 - `paged_append_attend` — the decode step's append+attend, as TWO
   launches: a small write kernel (`paged_append_attend_write`) merges
-  the current token's fresh K/V row into its pool page in place, then
+  the current token's fresh K/V row into its pool page in place (it
+  moves the sublane tile that holds the row, not the page), then
   the read-only attend runs over the pools the write RETURNED, with
   ``lengths + 1``. Each pool reaches the write kernel as that call's
   only use of it (one operand, aliased to its output), so XLA keeps the
@@ -36,14 +42,15 @@ Two entry points:
   kernel reads, so the compiler converts it both ways). The data
   dependence (attend reads what the write returned) orders the two.
 
-Both take an autotunable ``(pages_per_program, head_block)`` config
-(see `tune_paged_attention`): pages_per_program streams several pages
-per grid step (separate BlockSpecs — pool pages are not contiguous, so
-one bigger block cannot express this), head_block processes several
-consecutive KV heads of one page per program (their rows ARE contiguous
-in the head-major pool view). Both shrink the grid — the paged kernel's
-measured overhead at short cache lengths is per-program dispatch over a
-mostly-masked fixed-width table, not bandwidth.
+Both take a ``(pages_per_program, head_block)`` geometry. head_block is
+how many consecutive KV heads of a page one program takes (their rows
+ARE contiguous in the head-major pool view, so a page's head block is
+one copy); by default the largest divisor of Hkv whose blocks fit VMEM
+(`_default_head_block`: all 16 heads at GPT-3 XL), so the fewest
+programs and the longest copies, with no tuner run. pages_per_program
+is how many pages a program keeps in flight ahead of the one it folds
+(1: double buffering). `tune_paged_attention` can still measure both
+and persist a winner; a cache hit overrides the derived default.
 
 Forward-only (generation never differentiates through the cache).
 """
@@ -62,9 +69,17 @@ __all__ = ["paged_decode_attention", "paged_decode_attention_reference",
 _LANES = 128
 _NEG_INF = float("-inf")
 
-# fallback when the autotune cache has no entry for the shape family:
-# one page and one KV head per program (the pre-autotune geometry)
-_DEFAULT_CONFIG = (1, 1)
+# pages a program keeps in flight ahead of the fold when neither the
+# caller nor the autotune cache says: the next page's copy runs while
+# the current page is folded (double buffering)
+_DEFAULT_PAGES_IN_FLIGHT = 1
+
+# heads of a page folded at once (the head block's common divisor with
+# it): one batched `online_softmax_step`, whose vector work then covers
+# the heads' independent chains. On a v5e 8 folds as fast as 16 to 4%
+# and 1.5-2x faster than heads one at a time, unrolled or not, and
+# compiles sooner than 16 (PERF.md section 6, PR 30)
+_HEAD_CHUNK = 8
 
 
 def _tune_key(page, hkv, d, dtype, group, fused):
@@ -74,93 +89,192 @@ def _tune_key(page, hkv, d, dtype, group, fused):
         page=page, hkv=hkv, d=d, dtype=str(dtype), group=group)
 
 
+def _group_rows(group, dtype):
+    """A KV head's query rows, padded to the dtype's sublane tile."""
+    sub = _sublanes(dtype)
+    return max(sub, (group + sub - 1) // sub * sub)
+
+
+def _vmem_bytes(ppp, hb, page, d, dtype, group):
+    """VMEM one program of the attend holds at ``(ppp, hb)``, as
+    `analysis/kernelmodel` counts it (pipelined blocks twice, scratch
+    once): K and V landing buffers for ``ppp + 1`` pages; the q and o
+    blocks; the float32 accumulator, (m, l) and the stats block. The
+    write launch shares the head block and holds far less (six sublane
+    tiles of ``hb`` heads)."""
+    from paddle_tpu.analysis import kernelmodel as km
+    isz = km.itemsize(jnp.dtype(dtype))
+    rows = hb * _group_rows(group, dtype)
+    return (2 * (ppp + 1) * hb * page * d * isz
+            + 2 * km.DOUBLE_BUFFER * rows * d * isz
+            + rows * d * 4
+            + (2 + km.DOUBLE_BUFFER) * rows * _LANES * 4)
+
+
+def _default_head_block(ppp, page, hkv, d, dtype, group):
+    """The largest divisor of ``hkv`` whose blocks fit the VMEM budget
+    (`kernelmodel.vmem_budget_bytes`: 16 MiB less the compiler's
+    reserve): a page of the pool is contiguous over its heads, so the
+    more heads a program takes, the fewer programs and the longer
+    copies. A wide ``Hkv x page x D`` gets a smaller block, never a
+    failed compile; 1 is the floor whatever the budget says."""
+    from paddle_tpu.analysis import kernelmodel as km
+    budget = km.vmem_budget_bytes()
+    for hb in range(hkv, 1, -1):
+        if hkv % hb == 0 and _vmem_bytes(ppp, hb, page, d, dtype,
+                                         group) <= budget:
+            return hb
+    return 1
+
+
 def _resolve_config(ppp, hb, page, hkv, d, dtype, group, max_pages,
                     fused):
-    """Fill unset config knobs from the autotune cache (trace-time dict
-    read, ≙ flash_attention's block lookup) and clamp to validity:
+    """Fill unset config knobs: from the autotune cache where a tuner
+    has run (trace-time dict read, ≙ flash_attention's block lookup),
+    else ``pages_per_program`` 1 and the head block derived from the
+    shapes (`_default_head_block`). Clamp to validity:
     pages_per_program can't exceed the table width, head_block must
     divide Hkv."""
     if ppp is None or hb is None:
         from paddle_tpu.ops.pallas.autotune import get_cache
-        hit = get_cache().get(_tune_key(page, hkv, d, dtype, group,
-                                        fused))
-        t_ppp, t_hb = hit if hit is not None else _DEFAULT_CONFIG
+        t_ppp, t_hb = get_cache().get(_tune_key(
+            page, hkv, d, dtype, group, fused)) or (None, None)
         ppp = t_ppp if ppp is None else ppp
         hb = t_hb if hb is None else hb
     # ptlint: disable=PT001 -- ppp/hb are static Python config knobs
     # (autotune-cache hits or explicit kwargs; a tracer here would
     # already have failed the cache lookup), never device values
-    ppp = max(1, min(int(ppp), max_pages))
+    ppp = _DEFAULT_PAGES_IN_FLIGHT if ppp is None else int(ppp)
+    ppp = max(1, min(ppp, max_pages))
+    if hb is None:
+        hb = _default_head_block(ppp, page, hkv, d, dtype, group)
     hb = max(1, int(hb))  # ptlint: disable=PT001 -- static config knob
     while hkv % hb:
         hb -= 1
     return ppp, hb
 
 
-def _kernel(*refs, scale, page, hkv, ppp, hb, with_stats):
-    # Ref layout (the table prefetch ref is consumed by the BlockSpec
-    # index maps, not the body, but still appears in the ABI; the stats
-    # output exists only when requested, so trailing refs shift — same
-    # convention as the contiguous decode kernel):
-    #   len, table, q, k*ppp, v*ppp | o, [ml] | acc, m, l
-    len_ref, _table_ref, q_ref = refs[:3]
-    rest = refs[3:]
-    k_refs, v_refs = rest[:ppp], rest[ppp:2 * ppp]
-    rest = rest[2 * ppp:]
+def _kernel(*refs, scale, page, hkv, max_pages, ppp, hb, with_stats):
+    # Ref layout (the stats output exists only when requested, so the
+    # trailing refs shift, same convention as the contiguous decode
+    # kernel):
+    #   len, table, q, k_pool, v_pool | o, [ml] |
+    #   kbuf, vbuf, sems, base, acc, m, l
+    # The pools stay where they are (memory space ANY); kbuf/vbuf are
+    # the ppp + 1 landing buffers of a page's hb heads, and base (SMEM)
+    # is the buffer that holds THIS program's first page: the program
+    # before it started that copy, so the buffers rotate across
+    # programs and the grid runs in order ("arbitrary").
+    len_ref, table_ref, q_ref, k_hbm, v_hbm = refs[:5]
+    rest = refs[5:]
     ml_ref = None
     if with_stats:
-        o_ref, ml_ref, acc_ref, m_ref, l_ref = rest
+        o_ref, ml_ref = rest[:2]
+        rest = rest[2:]
     else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+        o_ref, rest = rest[0], rest[1:]
+    kbuf, vbuf, sems, base_ref, acc_ref, m_ref, l_ref = rest
     nhb = hkv // hb
+    nbuf = ppp + 1
+    chunk = math.gcd(hb, _HEAD_CHUNK)
     bh = pl.program_id(0)
-    j = pl.program_id(1)
-    b = bh // nhb
 
     from paddle_tpu.ops.pallas.decode_attention import (
         online_softmax_finalize, online_softmax_init,
         online_softmax_step, online_softmax_write_stats)
 
-    @pl.when(j == 0)
-    def _init():
-        online_softmax_init(acc_ref, m_ref, l_ref)
+    def live_pages(prog):
+        # a row's live pages: the walk ends at the last of them,
+        # whatever the table's width (and inside the table, whatever
+        # the length)
+        return jnp.minimum((len_ref[prog // nhb] + page - 1) // page,
+                           max_pages)
 
-    length = len_ref[b]
+    def copies(prog, j, slot):
+        # page j of program prog's row: its hb heads are hb consecutive
+        # rows of the pools' (N*Hkv, page, D) view
+        row0 = (table_ref[(prog // nhb) * max_pages + j] * hkv
+                + (prog % nhb) * hb)
+        return (pltpu.make_async_copy(k_hbm.at[pl.ds(row0, hb)],
+                                      kbuf.at[slot], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[pl.ds(row0, hb)],
+                                      vbuf.at[slot], sems.at[1, slot]))
 
-    # beyond the row's last valid page the index map re-presents that
-    # SAME page (DMA elided); the compute must not run again
-    for i in range(ppp):
-        col0 = (j * ppp + i) * page
+    def fetch(prog, j, slot):
+        for c in copies(prog, j, slot):
+            c.start()
 
-        @pl.when(col0 < length)
-        def _body(i=i, col0=col0):
-            for h in range(hb):
-                online_softmax_step(q_ref[h], k_refs[i][h], v_refs[i][h],
-                                    col0, length, acc_ref.at[h],
-                                    m_ref.at[h], l_ref.at[h], scale)
+    length = len_ref[bh // nhb]
+    n_live = live_pages(bh)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        for h in range(hb):
-            # hb == 1 passes the block ref whole: a ``.at[0:1]`` view of
-            # a size-1 dim is a "trivial" transform that the
-            # interpret-mode discharge mishandles when stacked under the
-            # helper's integer write
-            ov = o_ref if hb == 1 else o_ref.at[h:h + 1]
-            online_softmax_finalize(ov, acc_ref.at[h], l_ref.at[h])
-            if with_stats:
-                mlv = ml_ref if hb == 1 else ml_ref.at[h:h + 1]
-                online_softmax_write_stats(mlv, m_ref.at[h],
-                                           l_ref.at[h])
+    @pl.when(bh == 0)
+    def _first_program():
+        base_ref[0] = 0
+
+        @pl.when(n_live > 0)
+        def _own_first_page():
+            fetch(bh, 0, 0)
+
+    base = base_ref[0]
+
+    def fetch_next_program():
+        # the next program's first page lands while this one folds its
+        # last, in the buffer after it (free: its page is folded)
+        @pl.when(bh + 1 < pl.num_programs(0))
+        def _in_grid():
+            @pl.when(live_pages(bh + 1) > 0)
+            def _start():
+                fetch(bh + 1, 0, (base + n_live) % nbuf)
+
+    online_softmax_init(acc_ref, m_ref, l_ref)
+    for j in range(1, ppp):
+        @pl.when(j < n_live)
+        def _prologue(j=j):
+            fetch(bh, j, (base + j) % nbuf)
+
+    def fold(j, carry):
+        @pl.when(j + ppp < n_live)
+        def _ahead():
+            fetch(bh, j + ppp, (base + j + ppp) % nbuf)
+
+        @pl.when(j == n_live - 1)
+        def _last_page():
+            fetch_next_program()
+
+        slot = (base + j) % nbuf
+        for c in copies(bh, j, slot):
+            c.wait()
+
+        def fold_heads(g, carry):
+            hs = pl.ds(g * chunk, chunk)
+            online_softmax_step(q_ref[hs], kbuf[slot, hs], vbuf[slot, hs],
+                                j * page, length, acc_ref.at[hs],
+                                m_ref.at[hs], l_ref.at[hs], scale)
+            return carry
+
+        return jax.lax.fori_loop(0, hb // chunk, fold_heads, carry)
+
+    jax.lax.fori_loop(0, n_live, fold, 0)
+
+    @pl.when(n_live == 0)
+    def _empty_row():
+        fetch_next_program()
+
+    base_ref[0] = (base + n_live) % nbuf
+
+    online_softmax_finalize(o_ref, acc_ref, l_ref)
+    if with_stats:
+        online_softmax_write_stats(ml_ref, m_ref, l_ref)
 
 
 def _write_kernel(len_ref, _wpid_ref, krow_ref, vrow_ref, kin_ref,
-                  vin_ref, kout_ref, vout_ref, *, page, nhb, hb):
-    # one program per (row, head block): the block is the row's write
-    # page (the index maps read wpid), and row ``length % page`` of it
-    # is replaced by the fresh row; every other row passes through
-    off = len_ref[pl.program_id(0) // nhb] % page
-    sel = jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0) == off
+                  vin_ref, kout_ref, vout_ref, *, page, nhb, hb, sub):
+    # one program per (row, head block): the block is the sublane tile
+    # of the row's write page that holds row ``length % page`` (the
+    # index maps read wpid and the length); that row is replaced by the
+    # fresh row, the tile's other rows pass through
+    off = len_ref[pl.program_id(0) // nhb] % page % sub
+    sel = jax.lax.broadcasted_iota(jnp.int32, (sub, 1), 0) == off
     for h in range(hb):
         kout_ref[h] = jnp.where(sel, krow_ref[h][:1], kin_ref[h])
         vout_ref[h] = jnp.where(sel, vrow_ref[h][:1], vin_ref[h])
@@ -219,39 +333,23 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
     ppp, hb = _resolve_config(pages_per_program, head_block, page, hkv,
                               d, q.dtype, group, max_pages, False)
     nhb = hkv // hb
-    nj = (max_pages + ppp - 1) // ppp
 
-    sub = _sublanes(q.dtype)
-    gp = max(sub, (group + sub - 1) // sub * sub)
+    gp = _group_rows(group, q.dtype)
     qg = q.reshape(b * hkv, group, d)
     qg = jnp.pad(qg, ((0, 0), (0, gp - group), (0, 0)))
 
     lengths = jnp.asarray(lengths, jnp.int32)
     table_flat = jnp.asarray(page_table, jnp.int32).reshape(-1)
-    # pools are indexed (page, head) -> (page, D): merge Hkv into the
-    # leading dim via a head-major view so one block = hb consecutive
-    # (page, D) tiles of one page. (P, Hkv, page, D) -> (P*Hkv, page, D)
-    # with id p*Hkv+h; the hb-row block at p*nhb + head_block_index is
-    # contiguous because heads vary fastest.
+    # the pools' head-major view (P, Hkv, page, D) -> (P*Hkv, page, D):
+    # rows p*Hkv + h, so the hb heads of one page are hb consecutive
+    # rows, one contiguous copy
     kp = k_pages.reshape(-1, page, d)
     vp = v_pages.reshape(-1, page, d)
 
-    def bh_index(bh, j, lens, table):
+    def bh_index(bh, lens, table):
         return (bh, 0, 0)
 
-    def kv_index(i):
-        def index(bh, j, lens, table):
-            bb = bh // nhb
-            used = jnp.maximum((lens[bb] + page - 1) // page, 1)
-            jj = jnp.minimum(j * ppp + i, used - 1)
-            return (table[bb * max_pages + jj] * nhb + bh % nhb, 0, 0)
-        return index
-
-    in_specs = [pl.BlockSpec((hb, gp, d), bh_index)]
-    in_specs += [pl.BlockSpec((hb, page, d), kv_index(i))
-                 for i in range(ppp)]
-    in_specs += [pl.BlockSpec((hb, page, d), kv_index(i))
-                 for i in range(ppp)]
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     out_specs = [pl.BlockSpec((hb, gp, d), bh_index)]
     out_shape = [jax.ShapeDtypeStruct((b * hkv, gp, d), q.dtype)]
     if return_stats:  # stats output only exists when asked for
@@ -259,12 +357,21 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
         out_shape.append(
             jax.ShapeDtypeStruct((b * hkv, gp, _LANES), jnp.float32))
 
+    # one program a (row, head block): the page walk is the program's
+    # own loop, so the grid does not know the table's width. In order
+    # ("arbitrary"): a program starts the copy of the next one's first
+    # page
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b * nhb, nj),
-        in_specs=in_specs,
+        grid=(b * nhb,),
+        in_specs=[pl.BlockSpec((hb, gp, d), bh_index), pool_spec,
+                  pool_spec],
         out_specs=out_specs,
         scratch_shapes=[
+            pltpu.VMEM((ppp + 1, hb, page, d), kp.dtype),
+            pltpu.VMEM((ppp + 1, hb, page, d), vp.dtype),
+            pltpu.SemaphoreType.DMA((2, ppp + 1)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((hb, gp, d), jnp.float32),
             pltpu.VMEM((hb, gp, _LANES), jnp.float32),
             pltpu.VMEM((hb, gp, _LANES), jnp.float32),
@@ -274,15 +381,15 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
         # ptlint: disable=PT001 -- scale is a static Python float kwarg
         # (a tracer here would already fail partial-binding)
         functools.partial(_kernel, scale=float(scale), page=page,
-                          hkv=hkv, ppp=ppp, hb=hb,
+                          hkv=hkv, max_pages=max_pages, ppp=ppp, hb=hb,
                           with_stats=return_stats),
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         name=name,
         interpret=interpret,
-    )(lengths, table_flat, qg, *([kp] * ppp), *([vp] * ppp))
+    )(lengths, table_flat, qg, kp, vp)
     o = res[0][:, :group, :].reshape(b, hq, d)
     if not return_stats:
         return o
@@ -296,7 +403,9 @@ def _write_rows(k_pages, v_pages, k_row, v_row, write_pids, lengths, hb,
                 interpret):
     """The append half of `paged_append_attend`: row b's fresh K/V row
     replaces row ``lengths[b] % page`` of pool page ``write_pids[b]``,
-    K and V in one launch. Each pool is passed ONCE, its write block
+    K and V in one launch: a program moves the sublane tile that holds
+    the row (16 rows of bf16, 8 of float32) in, replaces the row and
+    moves the tile out. Each pool is passed ONCE, its write block
     aliased in to out — the call's only use of the pool, so XLA leaves
     the pool where it is."""
     b, hkv, d = k_row.shape
@@ -311,26 +420,28 @@ def _write_rows(k_pages, v_pages, k_row, v_row, write_pids, lengths, hb,
     def row_index(bh, lens, wpids):
         return (bh, 0, 0)
 
-    def page_index(bh, lens, wpids):
-        return (wpids[bh // nhb] * nhb + bh % nhb, 0, 0)
+    def tile_index(bh, lens, wpids):
+        b_ = bh // nhb
+        return (wpids[b_] * nhb + bh % nhb, lens[b_] % page // sub, 0)
 
     row_spec = pl.BlockSpec((hb, sub, d), row_index)
-    page_spec = pl.BlockSpec((hb, page, d), page_index)
+    tile_spec = pl.BlockSpec((hb, sub, d), tile_index)
     kp = k_pages.reshape(-1, page, d)
     vp = v_pages.reshape(-1, page, d)
     kp, vp = pl.pallas_call(
-        functools.partial(_write_kernel, page=page, nhb=nhb, hb=hb),
+        functools.partial(_write_kernel, page=page, nhb=nhb, hb=hb,
+                          sub=sub),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b * nhb,),
-            in_specs=[row_spec, row_spec, page_spec, page_spec],
-            out_specs=[page_spec, page_spec],
+            in_specs=[row_spec, row_spec, tile_spec, tile_spec],
+            out_specs=[tile_spec, tile_spec],
         ),
         out_shape=[jax.ShapeDtypeStruct(kp.shape, kp.dtype),
                    jax.ShapeDtypeStruct(vp.shape, vp.dtype)],
         # operand numbering counts the two scalar-prefetch refs and the
         # two row operands: pools 4 and 5 alias outputs 0 and 1, so
-        # untouched pages keep their input values
+        # what no program writes keeps its input values
         input_output_aliases={4: 0, 5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
@@ -354,8 +465,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
       page_table: (B, max_pages) int32 — row b's i-th page id in the
         pool; entries beyond ceil(lengths[b]/page_size) are ignored.
       lengths: (B,) int32 — row b attends to its first lengths[b]
-        tokens. Pages beyond a row's length are not fetched from HBM
-        (clamped scalar-prefetch index map).
+        tokens (at most the table's ``max_pages * page_size``). Pages
+        beyond a row's length are neither fetched nor visited: the
+        program's page loop ends at the row's last live page.
       scale: softmax scale, default 1/sqrt(D).
       interpret: defaults to True off-TPU so tests run on CPU.
       return_stats: also return the online-softmax running max ``m``
@@ -364,10 +476,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
         engine's pre-fusion formulation added the current token's
         fresh KV row this way, keeping the pools READ-ONLY inside its
         layer scan.
-      pages_per_program, head_block: kernel geometry; default (None)
-        reads the autotune cache per (page, Hkv, D, dtype, group) key
-        at trace time (`tune_paged_attention` fills it), falling back
-        to (1, 1).
+      pages_per_program, head_block: kernel geometry (pages in flight
+        ahead of the fold; KV heads a program takes). Default (None):
+        the autotune cache's entry for the (page, Hkv, D, dtype, group)
+        key where `tune_paged_attention` has filled one, else 1 and the
+        largest head block that fits VMEM.
 
     Returns (B, Hq, D) in q's dtype; with return_stats, (o, m, l).
     """
@@ -386,7 +499,7 @@ def paged_append_attend(q, k_pages, v_pages, k_row, v_row, page_table,
     token's key/value) into pool page ``write_pids[b]`` at row offset
     ``lengths[b] % page_size``, in place: each pool is that call's one
     pool operand, aliased to its output, so the write touches exactly
-    one page per (row, KV-head) and nothing copies the pool. Then the
+    one sublane tile per (row, KV-head) and nothing copies the pool. Then the
     read-only attend (`paged_append_attend` in a trace) runs over the
     pools the write returned, each row over ``lengths[b] + 1`` tokens:
     its prefix plus the row just written, folded in page order.
@@ -456,9 +569,14 @@ def tune_paged_attention(q, k_pages, v_pages, page_table, lengths,
     group = hq // hkv
     key = _tune_key(page, hkv, d, q.dtype, group, fused)
     if candidates is None:
+        # every head block that divides Hkv and fits VMEM, with one or
+        # two pages in flight
+        from paddle_tpu.analysis.kernelmodel import vmem_budget_bytes
         candidates = [(ppp, hb)
-                      for ppp in (1, 2, 4) if ppp <= max_pages
-                      for hb in (1, 2, 4) if hkv % hb == 0]
+                      for ppp in (1, 2) if ppp <= max_pages
+                      for hb in range(1, hkv + 1) if hkv % hb == 0
+                      and _vmem_bytes(ppp, hb, page, d, q.dtype,
+                                      group) <= vmem_budget_bytes()]
     if fused:
         k_row = jnp.zeros((b, hkv, d), k_pages.dtype)
         v_row = jnp.zeros((b, hkv, d), jnp.asarray(v_pages).dtype)
@@ -619,7 +737,7 @@ def ptgeom_cases():
         d = p["dm"] // p["heads"]
         hkv = p["kv_heads"]
         page = p["page"]
-        B = 8
+        B = 16
         mx = max(1, p["seq"] // page)
         q = km.sds((B, p["heads"], d), p["dtype"])
         pool = km.sds((B * mx + 1, hkv, page, d), p["dtype"])
@@ -643,10 +761,16 @@ def ptgeom_cases():
                         head_block=hb),
                     q, pool, pool, table, vec)
         tag = "fused" if fused else "plain"
+        config = "default" if ppp is None else f"ppp{ppp}.hb{hb}"
         return km.GeomCase(kernel=f"paged_{tag}", geometry=geom,
-                           config=f"ppp{ppp}.hb{hb}", run=run)
+                           config=config, run=run)
 
     cases = [case("tiny", 1, 1, True)]
+    # the geometry a call gets with no tuner run (what the benchmark's
+    # serving cells and every default engine run), at every rung
+    for geom in km.LADDER:
+        for fused in (False, True):
+            cases.append(case(geom, None, None, fused))
     for geom in ("350m", "r06"):
         for ppp, hb in ((1, 1), (2, 2), (4, 4)):
             cases.append(case(geom, ppp, hb, False))

@@ -31,6 +31,9 @@ import pytest
 
 DM, LAYERS, HEADS, HEAD_DIM, VOCAB, SEQ = 2048, 24, 16, 128, 50304, 2048
 BATCH, SLOTS, PAGE, POOL_PAGES = 4, 8, 128, 40
+# the paged kernels as the benchmark's dense serving cells run them: 16
+# slots, a table of SEQ // PAGE = 16 columns, the default geometry
+PAGED_SLOTS = 16
 BF16 = jnp.bfloat16
 
 
@@ -108,18 +111,22 @@ def test_fused_ce_fwd_bwd(one_chip):
 
 def _paged_shapes(pool_pages=POOL_PAGES):
     pool = _sds((LAYERS * pool_pages + 1, HEADS, PAGE, HEAD_DIM))
-    q = _sds((SLOTS, HEADS, HEAD_DIM))
-    table = _sds((SLOTS, SEQ // PAGE), jnp.int32)
-    vec = _sds((SLOTS,), jnp.int32)
+    q = _sds((PAGED_SLOTS, HEADS, HEAD_DIM))
+    table = _sds((PAGED_SLOTS, SEQ // PAGE), jnp.int32)
+    vec = _sds((PAGED_SLOTS,), jnp.int32)
     return pool, q, table, vec
 
 
 def test_paged_append_attend(one_chip):
     """Two launches: the in-place row write, then the read-only attend
     (both carry the ``paged_append_attend`` family name that the
-    benchmark's roofline share sums)."""
-    from paddle_tpu.ops.pallas.paged_attention import paged_append_attend
+    benchmark's roofline share sums), at 16 slots x 16 columns with the
+    default geometry: all 16 heads of a page a program."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        _resolve_config, paged_append_attend)
     pool, q, table, vec = _paged_shapes()
+    assert _resolve_config(None, None, PAGE, HEADS, HEAD_DIM, BF16, 1,
+                           SEQ // PAGE, True) == (1, HEADS)
     calls = _compile(
         functools.partial(paged_append_attend, interpret=False),
         (q, pool, pool, q, q, table, vec, vec), one_chip)

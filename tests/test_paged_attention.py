@@ -355,3 +355,168 @@ def test_shared_pool_two_sequences_interleaved():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
     assert list(np.asarray(lens)) == [140, 40]
+
+
+def _live_table(rs, b, columns, live, P):
+    """A table ``columns`` wide whose rows own ``live`` distinct pages
+    each; the columns past them hold page ids OUTSIDE the pool, so a
+    kernel that so much as fetched one would fault (or read garbage the
+    oracle does not)."""
+    table = np.full((b, columns), P + 7, np.int32)
+    table[:, :live] = rs.permutation(P)[:b * live].reshape(b, live)
+    return table
+
+
+@pytest.mark.parametrize("stats", [False, True])
+@pytest.mark.parametrize("hkv,group,d", [(2, 1, 64), (2, 4, 128),
+                                         (16, 1, 128), (16, 4, 64)])
+@pytest.mark.parametrize("columns", [16, 32])
+def test_default_geometry_wide_table_one_live_page(columns, hkv, group, d,
+                                                   stats):
+    """ISSUE 30: at the DEFAULT geometry (no tuner, no kwargs), under
+    jit, a table far wider than what is live: one live page of 16 and of
+    32 columns, beside a length-0 row. The oracle gathers through a
+    table whose dead columns are clamped into the pool; the kernel gets
+    them out of range and must never touch them."""
+    rs = np.random.RandomState(columns + hkv + group + d)
+    P, page, b = 5, 128, 3
+    k, v = _pool(rs, P, hkv, page, d)
+    q = jnp.asarray(rs.randn(b, hkv * group, d), jnp.float32)
+    table = _live_table(rs, b, columns, 1, P)
+    lengths = jnp.asarray([page, 0, 37], jnp.int32)
+
+    f = jax.jit(lambda *a: paged_decode_attention(*a, return_stats=stats))
+    got = f(q, k, v, jnp.asarray(table), lengths)
+    want = paged_decode_attention_reference(
+        q, k, v, jnp.asarray(np.minimum(table, P - 1)), lengths)
+    o = got[0] if stats else got
+    # the length-0 row: the kernel writes zeros (l == 0), the oracle's
+    # all-masked softmax is NaN
+    np.testing.assert_allclose(np.asarray(o)[[0, 2]],
+                               np.asarray(want)[[0, 2]],
+                               atol=1e-5, rtol=1e-5)
+    assert not np.asarray(o)[1].any()
+    if stats:
+        _, m, l = got
+        assert np.isneginf(np.asarray(m)[1]).all()
+        assert not np.asarray(l)[1].any()
+        assert (np.asarray(l)[[0, 2]] > 0).all()
+
+
+@pytest.mark.parametrize("stats", [False, True])
+@pytest.mark.parametrize("hkv,group,d", [(2, 4, 64), (16, 1, 128)])
+def test_default_geometry_full_table_row(hkv, group, d, stats):
+    """A row that FILLS its table (every column live, the last page
+    full) beside a one-token row, default geometry under jit; and past
+    the table: a length beyond ``columns * page`` walks the table's
+    columns and no further."""
+    rs = np.random.RandomState(7 + hkv + d)
+    P, page, b, columns = 9, 128, 2, 4
+    k, v = _pool(rs, P, hkv, page, d)
+    q = jnp.asarray(rs.randn(b, hkv * group, d), jnp.float32)
+    table = jnp.asarray(_live_table(rs, b, columns, columns, P))
+    f = jax.jit(lambda *a: paged_decode_attention(*a, return_stats=stats))
+    full = jnp.asarray([columns * page, 1], jnp.int32)
+    want = paged_decode_attention_reference(q, k, v, table, full)
+    for lengths in (full, full + jnp.asarray([300, 0], jnp.int32)):
+        got = f(q, k, v, table, lengths)
+        np.testing.assert_allclose(
+            np.asarray(got[0] if stats else got), np.asarray(want),
+            atol=1e-5, rtol=1e-5)
+
+
+def _attend_programs(fn, *args):
+    """The grid of every pallas_call in ``fn``'s jaxpr, in order."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_programs_do_not_follow_the_table_width(fused):
+    """The structural half of ISSUE 30: at fixed lengths the attend's
+    `pallas_call` has the same number of programs for a 4-column and a
+    32-column table (one a slot and head block; the page walk is the
+    program's own loop), and at the default geometry that is one a
+    slot where the heads fit VMEM."""
+    rs = np.random.RandomState(5)
+    P, hkv, page, d, b = 6, 4, 128, 64, 3
+    k, v = _pool(rs, P, hkv, page, d)
+    q = jnp.asarray(rs.randn(b, hkv, d), jnp.float32)
+    row = jnp.asarray(rs.randn(b, hkv, d), jnp.float32)
+    lengths = jnp.asarray([130, 0, 77], jnp.int32)
+    grids = {}
+    for columns in (4, 32):
+        table = jnp.asarray(_live_table(rs, b, columns, 2, P))
+        if fused:
+            grids[columns] = _attend_programs(
+                paged_append_attend, q, k, v, row, row, table,
+                table[:, 0], lengths)
+        else:
+            grids[columns] = _attend_programs(
+                paged_decode_attention, q, k, v, table, lengths)
+    assert grids[4] == grids[32]
+    # write launch (fused only) and attend: one program a slot each
+    assert grids[4] == [(b,)] * (2 if fused else 1)
+
+
+@pytest.mark.parametrize("budget_mb,want", [("16", 16), ("1.5", 4),
+                                            ("0.6", 1)])
+def test_default_head_block_follows_the_vmem_budget(monkeypatch,
+                                                    budget_mb, want):
+    """The default head block is the largest divisor of Hkv whose
+    blocks fit `kernelmodel.vmem_budget_bytes()`: all 16 heads at GPT-3
+    XL's shapes under the 16 MiB default, fewer under a smaller budget,
+    one at the floor (never a refusal)."""
+    from paddle_tpu.ops.pallas.paged_attention import (_resolve_config,
+                                                       _vmem_bytes)
+    from paddle_tpu.analysis import kernelmodel as km
+    monkeypatch.setenv("PT_VMEM_BUDGET_MB", budget_mb)
+    for fused in (False, True):
+        ppp, hb = _resolve_config(None, None, 128, 16, 128, jnp.bfloat16,
+                                  1, 16, fused)
+        assert (ppp, hb) == (1, want)
+    if want > 1:
+        assert _vmem_bytes(1, want, 128, 128, jnp.bfloat16,
+                           1) <= km.vmem_budget_bytes()
+    # an explicit head block is the caller's, clamped to a divisor
+    assert _resolve_config(2, 6, 128, 16, 128, jnp.bfloat16, 1, 16,
+                           True) == (2, 4)
+
+
+@pytest.mark.parametrize("rem", [15, 16, 17, 100])
+def test_append_attend_bf16_pool_tile_offsets(rem):
+    """The write launch moves the sublane tile that holds the fresh row
+    (16 rows of a bf16 pool), not the page: offsets at a tile's last
+    row, its first, its second and mid-page, pools bit for bit against
+    the scatter, one row changed."""
+    rs = np.random.RandomState(40 + rem)
+    P, hkv, page, d, b = 5, 4, 128, 64, 2
+    k, v = _pool(rs, P, hkv, page, d, jnp.bfloat16)
+    q = jnp.asarray(rs.randn(b, hkv, d), jnp.bfloat16)
+    table = jnp.asarray([[0, 3], [2, 1]], jnp.int32)
+    lengths = jnp.asarray([page + rem, rem], jnp.int32)
+    k_row = jnp.asarray(rs.randn(b, hkv, d), jnp.bfloat16)
+    v_row = jnp.asarray(rs.randn(b, hkv, d), jnp.bfloat16)
+    wpids = jnp.asarray([3, 2], jnp.int32)
+    o, k_out, v_out = jax.jit(paged_append_attend)(
+        q, k, v, k_row, v_row, table, wpids, lengths)
+    k2, v2 = _scatter_oracle(k, v, k_row, v_row, table, wpids, lengths,
+                             page)
+    for got, want, was in ((k_out, k2, k), (v_out, v2, v)):
+        got, want, was = (np.asarray(a.astype(jnp.float32))
+                          for a in (got, want, was))
+        np.testing.assert_array_equal(got, want)
+        assert np.flatnonzero(np.any(got != was,
+                                     axis=(0, 1, 3))).tolist() == [rem]
+    want = paged_decode_attention_reference(q, k2, v2, table, lengths + 1)
+    np.testing.assert_allclose(np.asarray(o.astype(jnp.float32)),
+                               np.asarray(want.astype(jnp.float32)),
+                               atol=2e-2, rtol=2e-2)

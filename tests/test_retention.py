@@ -367,4 +367,4 @@ def test_spans_of_the_chunked_prefill(model):
     assert max(s["prefilling"] for s in steps) >= 1
     assert sum(s["prefill_tokens"] for s in steps) == 4 + 2 * CHUNK + 3
     assert sum(s["decode_tokens"] for s in steps) >= 11 + 2
-    assert all(s["pages"] == 0 for s in steps)
+    assert all(s["pages"] == 0 and s["live_pages"] == 0 for s in steps)

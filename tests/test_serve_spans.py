@@ -147,10 +147,18 @@ def test_serving_step_nesting_and_pool_attributes(model):
         # the cache holds every token but the newest of each live slot
         assert tokens - n_live <= a["live_tokens"] <= tokens
         assert a["pages_used"] >= -(-a["live_tokens"] // 128)
+        # the pages the live tokens lie on, a slot at a time: at least
+        # what the tokens would fill packed, at most one part-full page
+        # a live slot more, and never more than the pool has in use
+        assert -(-a["live_tokens"] // 128) <= a["live_pages"] \
+            <= a["live_tokens"] // 128 + n_live
+        assert a["live_pages"] <= a["pages_used"]
     assert any(a[6]["pages_used"] >= 3 for a in steps)   # 140 + 2 slots
     # all retired: what is left is the 140-token prompt's full page, kept
     # warm by the prefix cache
     assert steps[-1][6]["live_tokens"] == 0
+    assert steps[-1][6]["live_pages"] == 0
+    assert max(a[6]["live_pages"] for a in steps) >= 3
     assert steps[-1][6]["pages_used"] == eng.P - eng.free_pages <= 1
 
 
