@@ -187,8 +187,7 @@ def setup(cell, seed, spans):
             f"({longest} tokens) in all {eng.S} slots at once: a decode "
             f"step could run out of pages")
     harness.say(
-        f"serve: engine {type(eng).__name__}, decode path "
-        f"{'megakernel' if eng.mega else 'per-layer fused' if eng.fused else 'unfused'}, "
+        f"serve: engine {type(eng).__name__}, "
         f"{eng.S} slots, {eng.P} pages of {eng.page}, buckets {eng.buckets}, "
         f"in-flight depth {eng.depth}; weights made in {t_weights:.1f} s, "
         f"model and engine built in "

@@ -1,5 +1,6 @@
 """The trace reduction on the small recorded trace: idle share, time per
-operation name and gap labels are the hand-checked values.
+operation name and gap labels are the hand-checked values; and on one
+made-up serving step, where the program's ``serve/`` spans name the gaps.
 
 The fixture (``fixtures/v5e_train_step.trace.json``) keeps the operation
 names as a v5e recorded them in PR 24's first chip run; its times are
@@ -64,6 +65,58 @@ def test_gaps_are_labelled_by_the_host_span(reduced):
     assert b["device_ops"][0] == ["flash_attention_fwd.17",
                                   pytest.approx(4.0e-3)]
     assert b["idle_gaps"] == [["bench/loss_fetch", pytest.approx(7.0e-3)]]
+
+
+def _serving_trace(with_program_spans=True):
+    """One serving step in the neutral form, times in ms: the device runs
+    0..1, 3..4, 9..10, 12..13 and 15..16 of a window of 0..20, so the gaps
+    are 1..3, 4..9, 10..12, 13..15 and 16..20."""
+    ms = 1e6
+    ops = [("%fusion.1 = bf16[8]{0} fusion(%p)", t * ms, 1 * ms)
+           for t in (0, 3, 9, 12, 15)]
+    host = [("bench/traced_window", 0, 20 * ms),
+            ("bench/frontend_step", 0, 16.5 * ms),
+            ("bench/harvest", 16.5 * ms, 1 * ms)]
+    if with_program_spans:
+        host += [("serve/frontend_step", 0.1 * ms, 16.3 * ms),
+                 ("serve/step", 0.2 * ms, 16.1 * ms),
+                 ("serve/admit", 0.5 * ms, 8.5 * ms),         # 0.5 .. 9
+                 ("serve/dispatch", 4 * ms, 4 * ms),          # 4 .. 8
+                 ("serve/harvest", 13.2 * ms, 3 * ms),        # 13.2 .. 16.2
+                 ("serve/device_wait", 13.4 * ms, 0.4 * ms)]
+    return {"/device:TPU:0": {"XLA Ops": ops}, "/host:CPU": {"python": host}}
+
+
+def test_gaps_are_labelled_by_the_innermost_program_span():
+    reduced = trace_reduce.reduce_trace(_serving_trace())
+    labels = sorted((round(s * 1e3, 3), label)
+                    for label, s in reduced["gaps"])
+    assert labels == [
+        (2.0, "serve/admit"),    # 1..3: inside the admission, no dispatch
+        (2.0, "serve/harvest"),  # 13..15: 1.8 of 2 in harvest; the wait
+                                 # inside it is too short to hold half
+        (2.0, "serve/step"),     # 10..12: the step, nothing inside it
+        (4.0, "bench/harvest"),  # 16..20: no span holds half of it
+        (5.0, "serve/dispatch"),
+    ]
+
+
+def test_program_spans_change_the_labels_and_nothing_else():
+    """What ``bench/frontend_step`` read before, the ``serve/`` labels sum
+    to now; gaps, busy time and the idle share are the same numbers."""
+    before = trace_reduce.reduce_trace(_serving_trace(False))
+    after = trace_reduce.reduce_trace(_serving_trace(True))
+    for key in ("window_s", "busy_s", "idle_share", "op_self_s", "op_calls"):
+        assert before[key] == after[key]
+    assert sorted(s for _, s in before["gaps"]) \
+        == sorted(s for _, s in after["gaps"])
+    old = dict(trace_reduce.breakdown(before)["idle_gaps"])
+    new = dict(trace_reduce.breakdown(after)["idle_gaps"])
+    assert old == {"bench/frontend_step": pytest.approx(11e-3),
+                   "bench/harvest": pytest.approx(4e-3)}
+    assert sum(s for label, s in new.items() if label.startswith("serve/")) \
+        == pytest.approx(old["bench/frontend_step"])
+    assert new["bench/harvest"] == pytest.approx(old["bench/harvest"])
 
 
 def test_no_device_operation_is_an_error():

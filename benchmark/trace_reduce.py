@@ -5,15 +5,18 @@
 (b) device time per operation name (self time: an operation that contains
     others, such as a ``while``, is charged only what its children leave),
     with the number of calls;
-(c) the idle gaps longer than ``MIN_GAP_S``, each labelled by the
-    benchmark's own ``bench/...`` span that the host was in.
+(c) the idle gaps longer than ``MIN_GAP_S``, each labelled by what the
+    host was doing: the innermost of the program's ``serve/...`` spans and
+    the benchmark's own ``bench/...`` spans that covers most of the gap.
 
 What a v5e trace looks like (looked at by hand, PR 24): the device is the
 plane ``/device:TPU:<n>``; its line ``XLA Ops`` holds one event per executed
 HLO operation, named by the HLO text (``%flash_attention_fwd.17 = (...)
 custom-call(...)``), properly nested; ``XLA Modules`` holds one event per
 program run. ``jax.profiler.TraceAnnotation`` spans land on the plane
-``/host:CPU``, line ``python``, on the same clock.
+``/host:CPU``, line ``python``, on the same clock: the benchmark's own
+(``harness.Spans``) and, while a profiler session runs, the program's
+(``paddle_tpu/observability/trace.py``), each under its own name.
 
 The reduction works on a neutral form, ``{plane: {line: [(name, start_ns,
 dur_ns), ...]}}``, so that the recorded fixture in ``tests/`` is a small
@@ -28,7 +31,7 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
-SPAN_PREFIX = "bench/"
+SPAN_PREFIXES = ("bench/", "serve/")      # the benchmark's, the program's
 WINDOW_SPAN = "bench/traced_window"
 MIN_GAP_S = 0.0005
 UNLABELLED = "host:outside_bench_spans"
@@ -44,7 +47,8 @@ def find_xplane(trace_dir: str) -> str:
 
 def load_xplane(path: str) -> dict:
     """Read an ``.xplane.pb`` into the neutral form, keeping only the
-    device planes' operation lines and the host's ``bench/`` spans."""
+    device planes' operation lines and the host's ``bench/`` and
+    ``serve/`` spans."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     out = {}
@@ -52,7 +56,7 @@ def load_xplane(path: str) -> dict:
         if DEVICE_PLANE.match(plane.name):
             keep = lambda line, ev: line.name == OPS_LINE
         elif plane.name == HOST_PLANE:
-            keep = lambda line, ev: ev.name.startswith(SPAN_PREFIX)
+            keep = lambda line, ev: ev.name.startswith(SPAN_PREFIXES)
         else:
             continue
         lines = {}
@@ -112,19 +116,28 @@ def _self_times(events):
 
 def _spans(trace):
     return [ev for events in trace.get(HOST_PLANE, {}).values()
-            for ev in events if ev[0].startswith(SPAN_PREFIX)]
+            for ev in events if ev[0].startswith(SPAN_PREFIXES)]
 
 
 def _label(gap_start, gap_end, spans):
-    """The ``bench/`` span (other than the window's own) that covers most
-    of the gap."""
-    best, best_overlap = UNLABELLED, 0.0
+    """The innermost span (other than the window's own) that covers most
+    of the gap: the shortest of those that hold more than half of it, so a
+    gap inside an admission reads ``serve/admit`` and not the
+    ``bench/frontend_step`` around it. Where no span holds half, the one
+    that holds most."""
+    half = 0.5 * (gap_end - gap_start)
+    best, best_key = UNLABELLED, (False, 0.0)
     for name, start, dur in spans:
         if name == WINDOW_SPAN:
             continue
         overlap = min(gap_end, start + dur) - max(gap_start, start)
-        if overlap > best_overlap:
-            best, best_overlap = name, overlap
+        if overlap <= 0:
+            continue
+        # covering spans before the others; among them the shortest,
+        # among the others the largest overlap
+        key = (True, -dur) if overlap > half else (False, overlap)
+        if key > best_key:
+            best, best_key = name, key
     return best
 
 
