@@ -788,10 +788,9 @@ def bench_decode(jax, jnp, peak, smoke=False):
                 devprof.launch_tax_fraction(disp, wall, name=key), 4)
             # kernel launches per generated token (ISSUE 19): pallas
             # launches in the dispatch program (scan-trip weighted,
-            # counted from the jaxpr without executing) — the
-            # single-dispatch megakernel claim as a LOWER-direction
-            # ladder row, with the per-step count alongside (mega
-            # paged step = 2: layer-folded kernel + sampling epilogue)
+            # counted from the jaxpr without executing) as a
+            # LOWER-direction ladder row, with the per-step count
+            # alongside (the paged step: two launches a layer)
             try:
                 fn, fargs = e.dispatch_fn_args()
                 lpc = devprof.count_pallas_launches(fn, *fargs)
@@ -1013,11 +1012,8 @@ def bench_decode(jax, jnp, peak, smoke=False):
         res["decode_spec_error"] = str(e)[:160]
 
     # speculative decoding on the PAGED engine (ISSUE 19): the same
-    # repetition-heavy workload through the engine's DEFAULT decode
-    # step (per-layer fused since PR 21 — the path the chip compiles);
-    # launches_per_step reports what that path costs per verify. The
-    # megakernel's 2-launch bound is asserted where it is asked for by
-    # name (tests/test_paged_mega.py). This row died in r05
+    # repetition-heavy workload through the paged engine;
+    # launches_per_step reports what a verify costs. This row died in r05
     # (RESOURCE_EXHAUSTED killed the engine build and the old suite had
     # no paged-spec row to notice); it is guarded by name in
     # tools/bench_diff.py.

@@ -140,21 +140,18 @@ def _batch(cfg, seed):
 
 
 def _print_block_sizes(cfg):
-    """Block sizes come from code defaults (the autotune cache was
+    """Block sizes come from code defaults (flash's autotune cache was
     cleared: no earlier sweep on this disk, and no timing sweep here,
-    picks them)."""
+    picks them; the paged kernels read no cache)."""
     from paddle_tpu.ops.pallas.flash_attention import _DEFAULT_BLOCKS
     from paddle_tpu.ops.pallas.fused_ce import _pick_block_v
-    from paddle_tpu.ops.pallas.paged_attention import _resolve_config
-    mx = math.ceil(cfg.max_seq_len / PAGE)
-    ppp, hb = _resolve_config(
-        None, None, PAGE, cfg.kv_heads, cfg.head_dim, cfg.dtype,
-        cfg.n_heads // cfg.kv_heads, mx, True)
+    from paddle_tpu.ops.pallas.paged_attention import _default_head_block
+    hb = _default_head_block(PAGE, cfg.kv_heads, cfg.head_dim, cfg.dtype,
+                             cfg.n_heads // cfg.kv_heads)
     say(f"kernel blocks (code defaults): flash (block_q, block_k)="
         f"{_DEFAULT_BLOCKS}; fused_ce (block_n, block_v)="
         f"(128, {_pick_block_v(cfg.vocab_size, 512)}); "
-        f"paged_append_attend (pages_per_program, head_block)="
-        f"({ppp}, {hb})")
+        f"paged_append_attend head_block={hb}")
 
 
 def _train_one_chip(model, seed, steps):
@@ -244,9 +241,8 @@ def phase_serve(model, seed):
                for n in PROMPT_LENS]
     eng = PagedDecodeEngine(model, n_pages=SLOTS * POOL_TOKENS_PER_SLOT
                             // PAGE, max_slots=SLOTS, page_size=PAGE)
-    say(f"serve: default PagedDecodeEngine decode path: "
-        f"{'megakernel' if eng.mega else 'per-layer fused' if eng.fused else 'unfused'}"
-        f" (page {eng.page}, {eng.S} slots, {eng.P} pages)")
+    say(f"serve: PagedDecodeEngine (page {eng.page}, {eng.S} slots, "
+        f"{eng.P} pages)")
     fn, args = eng.dispatch_fn_args()
     _require_kernels(fn.lower(*args).compile().as_text(),
                      ("paged_append_attend",), "decode step")

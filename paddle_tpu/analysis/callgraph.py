@@ -22,7 +22,9 @@ Bare-name uses are first run through local reference aliases
 ``_traced``, NOT to every function named ``step``) — the one spot where
 precision beats over-approximation, because a false jit root drags a
 host-only method into trace scope and produces false PT001/PT003
-findings on it.
+findings on it. For the same reason a call through a deeper chain
+(``self.kind.step(...)``) never resolves to a method of the caller's
+own class: it is the member's method that runs.
 
 Reachability (`reachable`) walks call edges plus the
 parent→nested-function edge: a ``def one(carry, _)`` defined inside a
@@ -385,8 +387,13 @@ class CallGraph:
             seen.add(fn)
             frontier.extend(fn.children)
             for base, name in fn.calls:
-                frontier.extend(
-                    self.resolve_edge(base, name, fn.ctx.relpath))
+                targets = self.resolve_edge(base, name, fn.ctx.relpath)
+                if base == "<expr>" and fn.cls:
+                    # ``self.kind.step(...)`` calls the MEMBER's step,
+                    # never the caller's own class's: that false edge
+                    # drags a host scheduler into trace scope
+                    targets = [t for t in targets if t.cls != fn.cls]
+                frontier.extend(targets)
         return seen
 
     def jit_scope(self) -> Set[FunctionInfo]:

@@ -396,7 +396,7 @@ LADDER: Dict[str, Dict[str, Any]] = {
 
 KERNEL_MODULES = (
     "flash_attention", "decode_attention", "paged_attention",
-    "decode_megakernel", "fused_ce", "layer_norm", "quant_matmul")
+    "fused_ce", "layer_norm", "quant_matmul")
 
 
 @dataclasses.dataclass

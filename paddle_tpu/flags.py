@@ -442,26 +442,9 @@ declare_env("PT_FLEET_PREFIX", "0 disables the fleet-wide prefix-cache "
             "directory (publication, lookup, and the router's "
             "pre-placement consult) — replicas fall back to local "
             "radix caches only.", default="1", owner="serving/disagg.py")
-declare_env("PT_PAGED_FUSED", "0 disables the fused append+attend paged "
-            "decode kernel, restoring the read-only-pool + one-scatter-"
-            "per-token formulation (the parity reference).", default="1",
-            owner="inference/paged_engine.py")
 declare_env("PT_PAGED_PREFIX", "0 disables prefix (radix) caching over "
             "the page pool — every prompt prefills cold and retirement "
             "frees pages instead of keeping them warm.", default="1",
-            owner="inference/paged_engine.py")
-declare_env("PT_PAGED_TUNE", "1 runs paged-kernel autotuning "
-            "(pages_per_program, head_block) from the engine "
-            "constructor, before any trace picks up the config.",
-            default="0", owner="inference/paged_engine.py")
-declare_env("PT_PAGED_MEGA", "1 asks for the single-dispatch decode "
-            "megakernel (layer-folded layers + fused sampling "
-            "epilogue, 2 launches/step) in place of the default "
-            "per-layer fused path (two paged launches per layer). The "
-            "v5e compiler refuses the megakernel today (weight slab "
-            "over VMEM at 1.3B; a dynamic_slice Mosaic does not "
-            "lower), so it is interpret-mode only and its error "
-            "propagates on a chip.", default="0",
             owner="inference/paged_engine.py")
 declare_env("PT_SERVE_ENGINE", "Default serving engine for the "
             "front-end/bench ladder: 'paged' (default) or 'contiguous' "
@@ -546,8 +529,7 @@ declare_env("PD_SIZE", "profile_decode model size: 1p3b (default), "
             "350m, or tiny (the CPU smoke).", default="1p3b",
             owner="tools/profile_decode.py")
 declare_env("PD_SECTIONS", "Comma-set of profile_decode report "
-            "sections: engine, paged, prof, mega (launches/step "
-            "accounting for the single-dispatch megakernel).",
+            "sections: engine, paged, prof.",
             default="engine,paged", owner="tools/profile_decode.py")
 declare_env("PD_INFLIGHT", "Comma-list of pipeline depths to sweep "
             "(e.g. 1,2,4); unset uses the engine default.",

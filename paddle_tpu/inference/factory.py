@@ -1,11 +1,10 @@
 """Default-engine factory for the serving surface (ISSUE 19).
 
 PAGED is the default serving engine for the front-end and the bench
-ladder (its decode step is the per-layer fused append+attend path; the
-single-dispatch megakernel is opt-in, see docs/serving.md). The
-slot-contiguous `DecodeEngine` stays available behind
-``PT_SERVE_ENGINE=contiguous`` (or ``engine="contiguous"``): it is
-the sampling-policy surface (temperature/top-k live there), for
+ladder (its decode step is `paged_append_attend` once a layer, see
+docs/serving.md). The slot-contiguous `DecodeEngine` stays available
+behind ``PT_SERVE_ENGINE=contiguous`` (or ``engine="contiguous"``): it
+is the sampling-policy surface (temperature/top-k live there), for
 models whose layers keep keys and values. Which engine is faster on
 the chip: not measured since PR 6.
 

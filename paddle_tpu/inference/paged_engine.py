@@ -16,27 +16,27 @@ TPU design decisions:
   selects its pages at DMA-schedule time — no per-layer slicing of the
   pool (a lax.dynamic_slice there would copy the full layer pool every
   step).
-- **Fused append+attend decode step** (default; ``PT_PAGED_FUSED=0``
-  falls back): each layer calls `paged_append_attend`, two launches: a
-  small write kernel merges the current token's fresh KV row into its
-  pool page in place (the layer-folded pools are that call's only pool
+- **One decode step**: each layer of a `lax.scan` calls its kind's
+  ``step`` (`models/layer_kinds.py`) with the pools as the carry. A
+  softmax layer calls `paged_append_attend`, two launches: a small
+  write kernel merges the current token's fresh KV row into its pool
+  page in place (the layer-folded pools are that call's only pool
   operands, aliased to its outputs; the write target is derived from
   the block table + per-slot length, inactive slots write the scratch
   page), then the read-only attend runs over the pools the write
-  returned, at ``lengths + 1``. The separate one-batched-scatter-per-
-  cache-per-token the read-only formulation paid (`_write_token_rows`)
-  is gone from the dispatch path, and nothing copies the pools: they
-  stay in place through the layer scan and the chunk scan
-  (`tests/test_chip_compile.py` holds the compiled program to that).
-  History: the original write-first form (per-layer scatter with the
-  pools as layer-scan carry) measured ~0.05x of the HBM roofline on
-  hardware; the read-only-pool form
-  (`paged_decode_attention(return_stats=True)` + `fold_fresh_row` +
-  one scatter per token) measured 0.17x; the single-launch fused kernel
-  (ISSUE 6) took each pool twice in one aliased call, which made XLA
-  copy both whole pools twice per layer — 84% of the GPT-3 XL step on
-  a v5e, a step that followed the pool's size and not the work
-  (PERF.md section 5, PR 26).
+  returned, at ``lengths + 1``, walking the slot's live pages. Nothing
+  copies the pools: they stay in place through the layer scan and the
+  chunk scan (`tests/test_chip_compile.py` holds the compiled program
+  to that).
+  History (the forms are in the tree at `01c360e` and before): a
+  per-layer scatter with the pools as layer-scan carry measured ~0.05x
+  of the HBM roofline on hardware; read-only pools with the fresh row
+  folded in analytically and one scatter per token 0.17x; a
+  single-launch fused kernel (ISSUE 6) took each pool twice in one
+  aliased call, which made XLA copy both whole pools twice per layer,
+  84% of the GPT-3 XL step on a v5e (PERF.md section 5, PR 26); a
+  layer-folded megakernel (two launches a step) that the v5e compiler
+  refused (docs/serving.md).
 - **Prefix/radix caching** (default; ``PT_PAGED_PREFIX=0`` disables):
   the page pool doubles as a shared radix store
   (`inference/prefix_cache.py`). ``submit``'s admission looks up the
@@ -103,10 +103,6 @@ from paddle_tpu.inference.decode_engine import (Request,
                                                 prompt_lookup_draft,
                                                 spec_accept)
 from paddle_tpu.inference.prefix_cache import PrefixCache
-from paddle_tpu.ops.pallas.decode_attention import fold_fresh_row
-from paddle_tpu.ops.pallas.decode_megakernel import (_WEIGHT_ORDER,
-                                                     mega_decode_layers,
-                                                     mega_logits_sample)
 from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
 
 __all__ = ["PagedDecodeEngine"]
@@ -133,21 +129,18 @@ class PagedDecodeEngine(ResilientScheduler):
     Status: greedy output is bit-identical to ``gpt.generate`` across
     page/chunk geometries in interpret mode (f32), and serving HBM
     scales with live tokens. On a chip (one v5e, GPT-3 XL; PERF.md has
-    the step times) the default per-layer fused step compiles and
-    serves, agreeing with ``gpt.generate`` up to bf16 ties. Since PR 26
-    the step no longer copies the pools; what bounds it now is the
-    paged attend itself: one attend launch per layer per token whose grid
-    walks the FULL fixed-width page table (ceil(max_seq_len/page)
-    columns, mostly masked at short lengths)."""
+    the step times) the decode step compiles and serves, agreeing with
+    ``gpt.generate`` up to bf16 ties. The step copies no pool (PR 26)
+    and its attend walks only the pages a slot holds (PR 30); what
+    bounds it now is the weights' read and, between steps, the host's
+    admission work (PERF.md section 5)."""
 
     def __init__(self, model, n_pages: int, max_slots: int = 8,
                  page_size: int = 128, steps_per_call: int = 1,
                  buckets=(16, 32, 64, 128, 256, 512),
                  share_weights_with=None, inflight=None,
-                 warmup: bool = False, fused: Optional[bool] = None,
-                 prefix: Optional[bool] = None,
+                 warmup: bool = False, prefix: Optional[bool] = None,
                  prefill_only: bool = False,
-                 mega: Optional[bool] = None,
                  speculative_k: int = 0,
                  prefill_chunk: int = 512):
         from paddle_tpu import compile_cache
@@ -200,40 +193,13 @@ class PagedDecodeEngine(ResilientScheduler):
         self._step_prefill_tokens = self._step_decode_tokens = 0
         from paddle_tpu.ops.pallas.paged_attention import PageAllocator
         self._alloc = PageAllocator(self.P, self.page)
-        # fused append+attend is the default; PT_PAGED_FUSED=0 restores
-        # the read-only-pool + one-scatter-per-token formulation (the
-        # parity reference the fused path is tested against)
-        self.fused = (os.environ.get("PT_PAGED_FUSED", "1") != "0"
-                      if fused is None else bool(fused))
-        # THE decode-step choice (docs/serving.md "Single-dispatch
-        # decode"). Default: the per-layer fused path — one
-        # `paged_append_attend` (row write + attend, two launches) per
-        # layer inside a lax.scan —
-        # because it is the one the v5e compiler accepts
-        # (tests/test_chip_compile.py; PR 21 chip run). The layer-folded
-        # megakernel is opt-in (mega=True or PT_PAGED_MEGA=1): it
-        # streams a layer's whole weight slab per grid step, 192 MiB
-        # double-buffered at 1.3B widths against 128 MiB of VMEM, and
-        # its row indexing uses a dynamic_slice Mosaic does not lower,
-        # so today it runs in interpret mode only. Asking for it where
-        # the compiler refuses raises the compiler's error at first
-        # dispatch — it is never swapped for another path.
-        if mega is None:
-            mega = os.environ.get("PT_PAGED_MEGA", "0") == "1"
-        if mega and not self.fused:
-            raise ValueError("mega=True needs the fused append+attend "
-                             "path (fused=False / PT_PAGED_FUSED=0 "
-                             "excludes it)")
-        self.mega = bool(mega)
-        if self.state and (self.mega or not self.fused or speculative_k):
+        if self.state and speculative_k:
             raise NotImplementedError(
-                f"{self.kind.name} layers are served by the per-layer "
-                f"fused step only (no megakernel, unfused or "
-                f"speculative path)")
-        # speculative decode rides the paged step (r05 retired the
-        # contiguous-only row): drafts come from the shared on-device
-        # prompt-lookup helper, and with mega on, verify/accept run as
-        # the SAME single-dispatch program at K rows per slot
+                f"{self.kind.name} layers are served by the plain decode "
+                f"step only (no speculative path)")
+        # speculative decode rides the paged step: drafts come from the
+        # shared on-device prompt-lookup helper, verify is one per-layer
+        # pass at K rows per slot
         self.spec_k = int(speculative_k)
         if self.spec_k and self.spec_k < 2:
             raise ValueError("speculative_k must be >= 2 (one input "
@@ -302,11 +268,6 @@ class PagedDecodeEngine(ResilientScheduler):
         self._table_dev = None       # cached device page table
         self._table_dirty = True
         self._update_pool_gauges()
-        if os.environ.get("PT_PAGED_TUNE", "0") == "1":
-            # tune BEFORE any trace: the kernels read the tuned
-            # (pages_per_program, head_block) from the autotune cache
-            # at trace time, so warmup traces pick it up
-            self.autotune()
         if warmup:
             self.warmup()
 
@@ -411,44 +372,6 @@ class PagedDecodeEngine(ResilientScheduler):
         self.kp = self.kp.at[ids].set(0)
         self.vp = self.vp.at[ids].set(0)
 
-    def autotune(self, iters: int = 3, candidates=None):
-        """Measure paged-kernel geometry candidates on this engine's
-        shape family ((page, Hkv, D, dtype, group)) and persist the
-        winner in the autotune cache (`ops/pallas/autotune.py`). Run
-        BEFORE ``warmup()`` / the first request: Pallas grids are
-        trace-time constants, so already-traced dispatch functions keep
-        whatever config they saw. ``PT_PAGED_TUNE=1`` runs this from
-        the constructor automatically."""
-        from paddle_tpu.ops.pallas.paged_attention import (
-            tune_paged_attention)
-        cfg = self.cfg
-        mx = (cfg.max_seq_len + self.page - 1) // self.page
-        # representative shapes: full batch, mid-length rows, distinct
-        # in-range pages (page ids only steer DMA addresses; the values
-        # don't change the kernel's work)
-        q = jnp.zeros((self.S, cfg.n_heads, cfg.head_dim), cfg.dtype)
-        table = jnp.asarray(
-            np.arange(self.S * mx, dtype=np.int32).reshape(self.S, mx)
-            % self.P)
-        lengths = jnp.full((self.S,), max(1, cfg.max_seq_len // 2),
-                           jnp.int32)
-        res = tune_paged_attention(q, self.kp, self.vp, table, lengths,
-                                   fused=self.fused, iters=iters,
-                                   candidates=candidates)
-        if self.mega:
-            # the megakernel's sampling epilogue has its own knob (the
-            # vocab-tile width) keyed on the FOLDED geometry
-            from paddle_tpu.ops.pallas.decode_megakernel import (
-                tune_mega_epilogue)
-            head = self._head
-            x = jnp.zeros((self.S, cfg.d_model), cfg.dtype)
-            w = (head["wte"].T if head["lm_head"] is None
-                 else head["lm_head"])
-            tune_mega_epilogue(x, head["lnf_scale"], head["lnf_bias"],
-                               w, layers=cfg.n_layers, page=self.page,
-                               iters=iters)
-        return res
-
     def _table_array(self) -> jnp.ndarray:
         """(S, max_pages) padded page table at a FIXED width
         (ceil(max_seq_len/page)) so the chunked step never recompiles
@@ -479,153 +402,52 @@ class PagedDecodeEngine(ResilientScheduler):
              else head["lm_head"])
         return x @ w
 
-    def _write_token_rows(self, kp, vp, k_rows, v_rows, table, lengths,
-                          active):
-        """Write one decode step's new KV rows for EVERY layer at once:
-        k_rows/v_rows (L, S, Hkv, D) land at position lengths[s] of
-        slot s (page ids are layer-folded), in a single batched scatter
-        per cache. Writing once per token OUTSIDE the layer scan keeps
-        the pools read-only inside it — carrying the pools through the
-        layer scan with a per-layer scatter is what the first hardware
-        exercise measured at ~0.05x roofline. Inactive slots scatter
-        into the scratch page — their padded tables point at pool page
-        0, which a live sequence may own."""
-        L = k_rows.shape[0]
-        offs = lengths % self.page
-        pidx = lengths // self.page
-        base = jnp.take_along_axis(table, pidx[:, None], axis=1)[:, 0]
-        pids = (jnp.arange(L, dtype=jnp.int32)[:, None] * self.P
-                + base[None, :])
-        pids = jnp.where(active[None, :], pids, self._scratch)
-        offs_all = jnp.broadcast_to(offs[None, :], pids.shape)
-        S = k_rows.shape[1]
-        kp = kp.at[pids.reshape(-1), :, offs_all.reshape(-1), :].set(
-            k_rows.reshape(L * S, *k_rows.shape[2:]))
-        vp = vp.at[pids.reshape(-1), :, offs_all.reshape(-1), :].set(
-            v_rows.reshape(L * S, *v_rows.shape[2:]))
-        return kp, vp
-
     def _one_token(self, head, stacked, kp, vp, state, table, lengths,
                    last, active, poison):
         """Advance every active slot one token. Per-slot ``bad`` flags
         non-finite logits (numerical blowup or injected poison) — the
         slot stops advancing and the host evicts only that request.
 
-        FUSED path (default): each layer runs its kind's ``step``
-        (`models/layer_kinds.py`) with the pools as the layer scan's
-        carry. Softmax layers call `paged_append_attend` — its write
-        launch merges the fresh KV row into its pool page in
-        place (the pools are that
-        call's only pool operands; inactive slots' writes target the
-        scratch page), then its read-only attend runs over the
-        returned pools at ``lengths + 1``. No per-token scatter and no
-        pool copy remain in the dispatch. An inactive slot attends a
-        row nobody wrote (its own page's stale row at ``lengths``):
-        its output is finite garbage that ``nxt``/``bad`` below mask
-        by ``active``. Retention layers call `retention_step`, which
-        updates and reads the per-slot state pools the same way (each
-        handed in once, aliased in to out) and skips inactive slots.
-
-        Fallback (``PT_PAGED_FUSED=0``): the pools stay READ-ONLY
-        inside the layer scan — `paged_decode_attention(return_stats)`
-        plus the analytic `fold_fresh_row`, per-layer rows out as scan
-        ys, ONE batched scatter per cache per token after the scan
-        (the 0.17x-roofline formulation the fused path is parity-tested
-        against)."""
+        Each layer runs its kind's ``step`` (`models/layer_kinds.py`)
+        with the pools as the layer scan's carry. Softmax layers call
+        `paged_append_attend`: its write launch merges the fresh KV row
+        into its pool page in place (the pools are that call's only
+        pool operands; inactive slots' writes target the scratch page),
+        then its read-only attend runs over the returned pools at
+        ``lengths + 1``. No per-token scatter and no pool copy are in
+        the dispatch. An inactive slot attends a row nobody wrote (its
+        own page's stale row at ``lengths``): its output is finite
+        garbage that ``nxt``/``bad`` below mask by ``active``.
+        Retention layers call `retention_step`, which updates and
+        reads the per-slot state pools the same way (each handed in
+        once, aliased in to out) and skips inactive slots."""
         x = jnp.take(head["wte"], last, axis=0)
         if head["wpe"] is not None:
             x = x + jnp.take(head["wpe"], lengths, axis=0)
         x = x[:, None, :]
-        L = self.cfg.n_layers
-        scale = 1.0 / math.sqrt(self.cfg.head_dim)
+        pidx = jnp.minimum(lengths // self.page, table.shape[1] - 1)
+        view = {"table": table, "n_pages": self.P,
+                "scratch": self._scratch,
+                "base": jnp.take_along_axis(table, pidx[:, None],
+                                            axis=1)[:, 0]}
 
-        if self.fused:
-            pidx = jnp.minimum(lengths // self.page, table.shape[1] - 1)
-            view = {"table": table, "n_pages": self.P,
-                    "scratch": self._scratch,
-                    "base": jnp.take_along_axis(table, pidx[:, None],
-                                                axis=1)[:, 0]}
+        def layer_body(carry, blk_i):
+            h, pools = carry
+            blk, i = blk_i
+            attn, pools = self.kind.step(blk, i, h, lengths, active,
+                                         pools, view)
+            return (blk._block_tail(h, attn), pools), None
 
-            def layer_body_fused(carry, blk_i):
-                h, pools = carry
-                blk, i = blk_i
-                attn, pools = self.kind.step(blk, i, h, lengths, active,
-                                             pools, view)
-                return (blk._block_tail(h, attn), pools), None
-
-            (x, pools), _ = lax.scan(
-                layer_body_fused, (x, dict(state, kp=kp, vp=vp)),
-                (stacked, jnp.arange(L)))
-            kp, vp = pools.pop("kp"), pools.pop("vp")
-            state = pools
-        else:
-            def layer_body(h, blk_i):
-                blk, i = blk_i
-                q, k, v = blk._qkv(h, lengths)
-                k_row = k[:, 0].astype(kp.dtype)
-                v_row = v[:, 0].astype(vp.dtype)
-                o, m, l = paged_decode_attention(
-                    q[:, 0].astype(kp.dtype), kp, vp, i * self.P + table,
-                    lengths, scale=scale, return_stats=True)
-                attn = fold_fresh_row(o, m, l, q[:, 0], k_row, v_row,
-                                      scale, blk.n_heads // blk.kv_heads)
-                attn = attn.astype(h.dtype).reshape(h.shape)
-                h = blk._block_tail(h, attn)
-                return h, (k_row, v_row)
-
-            x, (k_rows, v_rows) = lax.scan(
-                layer_body, x, (stacked, jnp.arange(L)))
-            kp, vp = self._write_token_rows(kp, vp, k_rows, v_rows,
-                                            table, lengths, active)
+        (x, pools), _ = lax.scan(
+            layer_body, (x, dict(state, kp=kp, vp=vp)),
+            (stacked, jnp.arange(self.cfg.n_layers)))
+        kp, vp = pools.pop("kp"), pools.pop("vp")
+        state = pools
         logits = self._lm_head(head, x)[:, 0]
         logits = jnp.where(poison[:, None], jnp.nan, logits)
         bad = active & ~jnp.all(jnp.isfinite(logits), axis=-1)
         nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(jnp.int32)
         nxt = jnp.where(active & ~bad, nxt, last)
-        lengths = lengths + (active & ~bad).astype(jnp.int32)
-        return kp, vp, state, lengths, nxt, bad
-
-    def _mega_rows(self, head, stacked, kp, vp, table, pos, row_slot,
-                   row_write, tokens, poison_rows):
-        """One megakernel pass over a flat row batch: embed ``tokens``
-        at ``pos``, run every layer + the fused final-norm → logits →
-        greedy-sampling epilogue as TWO kernel launches total, and
-        return the sampled token + non-finite flag per row. The plain
-        step is one row per slot; the speculative verify is K rows per
-        slot through the SAME program (write-then-attend is causal:
-        row t's attention bound pos+1 masks rows t' > t)."""
-        cfg = self.cfg
-        x = jnp.take(head["wte"], tokens, axis=0)
-        if head["wpe"] is not None:
-            x = x + jnp.take(head["wpe"], pos, axis=0)
-        weights = {n: getattr(stacked, n) for n in _WEIGHT_ORDER}
-        x, kp, vp = mega_decode_layers(
-            x, weights, kp, vp, table, pos, row_slot, row_write,
-            page=self.page, n_pages=self.P, n_heads=cfg.n_heads,
-            kv_heads=cfg.kv_heads, head_dim=cfg.head_dim,
-            rope=cfg.rope, rope_theta=cfg.rope_theta,
-            scale=1.0 / math.sqrt(cfg.head_dim))
-        w = (head["wte"].T if head["lm_head"] is None
-             else head["lm_head"])
-        tok, nf = mega_logits_sample(
-            x, head["lnf_scale"], head["lnf_bias"], w, poison_rows,
-            layers=cfg.n_layers, page=self.page)
-        return kp, vp, tok, nf
-
-    def _one_token_mega(self, head, stacked, kp, vp, state, table,
-                        lengths, last, active, poison):
-        """Single-dispatch variant of `_one_token` (``PT_PAGED_MEGA``):
-        same signature, same greedy stream, ≤2 kernel launches. The
-        per-layer fused path above stays as the parity reference
-        (token streams identical; both fold the fresh KV row in page
-        order, but as differently shaped accumulations, so layer>=1
-        pool rows may differ in the final bit)."""
-        kp, vp, tok, nf = self._mega_rows(
-            head, stacked, kp, vp, table, lengths,
-            jnp.arange(self.S, dtype=jnp.int32),
-            active.astype(jnp.int32), last, poison)
-        bad = active & (nf > 0)
-        nxt = jnp.where(active & ~bad, tok, last)
         lengths = lengths + (active & ~bad).astype(jnp.int32)
         return kp, vp, state, lengths, nxt, bad
 
@@ -638,11 +460,10 @@ class PagedDecodeEngine(ResilientScheduler):
         one (3, chunk, S) int32 array — the lagged harvest pays exactly
         one device→host transfer."""
         _note_retrace("paged_multi")
-        one_tok = self._one_token_mega if self.mega else self._one_token
 
         def one(carry, _):
             kp, vp, state, lengths, last, active, remaining = carry
-            kp, vp, state, lengths, nxt, bad = one_tok(
+            kp, vp, state, lengths, nxt, bad = self._one_token(
                 head, stacked, kp, vp, state, table, lengths, last,
                 active, poison)
             emit = active & ~bad
@@ -663,63 +484,50 @@ class PagedDecodeEngine(ResilientScheduler):
     def _verify_paged(self, head, stacked, kp, vp, table, lengths,
                       cand, active, poison):
         """One speculative verify over the page pool: K candidate
-        tokens per slot in one pass. With mega on, the K rows per slot
-        ride the SAME single-dispatch megakernel program as the plain
-        step (flat (S*K) row batch, per-row position/slot); otherwise
-        a per-layer XLA reference (batched pool scatter + the paged
-        read kernel at one query row per candidate) — the parity
-        target the mega verify is tested against. Returns the model's
-        predictions (S, K), the accepted-prefix length n_acc (0..K-1)
-        and the per-slot non-finite flag, exactly like
+        tokens per slot in one per-layer pass (batched pool scatter +
+        the paged read kernel at one query row per candidate). Returns
+        the model's predictions (S, K), the accepted-prefix length
+        n_acc (0..K-1) and the per-slot non-finite flag, exactly like
         `DecodeEngine._verify_impl`."""
         S, K = cand.shape
         cfg = self.cfg
         pos = lengths[:, None] + jnp.arange(K)              # (S, K)
-        if self.mega:
-            kp, vp, tok, nf = self._mega_rows(
-                head, stacked, kp, vp, table, pos.reshape(-1),
-                jnp.repeat(jnp.arange(S, dtype=jnp.int32), K),
-                jnp.repeat(active.astype(jnp.int32), K),
-                cand.reshape(-1), jnp.repeat(poison, K))
-            pred = tok.reshape(S, K)
-            bad = jnp.any((nf > 0).reshape(S, K), axis=1)
-        else:
-            x = jnp.take(head["wte"], cand, axis=0)
-            if head["wpe"] is not None:
-                x = x + jnp.take(head["wpe"], pos, axis=0)
-            scale = 1.0 / math.sqrt(cfg.head_dim)
-            mx = table.shape[1]
-            pidx = jnp.minimum(pos // self.page, mx - 1)
-            pages = jnp.take_along_axis(table, pidx, axis=1)
-            offs = (pos % self.page).reshape(-1)
-            lens_t = (pos + 1).reshape(-1)
+        x = jnp.take(head["wte"], cand, axis=0)
+        if head["wpe"] is not None:
+            x = x + jnp.take(head["wpe"], pos, axis=0)
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+        mx = table.shape[1]
+        pidx = jnp.minimum(pos // self.page, mx - 1)
+        pages = jnp.take_along_axis(table, pidx, axis=1)
+        offs = (pos % self.page).reshape(-1)
+        lens_t = (pos + 1).reshape(-1)
 
-            def layer(carry, blk_i):
-                x, kp, vp = carry
-                blk, i = blk_i
-                q, k, v = blk._qkv(x, lengths)
-                rows = jnp.where(active[:, None], i * self.P + pages,
-                                 self._scratch).reshape(-1)
-                kp = kp.at[rows, :, offs, :].set(
-                    k.reshape(S * K, cfg.kv_heads,
-                              cfg.head_dim).astype(kp.dtype))
-                vp = vp.at[rows, :, offs, :].set(
-                    v.reshape(S * K, cfg.kv_heads,
-                              cfg.head_dim).astype(vp.dtype))
-                o = paged_decode_attention(
-                    q.reshape(S * K, cfg.n_heads,
-                              cfg.head_dim).astype(kp.dtype),
-                    kp, vp, jnp.repeat(i * self.P + table, K, axis=0),
-                    lens_t, scale=scale)
-                attn = o.astype(x.dtype).reshape(x.shape)
-                return (blk._block_tail(x, attn), kp, vp), None
+        def layer(carry, blk_i):
+            x, kp, vp = carry
+            blk, i = blk_i
+            q, k, v = blk._qkv(x, lengths)
+            rows = jnp.where(active[:, None], i * self.P + pages,
+                             self._scratch).reshape(-1)
+            kp = kp.at[rows, :, offs, :].set(
+                k.reshape(S * K, cfg.kv_heads,
+                          cfg.head_dim).astype(kp.dtype))
+            vp = vp.at[rows, :, offs, :].set(
+                v.reshape(S * K, cfg.kv_heads,
+                          cfg.head_dim).astype(vp.dtype))
+            o = paged_decode_attention(
+                q.reshape(S * K, cfg.n_heads,
+                          cfg.head_dim).astype(kp.dtype),
+                kp, vp, jnp.repeat(i * self.P + table, K, axis=0),
+                lens_t, scale=scale)
+            attn = o.astype(x.dtype).reshape(x.shape)
+            return (blk._block_tail(x, attn), kp, vp), None
 
-            (x, kp, vp), _ = lax.scan(
-                layer, (x, kp, vp), (stacked, jnp.arange(cfg.n_layers)))
-            logits = self._lm_head(head, x).astype(jnp.float32)
-            logits = jnp.where(poison[:, None, None], jnp.nan, logits)
-            bad = ~jnp.all(jnp.isfinite(logits), axis=(1, 2))
-            pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        (x, kp, vp), _ = lax.scan(
+            layer, (x, kp, vp), (stacked, jnp.arange(cfg.n_layers)))
+        logits = self._lm_head(head, x).astype(jnp.float32)
+        logits = jnp.where(poison[:, None, None], jnp.nan, logits)
+        bad = ~jnp.all(jnp.isfinite(logits), axis=(1, 2))
+        pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         match = jnp.cumprod(
             (cand[:, 1:] == pred[:, :-1]).astype(jnp.int32), axis=1)
         n_acc = jnp.sum(match, axis=1)                      # 0..K-1
@@ -1996,9 +1804,8 @@ class PagedDecodeEngine(ResilientScheduler):
 
     def dispatch_cost(self, name=None):
         """ISSUE 15 roofline capture for the paged path: AOT
-        cost/memory analysis of one paged decode dispatch (megakernel
-        when PT_PAGED_MEGA, fused append+attend when PT_PAGED_FUSED,
-        the speculative verify program when ``speculative_k``) at the
+        cost/memory analysis of one paged decode dispatch (the
+        speculative verify program when ``speculative_k``) at the
         current pool/table geometry. See DecodeEngine.dispatch_cost."""
         from paddle_tpu.observability import devprof
         if self.spec_k:
@@ -2015,8 +1822,9 @@ class PagedDecodeEngine(ResilientScheduler):
 
     def dispatch_fn_args(self):
         """The decode dispatch's (jitted fn, args) at the current
-        geometry — what `tools/profile_decode.py`'s launches/step
-        section lowers to count kernel launches without executing."""
+        geometry (the speculative verify's when ``speculative_k``):
+        what `bench.py` and the tests lower to count kernel launches
+        without executing, and `chip_smoke.py` compiles."""
         if self.spec_k:
             return (self._verify_fn,
                     (self._head, self._stacked, self.kp, self.vp,

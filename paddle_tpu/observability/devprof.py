@@ -146,8 +146,8 @@ def count_pallas_launches(fn, *args, **kwargs) -> int:
     ONE launch), weighted by the trip count of enclosing ``scan``s —
     so a chunked decode dispatch reports chunk × launches-per-step.
     Backend-independent (interpret-mode pallas_calls count the same),
-    which is what lets the CPU suite assert the single-dispatch
-    contract the ISSUE 19 megakernel exists for. ``while`` bodies
+    which is what lets the CPU suite hold a decode dispatch to its
+    launches per layer. ``while`` bodies
     count once (trip count unknown — a lower bound); ``cond`` branches
     count at the worst case."""
     import jax

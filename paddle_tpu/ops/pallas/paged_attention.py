@@ -42,15 +42,15 @@ Two entry points:
   kernel reads, so the compiler converts it both ways). The data
   dependence (attend reads what the write returned) orders the two.
 
-Both take a ``(pages_per_program, head_block)`` geometry. head_block is
-how many consecutive KV heads of a page one program takes (their rows
-ARE contiguous in the head-major pool view, so a page's head block is
-one copy); by default the largest divisor of Hkv whose blocks fit VMEM
-(`_default_head_block`: all 16 heads at GPT-3 XL), so the fewest
-programs and the longest copies, with no tuner run. pages_per_program
-is how many pages a program keeps in flight ahead of the one it folds
-(1: double buffering). `tune_paged_attention` can still measure both
-and persist a winner; a cache hit overrides the derived default.
+One geometry rule for both. ``head_block`` is how many consecutive KV
+heads of a page one program takes (their rows ARE contiguous in the
+head-major pool view, so a page's head block is one copy): the largest
+divisor of Hkv whose blocks fit VMEM (`_default_head_block`: all 16
+heads at GPT-3 XL), so the fewest programs and the longest copies,
+unless the caller names one. A program keeps `_PAGES_IN_FLIGHT` pages
+ahead of the one it folds (1: double buffering; a second bought nothing
+on a v5e, PERF.md section 6, PR 30). Nothing outside the call's own
+arguments and shapes decides the geometry.
 
 Forward-only (generation never differentiates through the cache).
 """
@@ -64,15 +64,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_reference",
-           "paged_append_attend", "tune_paged_attention", "PagedKVCache"]
+           "paged_append_attend", "PagedKVCache"]
 
 _LANES = 128
 _NEG_INF = float("-inf")
 
-# pages a program keeps in flight ahead of the fold when neither the
-# caller nor the autotune cache says: the next page's copy runs while
-# the current page is folded (double buffering)
-_DEFAULT_PAGES_IN_FLIGHT = 1
+# pages a program keeps in flight ahead of the fold: the next page's
+# copy runs while the current page is folded (double buffering)
+_PAGES_IN_FLIGHT = 1
 
 # heads of a page folded at once (the head block's common divisor with
 # it): one batched `online_softmax_step`, whose vector work then covers
@@ -82,36 +81,29 @@ _DEFAULT_PAGES_IN_FLIGHT = 1
 _HEAD_CHUNK = 8
 
 
-def _tune_key(page, hkv, d, dtype, group, fused):
-    from paddle_tpu.ops.pallas.autotune import AutotuneCache
-    return AutotuneCache.key(
-        "paged_append" if fused else "paged_attention",
-        page=page, hkv=hkv, d=d, dtype=str(dtype), group=group)
-
-
 def _group_rows(group, dtype):
     """A KV head's query rows, padded to the dtype's sublane tile."""
     sub = _sublanes(dtype)
     return max(sub, (group + sub - 1) // sub * sub)
 
 
-def _vmem_bytes(ppp, hb, page, d, dtype, group):
-    """VMEM one program of the attend holds at ``(ppp, hb)``, as
+def _vmem_bytes(hb, page, d, dtype, group):
+    """VMEM one program of the attend holds at head block ``hb``, as
     `analysis/kernelmodel` counts it (pipelined blocks twice, scratch
-    once): K and V landing buffers for ``ppp + 1`` pages; the q and o
-    blocks; the float32 accumulator, (m, l) and the stats block. The
-    write launch shares the head block and holds far less (six sublane
-    tiles of ``hb`` heads)."""
+    once): K and V landing buffers for ``_PAGES_IN_FLIGHT + 1`` pages;
+    the q and o blocks; the float32 accumulator, (m, l) and the stats
+    block. The write launch shares the head block and holds far less
+    (six sublane tiles of ``hb`` heads)."""
     from paddle_tpu.analysis import kernelmodel as km
     isz = km.itemsize(jnp.dtype(dtype))
     rows = hb * _group_rows(group, dtype)
-    return (2 * (ppp + 1) * hb * page * d * isz
+    return (2 * (_PAGES_IN_FLIGHT + 1) * hb * page * d * isz
             + 2 * km.DOUBLE_BUFFER * rows * d * isz
             + rows * d * 4
             + (2 + km.DOUBLE_BUFFER) * rows * _LANES * 4)
 
 
-def _default_head_block(ppp, page, hkv, d, dtype, group):
+def _default_head_block(page, hkv, d, dtype, group):
     """The largest divisor of ``hkv`` whose blocks fit the VMEM budget
     (`kernelmodel.vmem_budget_bytes`: 16 MiB less the compiler's
     reserve): a page of the pool is contiguous over its heads, so the
@@ -121,40 +113,25 @@ def _default_head_block(ppp, page, hkv, d, dtype, group):
     from paddle_tpu.analysis import kernelmodel as km
     budget = km.vmem_budget_bytes()
     for hb in range(hkv, 1, -1):
-        if hkv % hb == 0 and _vmem_bytes(ppp, hb, page, d, dtype,
+        if hkv % hb == 0 and _vmem_bytes(hb, page, d, dtype,
                                          group) <= budget:
             return hb
     return 1
 
 
-def _resolve_config(ppp, hb, page, hkv, d, dtype, group, max_pages,
-                    fused):
-    """Fill unset config knobs: from the autotune cache where a tuner
-    has run (trace-time dict read, ≙ flash_attention's block lookup),
-    else ``pages_per_program`` 1 and the head block derived from the
-    shapes (`_default_head_block`). Clamp to validity:
-    pages_per_program can't exceed the table width, head_block must
-    divide Hkv."""
-    if ppp is None or hb is None:
-        from paddle_tpu.ops.pallas.autotune import get_cache
-        t_ppp, t_hb = get_cache().get(_tune_key(
-            page, hkv, d, dtype, group, fused)) or (None, None)
-        ppp = t_ppp if ppp is None else ppp
-        hb = t_hb if hb is None else hb
-    # ptlint: disable=PT001 -- ppp/hb are static Python config knobs
-    # (autotune-cache hits or explicit kwargs; a tracer here would
-    # already have failed the cache lookup), never device values
-    ppp = _DEFAULT_PAGES_IN_FLIGHT if ppp is None else int(ppp)
-    ppp = max(1, min(ppp, max_pages))
+def _head_block(hb, page, hkv, d, dtype, group):
+    """The head block a call runs at: the caller's, lowered to a
+    divisor of Hkv, or else the one derived from the shapes
+    (`_default_head_block`)."""
     if hb is None:
-        hb = _default_head_block(ppp, page, hkv, d, dtype, group)
+        return _default_head_block(page, hkv, d, dtype, group)
     hb = max(1, int(hb))  # ptlint: disable=PT001 -- static config knob
     while hkv % hb:
         hb -= 1
-    return ppp, hb
+    return hb
 
 
-def _kernel(*refs, scale, page, hkv, max_pages, ppp, hb, with_stats):
+def _kernel(*refs, scale, page, hkv, max_pages, hb, with_stats):
     # Ref layout (the stats output exists only when requested, so the
     # trailing refs shift, same convention as the contiguous decode
     # kernel):
@@ -175,6 +152,7 @@ def _kernel(*refs, scale, page, hkv, max_pages, ppp, hb, with_stats):
         o_ref, rest = rest[0], rest[1:]
     kbuf, vbuf, sems, base_ref, acc_ref, m_ref, l_ref = rest
     nhb = hkv // hb
+    ppp = _PAGES_IN_FLIGHT
     nbuf = ppp + 1
     chunk = math.gcd(hb, _HEAD_CHUNK)
     bh = pl.program_id(0)
@@ -311,7 +289,7 @@ def _sublanes(dtype):
 
 
 def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
-                interpret, return_stats, pages_per_program, head_block,
+                interpret, return_stats, head_block,
                 name="paged_decode_attention"):
     """Call-site builder of the read-only paged attend; ``name`` is the
     launch's name in a device trace."""
@@ -330,9 +308,9 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    ppp, hb = _resolve_config(pages_per_program, head_block, page, hkv,
-                              d, q.dtype, group, max_pages, False)
+    hb = _head_block(head_block, page, hkv, d, q.dtype, group)
     nhb = hkv // hb
+    nbuf = _PAGES_IN_FLIGHT + 1
 
     gp = _group_rows(group, q.dtype)
     qg = q.reshape(b * hkv, group, d)
@@ -368,9 +346,9 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
                   pool_spec],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((ppp + 1, hb, page, d), kp.dtype),
-            pltpu.VMEM((ppp + 1, hb, page, d), vp.dtype),
-            pltpu.SemaphoreType.DMA((2, ppp + 1)),
+            pltpu.VMEM((nbuf, hb, page, d), kp.dtype),
+            pltpu.VMEM((nbuf, hb, page, d), vp.dtype),
+            pltpu.SemaphoreType.DMA((2, nbuf)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((hb, gp, d), jnp.float32),
             pltpu.VMEM((hb, gp, _LANES), jnp.float32),
@@ -381,7 +359,7 @@ def _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
         # ptlint: disable=PT001 -- scale is a static Python float kwarg
         # (a tracer here would already fail partial-binding)
         functools.partial(_kernel, scale=float(scale), page=page,
-                          hkv=hkv, max_pages=max_pages, ppp=ppp, hb=hb,
+                          hkv=hkv, max_pages=max_pages, hb=hb,
                           with_stats=return_stats),
         grid_spec=grid_spec,
         out_shape=out_shape,
@@ -454,8 +432,7 @@ def _write_rows(k_pages, v_pages, k_row, v_row, write_pids, lengths, hb,
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                            scale=None, interpret=None,
-                           return_stats=False, pages_per_program=None,
-                           head_block=None):
+                           return_stats=False, head_block=None):
     """One decode step of cached attention over a PAGED KV pool.
 
     Args:
@@ -476,22 +453,18 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
         engine's pre-fusion formulation added the current token's
         fresh KV row this way, keeping the pools READ-ONLY inside its
         layer scan.
-      pages_per_program, head_block: kernel geometry (pages in flight
-        ahead of the fold; KV heads a program takes). Default (None):
-        the autotune cache's entry for the (page, Hkv, D, dtype, group)
-        key where `tune_paged_attention` has filled one, else 1 and the
-        largest head block that fits VMEM.
+      head_block: KV heads a program takes. Default (None): the
+        largest divisor of Hkv whose blocks fit VMEM.
 
     Returns (B, Hq, D) in q's dtype; with return_stats, (o, m, l).
     """
     return _paged_call(q, k_pages, v_pages, page_table, lengths, scale,
-                       interpret, return_stats, pages_per_program,
-                       head_block)
+                       interpret, return_stats, head_block)
 
 
 def paged_append_attend(q, k_pages, v_pages, k_row, v_row, page_table,
                         write_pids, lengths, scale=None, interpret=None,
-                        pages_per_program=None, head_block=None):
+                        head_block=None):
     """Append+attend decode step over a paged KV pool, in two launches.
 
     First the write kernel (`paged_append_attend_write` in a trace)
@@ -529,11 +502,9 @@ def paged_append_attend(q, k_pages, v_pages, k_row, v_row, page_table,
     max_pages = page_table.shape[1]
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    # one geometry for both launches (the write's block is the attend's),
-    # from this entry point's own autotune family
-    ppp, hb = _resolve_config(pages_per_program, head_block, page, hkv,
-                              d, q.dtype, q.shape[1] // hkv, max_pages,
-                              True)
+    # one geometry for both launches: the write's block is the attend's
+    hb = _head_block(head_block, page, hkv, d, q.dtype,
+                     q.shape[1] // hkv)
     lengths = jnp.asarray(lengths, jnp.int32)
     k_pages, v_pages = _write_rows(k_pages, v_pages, k_row, v_row,
                                    write_pids, lengths, hb, interpret)
@@ -541,70 +512,8 @@ def paged_append_attend(q, k_pages, v_pages, k_row, v_row, page_table,
     # only keeps such a (masked-out) row's page walk inside its table
     o = _paged_call(q, k_pages, v_pages, page_table,
                     jnp.minimum(lengths + 1, max_pages * page), scale,
-                    interpret, False, ppp, hb, name="paged_append_attend")
+                    interpret, False, hb, name="paged_append_attend")
     return o, k_pages, v_pages
-
-
-def tune_paged_attention(q, k_pages, v_pages, page_table, lengths,
-                         scale=None, fused=True, candidates=None,
-                         iters=3):
-    """Eagerly measure paged-kernel geometry candidates on the REAL
-    shapes and persist the winner (≙ flash_attention's
-    tune_flash_attention; Pallas grids are trace-time constants, so
-    tuning runs outside jit and later calls pick the tuned
-    ``(pages_per_program, head_block)`` from the cache at trace time —
-    warmup-compatible as long as tuning runs before the engine traces).
-
-    Keyed per (page_size, Hkv, D, dtype, group) shape family — the
-    knobs that set the kernel's per-program work — not per batch/table
-    width, which only clamp the config. Returns (config, timings).
-    """
-    from paddle_tpu.ops.pallas import autotune as at
-
-    q = jnp.asarray(q)
-    k_pages = jnp.asarray(k_pages)
-    b, hq, d = q.shape
-    hkv, page = k_pages.shape[1], k_pages.shape[2]
-    max_pages = page_table.shape[1]
-    group = hq // hkv
-    key = _tune_key(page, hkv, d, q.dtype, group, fused)
-    if candidates is None:
-        # every head block that divides Hkv and fits VMEM, with one or
-        # two pages in flight
-        from paddle_tpu.analysis.kernelmodel import vmem_budget_bytes
-        candidates = [(ppp, hb)
-                      for ppp in (1, 2) if ppp <= max_pages
-                      for hb in range(1, hkv + 1) if hkv % hb == 0
-                      and _vmem_bytes(ppp, hb, page, d, q.dtype,
-                                      group) <= vmem_budget_bytes()]
-    if fused:
-        k_row = jnp.zeros((b, hkv, d), k_pages.dtype)
-        v_row = jnp.zeros((b, hkv, d), jnp.asarray(v_pages).dtype)
-        wpids = jnp.asarray(page_table, jnp.int32)[:, 0]
-
-    jitted = {}
-
-    def build_and_run(cfg):
-        if cfg not in jitted:
-            ppp, hb = cfg
-            if fused:
-                def fn(q, kp, vp, table, lens, _ppp=ppp, _hb=hb):
-                    o, kp2, vp2 = paged_append_attend(
-                        q, kp, vp, k_row, v_row, table, wpids, lens,
-                        scale=scale, pages_per_program=_ppp,
-                        head_block=_hb)
-                    return jnp.sum(o.astype(jnp.float32) ** 2)
-            else:
-                def fn(q, kp, vp, table, lens, _ppp=ppp, _hb=hb):
-                    o = paged_decode_attention(
-                        q, kp, vp, table, lens, scale=scale,
-                        pages_per_program=_ppp, head_block=_hb)
-                    return jnp.sum(o.astype(jnp.float32) ** 2)
-            jitted[cfg] = jax.jit(fn)
-        out = jitted[cfg](q, k_pages, v_pages, page_table, lengths)
-        float(out)  # sync — the timing loop must see the kernel finish
-    return at.tune("paged_attention", key, candidates, build_and_run,
-                   iters=iters)
 
 
 class PageAllocator:
@@ -727,12 +636,12 @@ class PagedKVCache:
 
 
 def ptgeom_cases():
-    """Geometry registry for tools/ptgeom.py (ISSUE 20): plain and
-    fused paged decode across the (pages_per_program, head_block)
-    autotune space, under jax.eval_shape."""
+    """Geometry registry for tools/ptgeom.py (ISSUE 20): the read-only
+    attend and the append+attend at the derived head block on every
+    rung, and at explicit head blocks on two, under jax.eval_shape."""
     from paddle_tpu.analysis import kernelmodel as km
 
-    def case(geom, ppp, hb, fused):
+    def case(geom, hb, append):
         p = km.LADDER[geom]
         d = p["dm"] // p["heads"]
         hkv = p["kv_heads"]
@@ -747,33 +656,31 @@ def ptgeom_cases():
 
         def run():
             import jax as _jax
-            if fused:
+            if append:
                 _jax.eval_shape(
                     lambda q, kp, vp, kr, vr, tab, wp, ln:
                     paged_append_attend(q, kp, vp, kr, vr, tab, wp,
-                                        ln, pages_per_program=ppp,
-                                        head_block=hb),
+                                        ln, head_block=hb),
                     q, pool, pool, row, row, table, vec, vec)
             else:
                 _jax.eval_shape(
                     lambda q, kp, vp, tab, ln: paged_decode_attention(
-                        q, kp, vp, tab, ln, pages_per_program=ppp,
-                        head_block=hb),
+                        q, kp, vp, tab, ln, head_block=hb),
                     q, pool, pool, table, vec)
-        tag = "fused" if fused else "plain"
-        config = "default" if ppp is None else f"ppp{ppp}.hb{hb}"
+        tag = "fused" if append else "plain"
+        config = "default" if hb is None else f"hb{hb}"
         return km.GeomCase(kernel=f"paged_{tag}", geometry=geom,
                            config=config, run=run)
 
-    cases = [case("tiny", 1, 1, True)]
-    # the geometry a call gets with no tuner run (what the benchmark's
-    # serving cells and every default engine run), at every rung
+    cases = [case("tiny", 1, True)]
+    # the geometry a call gets when it names none (what the benchmark's
+    # serving cells and every engine run), at every rung
     for geom in km.LADDER:
-        for fused in (False, True):
-            cases.append(case(geom, None, None, fused))
+        for append in (False, True):
+            cases.append(case(geom, None, append))
     for geom in ("350m", "r06"):
-        for ppp, hb in ((1, 1), (2, 2), (4, 4)):
-            cases.append(case(geom, ppp, hb, False))
-        for ppp, hb in ((1, 1), (2, 2)):
-            cases.append(case(geom, ppp, hb, True))
+        for hb in (1, 2, 4):
+            cases.append(case(geom, hb, False))
+        for hb in (1, 2):
+            cases.append(case(geom, hb, True))
     return cases

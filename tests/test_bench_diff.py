@@ -137,11 +137,9 @@ def test_goodput_dip_is_lower_is_better():
 
 
 def test_launch_rows_are_lower_is_better():
-    """The kernel-launch accounting rows (ISSUE 19): launches per
-    token/step guard the single-dispatch megakernel — MORE launches is
-    a regression (a fall back to one-launch-per-layer), fewer is the
-    win. The row name must not be swallowed by the higher-is-better
-    token fragments."""
+    """The kernel-launch accounting rows (ISSUE 19): MORE launches
+    per token/step is a regression, fewer is the win. The row name
+    must not be swallowed by the higher-is-better token fragments."""
     assert bd.direction("decode_engine_paged_launches_per_token") == -1
     assert bd.direction("decode_spec_paged_launches_per_step") == -1
     v = bd.compare(_doc(decode_engine_paged_launches_per_step=2.0),

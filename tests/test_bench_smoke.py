@@ -46,10 +46,7 @@ def test_bench_decode_smoke():
     # regression would otherwise vanish silently)
     assert out.get("decode_spec_tokens_per_step", 0) > 0, out
     # paged-spec row (ISSUE 19) — the r05 row death must fail here
-    # first. It rides the engine's DEFAULT decode step (per-layer fused
-    # since PR 21), so launches scale with layers; the megakernel's
-    # 2-launch bound is asserted in test_paged_mega where mega=True is
-    # asked for by name
+    # first; its launches scale with layers
     assert out.get("decode_spec_paged_tokens_per_step", 0) > 0, out
     assert out.get("decode_spec_paged_launches_per_step", 0) > 0, out
     # kernel-launch ladder row present on the engine path too

@@ -5,14 +5,11 @@ refuses here, at no chip time (on-chip-measurement guide, section 2).
 
 Every kernel a default path of ``chip_smoke.py`` reaches is here: the
 train step's flash attention and fused CE (forward and backward), the
-paged engine's ``paged_append_attend`` (default decode step) and
+paged engine's ``paged_append_attend`` (the decode step) and
 ``paged_decode_attention`` (suffix prefill), plus ``decode_attention``
-and ``int8_matmul`` (the contiguous engine), ``mega_logits_sample``
-(opt-in, compiles since PR 21) and, at Brumby-14B's widths,
+and ``int8_matmul`` (the contiguous engine) and, at Brumby-14B's widths,
 ``retention_step`` (the decode step of retention layers) with the
-dataflow that keeps its 4.4 GB state pool in place. ``mega_decode_layers`` is not: the
-compiler refuses it and no default path launches it
-(ops/pallas/decode_megakernel.py).
+dataflow that keeps its 4.4 GB state pool in place.
 
 The topology is described inside a module-scoped fixture of THIS file —
 only the xdist worker that is handed the file loads libtpu — and the
@@ -123,10 +120,9 @@ def test_paged_append_attend(one_chip):
     benchmark's roofline share sums), at 16 slots x 16 columns with the
     default geometry: all 16 heads of a page a program."""
     from paddle_tpu.ops.pallas.paged_attention import (
-        _resolve_config, paged_append_attend)
+        _default_head_block, paged_append_attend)
     pool, q, table, vec = _paged_shapes()
-    assert _resolve_config(None, None, PAGE, HEADS, HEAD_DIM, BF16, 1,
-                           SEQ // PAGE, True) == (1, HEADS)
+    assert _default_head_block(PAGE, HEADS, HEAD_DIM, BF16, 1) == HEADS
     calls = _compile(
         functools.partial(paged_append_attend, interpret=False),
         (q, pool, pool, q, q, table, vec, vec), one_chip)
@@ -207,17 +203,6 @@ def test_int8_matmul(one_chip):
         (_sds((SLOTS, DM)), _sds((DM, 4 * DM), jnp.int8),
          _sds((1, 4 * DM), jnp.float32)), one_chip)
     assert _named(calls, "int8_matmul") == 1
-
-
-def test_mega_logits_sample(one_chip):
-    """The fused norm -> logits -> argmax epilogue (opt-in with
-    mega=True): refused before PR 21 for a bf16 matmul accumulator."""
-    from paddle_tpu.ops.pallas.decode_megakernel import mega_logits_sample
-    calls = _compile(
-        functools.partial(mega_logits_sample, interpret=False),
-        (_sds((SLOTS, DM)), _sds((DM,)), _sds((DM,)), _sds((DM, VOCAB)),
-         _sds((SLOTS,), jnp.int32)), one_chip)
-    assert _named(calls, "mega_logits_sample") == 1
 
 
 # Brumby-14B-Base's retention layers at their published widths (40 query

@@ -60,7 +60,7 @@ def test_train_and_serve_phases_pass_at_tiny(stepped_over, capsys):
     assert last["ok"] is True
     assert set(last["device"]) == {"platform", "kind", "count"}
     out = "\n".join(lines)
-    assert "per-layer fused" in out          # the default decode path
+    assert "serve: PagedDecodeEngine (page" in out
     assert out.count("identical to gpt.generate") == 2   # f32: bit-exact
     assert "compile cache at" in out and "train: compile" in out
 

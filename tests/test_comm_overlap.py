@@ -477,19 +477,25 @@ def _converge_in_child(method):
 
 @pytest.mark.parametrize("method", [None, "bf16", "int8"])
 def test_overlap_step_converges(method):
-    """30 steps must converge on every wire. Run in a CHILD process:
-    XLA:CPU under jaxlib 0.9.0 runs independent collectives of one
-    program concurrently under one rendezvous key and then ABORTS the
-    process ("Unexpected number of participants", or a 40 s rendezvous
-    timeout); the bf16 wire's program hits it on every run seen, at the
-    seed of PR 21 too. In-process that kills the xdist worker and can
-    stall the whole session; in a child it is a failure this test
-    reports. A TPU orders its collectives and is not affected."""
+    """30 steps must converge on every wire. Run in a CHILD process
+    whose CPU programs run their collectives in order: XLA:CPU under
+    jaxlib 0.9.0 runs independent collectives of one program
+    concurrently under one rendezvous key and then ABORTS the process
+    ("Unexpected number of participants", "id < num_threads", or a 40 s
+    rendezvous timeout); the bf16 wire's program hit it on every run
+    seen, at the seed of PR 21 too. The child therefore turns XLA:CPU's
+    concurrency-optimized scheduler off (the program's thunks then run
+    in one order on every device, which is what a TPU does anyway), and
+    an abort that still happens is a failure this test reports and not
+    a dead xdist worker."""
     code = (f"import sys; sys.path.insert(0, {os.path.dirname(__file__)!r}); "
             f"import test_comm_overlap as t; "
             f"t._converge_in_child({method!r})")
+    env = dict(os.environ, XLA_FLAGS=(
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_cpu_enable_concurrency_optimized_scheduler=false"))
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=600)
+                       text=True, timeout=600, env=env)
     assert r.returncode == 0, (r.returncode, r.stderr[-1500:])
     out = json.loads(r.stdout.strip().splitlines()[-1])
     losses = out["losses"]

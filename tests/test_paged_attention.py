@@ -91,17 +91,17 @@ def _scatter_oracle(k, v, k_row, v_row, table, write_pids, lengths,
     return k2, v2
 
 
-@pytest.mark.parametrize("group,cfg", [(1, None), (4, None),
-                                       (1, (2, 2))])
-def test_fused_append_attend_matches_scatter_then_attend(group, cfg):
+@pytest.mark.parametrize("group,head_block", [(1, None), (4, None),
+                                              (1, 2)])
+def test_fused_append_attend_matches_scatter_then_attend(group,
+                                                         head_block):
     """ISSUE 6 tentpole parity: `paged_append_attend` (fresh KV row
     merged into its pool page by the write launch, then attended with
     the prefix) must be bit-compatible with the scatter-then-attend
     formulation it replaces — both the attention output and the ENTIRE
     pool (the in-place write lands exactly one row; untouched pages
     identical). Covers page-edge lengths (write lands in a fresh page),
-    an empty row (length 0), GQA, and a non-default
-    (pages_per_program, head_block) geometry."""
+    an empty row (length 0), GQA, and a non-default head block."""
     rs = np.random.RandomState(11)
     P, hkv, page, d = 10, 2, 128, 32
     b, max_pages = 3, 3
@@ -116,10 +116,9 @@ def test_fused_append_attend_matches_scatter_then_attend(group, cfg):
         [int(table[i, int(lengths[i]) // page]) for i in range(b)],
         jnp.int32)
 
-    ppp, hb = cfg if cfg else (None, None)
     o, k_out, v_out = paged_append_attend(
         q, k, v, k_row, v_row, table, wpids, lengths,
-        pages_per_program=ppp, head_block=hb)
+        head_block=head_block)
 
     k2, v2 = _scatter_oracle(k, v, k_row, v_row, table, wpids, lengths,
                              page)
@@ -219,43 +218,41 @@ def test_append_attend_page_offsets_and_idle_slot(rem, group, d):
     assert np.isfinite(np.asarray(o)).all()
 
 
-def test_paged_autotune_cache_roundtrip(tmp_path, monkeypatch):
-    """`tune_paged_attention` measures candidates eagerly, persists the
-    winner per (page, Hkv, D, dtype, group) key, and the kernels pick
-    the tuned config up from the cache at trace time — every candidate
-    geometry must also be numerically identical."""
+@pytest.mark.parametrize("family", ["paged_append", "paged_attention"])
+def test_planted_autotune_entry_does_not_move_the_geometry(
+        tmp_path, monkeypatch, family):
+    """The paged kernels' geometry is the call's own (its head block,
+    or the one derived from the shapes): an entry under the key the
+    removed paged tuner wrote, in the cache flash attention still
+    reads, changes neither launch's grid."""
     import paddle_tpu.ops.pallas.autotune as at
-    from paddle_tpu.ops.pallas.paged_attention import (
-        tune_paged_attention)
 
     monkeypatch.setattr(at, "_GLOBAL", None)
     monkeypatch.setenv("PT_AUTOTUNE_CACHE",
                        str(tmp_path / "autotune.json"))
     rs = np.random.RandomState(13)
-    P, hkv, page, d = 8, 4, 128, 16
-    b, max_pages = 2, 2
+    P, hkv, page, d, b = 8, 4, 128, 16, 2
     k, v = _pool(rs, P, hkv, page, d)
     q = jnp.asarray(rs.randn(b, hkv, d), jnp.float32)
+    row = jnp.asarray(rs.randn(b, hkv, d), jnp.float32)
     table = jnp.asarray([[0, 5], [7, 1]], jnp.int32)
     lengths = jnp.asarray([200, 140], jnp.int32)
-
-    for fused in (False, True):
-        cfg, timings = tune_paged_attention(
-            q, k, v, table, lengths, fused=fused, iters=1,
-            candidates=[(1, 1), (2, 2), (1, 4)])
-        assert cfg in timings and len(timings) == 3
-        # cache hit: second call measures nothing
-        cfg2, timings2 = tune_paged_attention(
-            q, k, v, table, lengths, fused=fused, iters=1,
-            candidates=[(1, 1), (2, 2), (1, 4)])
-        assert cfg2 == cfg and timings2 == {}
-
-    # tuned config (read from the cache at trace time) == default
-    want = paged_decode_attention(q, k, v, table, lengths,
-                                  pages_per_program=1, head_block=1)
-    got = paged_decode_attention(q, k, v, table, lengths)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
+    if family == "paged_append":
+        fn, args = paged_append_attend, (q, k, v, row, row, table,
+                                         table[:, 1], lengths)
+    else:
+        fn, args = paged_decode_attention, (q, k, v, table, lengths)
+    before = _attend_programs(fn, *args)
+    at.get_cache().put(at.AutotuneCache.key(
+        family, page=page, hkv=hkv, d=d, dtype=str(q.dtype), group=1),
+        (2, 1))
+    assert (tmp_path / "autotune.json").exists()
+    assert _attend_programs(fn, *args) == before
+    # all four heads a program: one program a row, in every launch
+    assert set(before) == {(b,)}
+    # an explicit head block is the only way to another grid
+    assert _attend_programs(
+        lambda *a: fn(*a, head_block=1), *args) == [(b * hkv,)] * len(before)
 
 
 def test_kernel_gqa_and_jit_traced_operands():
@@ -475,20 +472,16 @@ def test_default_head_block_follows_the_vmem_budget(monkeypatch,
     blocks fit `kernelmodel.vmem_budget_bytes()`: all 16 heads at GPT-3
     XL's shapes under the 16 MiB default, fewer under a smaller budget,
     one at the floor (never a refusal)."""
-    from paddle_tpu.ops.pallas.paged_attention import (_resolve_config,
+    from paddle_tpu.ops.pallas.paged_attention import (_head_block,
                                                        _vmem_bytes)
     from paddle_tpu.analysis import kernelmodel as km
     monkeypatch.setenv("PT_VMEM_BUDGET_MB", budget_mb)
-    for fused in (False, True):
-        ppp, hb = _resolve_config(None, None, 128, 16, 128, jnp.bfloat16,
-                                  1, 16, fused)
-        assert (ppp, hb) == (1, want)
+    assert _head_block(None, 128, 16, 128, jnp.bfloat16, 1) == want
     if want > 1:
-        assert _vmem_bytes(1, want, 128, 128, jnp.bfloat16,
+        assert _vmem_bytes(want, 128, 128, jnp.bfloat16,
                            1) <= km.vmem_budget_bytes()
     # an explicit head block is the caller's, clamped to a divisor
-    assert _resolve_config(2, 6, 128, 16, 128, jnp.bfloat16, 1, 16,
-                           True) == (2, 4)
+    assert _head_block(6, 128, 16, 128, jnp.bfloat16, 1) == 4
 
 
 @pytest.mark.parametrize("rem", [15, 16, 17, 100])

@@ -10,11 +10,17 @@ import pytest
 
 from paddle_tpu.inference.paged_engine import PagedDecodeEngine
 from paddle_tpu.models import gpt
+from paddle_tpu.testing import faults
+
+# drafts of the prompt-lookup speculation actually accept on this one
+REPETITIVE = [7, 8, 9, 7, 8, 9, 7, 8, 9, 7, 8]
 
 
-def _model(max_seq=512, heads=4):
+def _model(max_seq=512, heads=4, kv_heads=None, rope=False, layers=2):
     cfg = gpt.GPTConfig(vocab_size=96, max_seq_len=max_seq, d_model=32,
-                        n_layers=2, n_heads=heads, dtype=jnp.float32)
+                        n_layers=layers, n_heads=heads,
+                        n_kv_heads=kv_heads, dtype=jnp.float32,
+                        rope=rope)
     return gpt.GPT(cfg, seed=0)
 
 
@@ -52,6 +58,48 @@ def test_paged_parity_with_generate_mixed_lengths():
     # everything retired -> every page free or warm in the prefix
     # cache at refcount zero (nothing still mapped)
     _assert_pool_drained(eng, 12)
+
+
+@pytest.mark.parametrize("rope,kv_heads", [(False, None), (True, 2)])
+def test_streams_match_generate_rope_and_grouped_heads(rope, kv_heads):
+    model = _model(rope=rope, kv_heads=kv_heads)
+    rs = np.random.RandomState(0)
+    prompts = [list(rs.randint(0, 96, size=n)) for n in (5, 170, 23)]
+    eng = PagedDecodeEngine(model, n_pages=14, max_slots=2,
+                            steps_per_call=3)
+    reqs = [eng.submit(p, max_new_tokens=9) for p in prompts]
+    eng.run()
+    for req, p in zip(reqs, prompts):
+        assert req.tokens == _reference(model, p, 9), (rope, kv_heads)
+
+
+def test_paged_spec_streams_match_generate():
+    """Speculative decode on the paged path: prompt-lookup drafts +
+    the per-layer verify must leave greedy streams bit-identical to
+    gpt.generate."""
+    model = _model()
+    rs = np.random.RandomState(1)
+    prompts = [REPETITIVE, list(rs.randint(0, 96, size=40))]
+    eng = PagedDecodeEngine(model, n_pages=14, max_slots=2,
+                            steps_per_call=3, speculative_k=4)
+    reqs = [eng.submit(p, max_new_tokens=12) for p in prompts]
+    eng.run()
+    for req, p in zip(reqs, prompts):
+        assert req.tokens == _reference(model, p, 12)
+
+
+def test_paged_spec_stops_at_eos_like_generate():
+    """A speculative step accepts several tokens at once: the stream
+    still ends at the first eos, as gpt.generate's does."""
+    model = _model()
+    rs = np.random.RandomState(3)
+    prompt = list(rs.randint(0, 96, size=31))
+    ref = _reference(model, prompt, 24, eos=7)
+    eng = PagedDecodeEngine(model, n_pages=14, max_slots=2,
+                            steps_per_call=4, speculative_k=3)
+    req = eng.submit(prompt, max_new_tokens=24, eos_id=7)
+    eng.run()
+    assert req.tokens == ref
 
 
 def test_paged_pages_allocated_on_demand_and_reused():
@@ -139,18 +187,23 @@ def test_page_size_must_divide_buckets():
         PagedDecodeEngine(model, n_pages=4, max_slots=1, page_size=384)
 
 
+@pytest.mark.parametrize("spec", [0, 4])
 @pytest.mark.parametrize("depth", [2, 3])
-def test_paged_pipelined_depths_bit_identical(depth):
+def test_paged_pipelined_depths_bit_identical(depth, spec):
     """ISSUE 4: the pipelined paged engine (lag-one harvest, one packed
     transfer per dispatch) serves byte-identical streams to depth=1,
-    with every page back in the pool at drain."""
+    plain and speculative, with every page back in the pool at
+    drain."""
     model = _model()
     rs = np.random.RandomState(6)
     prompts = [list(rs.randint(0, 96, size=n)) for n in (5, 170, 23)]
+    if spec:
+        prompts[0] = REPETITIVE
 
     def run(d):
         eng = PagedDecodeEngine(model, n_pages=12, max_slots=2,
-                                steps_per_call=4, inflight=d)
+                                steps_per_call=4, inflight=d,
+                                speculative_k=spec)
         reqs = [eng.submit(p, max_new_tokens=9) for p in prompts]
         eng.step()
         eng.run()
@@ -180,34 +233,86 @@ def test_paged_warmup_pretraces():
     assert eng._multi_fn._cache_size() == 1, "serving recompiled"
 
 
-def test_fused_vs_scatter_bit_identical_and_no_scatter_dispatch():
-    """ISSUE 6 tentpole: the fused append+attend engine (default) must
-    serve byte-identical streams to the PT_PAGED_FUSED=0 scatter
-    formulation it replaces — and the per-token scatter
-    (`_write_token_rows`) must be GONE from the fused dispatch path
-    (the CPU-verifiable proxy for the removed pool traffic)."""
+def _primitives(jaxpr, prefix):
+    """(primitive name, operand shapes) of every equation of ``jaxpr``
+    and the jaxprs inside it whose name starts with ``prefix``."""
+    import jax
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith(prefix):
+            found.append((eqn.primitive.name,
+                          [tuple(v.aval.shape) for v in eqn.invars]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_primitives(sub, prefix))
+    return found
+
+
+def test_decode_dispatch_scatters_into_no_pool():
+    """The decode step writes the fresh KV row through the write
+    launch of `paged_append_attend` and nowhere else: no ``scatter`` of
+    the dispatch's jaxpr takes a pool (the CPU-verifiable proxy for the
+    pool traffic a per-token scatter costs; on the chip
+    tests/test_chip_compile.py::test_decode_dataflow_copies_no_pool
+    holds the compiled program to it)."""
+    import jax
     model = _model()
-    rs = np.random.RandomState(20)
-    prompts = [list(rs.randint(0, 96, size=n)) for n in (7, 170, 40)]
+    eng = PagedDecodeEngine(model, n_pages=12, max_slots=2,
+                            steps_per_call=4)
+    fn, args = eng.dispatch_fn_args()
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    pool = tuple(eng.kp.shape)
+    view = (pool[0] * pool[1],) + pool[2:]
+    assert _primitives(jaxpr, "pallas_call")       # the walk sees them
+    on_pool = [(name, shapes) for name, shapes
+               in _primitives(jaxpr, "scatter")
+               if pool in shapes or view in shapes]
+    assert not on_pool, on_pool
 
-    def run(fused):
-        eng = PagedDecodeEngine(model, n_pages=12, max_slots=2,
-                                steps_per_call=4, fused=fused)
-        assert eng.fused is fused
-        if fused:
-            def boom(*a, **k):
-                raise AssertionError(
-                    "fused dispatch called the per-token scatter")
-            eng._write_token_rows = boom
-        reqs = [eng.submit(p, max_new_tokens=9) for p in prompts]
+
+@pytest.mark.parametrize("spec", [0, 3])
+def test_launches_a_step_follow_the_layers(spec):
+    """A decode step is `paged_append_attend` once a layer, two
+    launches (row write, attend); a speculative verify is one read-only
+    attend a layer. Counted from the dispatch's jaxpr (scan-trip
+    weighted), so the assert holds on any backend; the AOT lowering's
+    custom-call counter returns a number (0 in CPU interpret mode,
+    where pallas lowers to inline HLO; one a launch on a TPU)."""
+    from paddle_tpu.observability import devprof
+    model = _model(layers=3)
+    eng = PagedDecodeEngine(model, n_pages=20, max_slots=2,
+                            steps_per_call=4, speculative_k=spec)
+    fn, args = eng.dispatch_fn_args()
+    per_step = devprof.count_pallas_launches(fn, *args) / eng.chunk
+    assert per_step == (1 if spec else 2) * model.cfg.n_layers
+    n = devprof.count_hlo_custom_calls(fn, *args)
+    assert n is not None and n >= 0
+
+
+@pytest.mark.parametrize("spec", [0, 3])
+def test_poison_eviction_scrubs_and_isolates(spec):
+    """Non-finite logits evict ONLY the poisoned slot; the survivor's
+    stream is untouched and the retired slot's pages return to the pool
+    (free or refcount-zero cached)."""
+    model = _model()
+    rs = np.random.RandomState(5)
+    pa, pb = (list(rs.randint(0, 96, size=n)) for n in (5, 23))
+    eng = PagedDecodeEngine(model, n_pages=14, max_slots=2,
+                            steps_per_call=2, speculative_k=spec)
+    ra = eng.submit(pa, max_new_tokens=8)
+    rb = eng.submit(pb, max_new_tokens=8)
+    with faults.inject("engine.poison_logits", "nan", slot=0):
         eng.run()
-        assert all(r.done and not r.failed for r in reqs)
-        return [list(r.tokens) for r in reqs]
+    assert ra.failed and "non-finite" in ra.error
+    assert not rb.failed and rb.tokens == _reference(model, pb, 8)
+    _assert_pool_drained(eng, 14)
 
-    want = run(False)
-    for got, p in zip(want, prompts):
-        assert got == _reference(model, p, 9), len(p)
-    assert run(True) == want
+
+@pytest.mark.parametrize("removed", [{"mega": True}, {"fused": False}])
+def test_removed_decode_switches_are_type_errors(removed):
+    """One decode step: the arguments that chose another are gone."""
+    with pytest.raises(TypeError):
+        PagedDecodeEngine(_model(), n_pages=4, **removed)
+    assert not hasattr(PagedDecodeEngine, "autotune")
 
 
 def test_warm_prefix_hit_prefills_only_suffix():

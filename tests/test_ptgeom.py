@@ -2,7 +2,7 @@
 
 Per-rule fixtures for PT006–PT009 over hand-built KernelSpecs, the
 inline-suppression and baseline round-trips, harvest parity against
-hand-computed block bytes for the megakernel, the planted over-budget
+hand-computed block bytes for the paged append+attend, the planted over-budget
 kernel the CLI must catch BY NAME, the repo self-sweep zero-new gate,
 and the autotune geometry-refusal contract.
 
@@ -112,7 +112,7 @@ def test_pt007_sublane_and_lane_misalignment(tmp_path):
     assert len(f) == 1
     assert "sublane" in f[0].message and "lane" in f[0].message
     # a 1-row block of a many-row array: the v5e lowering REFUSED
-    # exactly this on the megakernel's stacked LN/bias vectors (PR 21)
+    # exactly this on stacked LN/bias vectors (PR 21)
     row = _spec(inputs=[_op(index=0, shape=(24, 2048), block=(1, 2048),
                             dtype="bfloat16")])
     f = [f for f in _run(tmp_path, [row]) if f.rule == "PT007"]
@@ -224,55 +224,85 @@ def test_geom_baseline_roundtrip(tmp_path):
 
 # -- harvest parity ----------------------------------------------------------
 
-def test_mega_harvest_parity_hand_computed():
-    """mega_decode_layers at tiny geometry, L=3: the harvested spec
-    must agree with hand-computed grid/prefetch/alias/block facts."""
-    from paddle_tpu.ops.pallas.decode_megakernel import \
-        mega_decode_layers
+def test_paged_append_attend_harvest_parity_hand_computed():
+    """`paged_append_attend` at tiny geometry, 8 rows over a 2-column
+    table: two launches (the row write, then the attend), whose
+    harvested specs must agree with hand-computed grid/prefetch/alias/
+    block facts."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        _vmem_bytes, paged_append_attend)
     p = km.LADDER["tiny"]
     dm, hq, hkv = p["dm"], p["heads"], p["kv_heads"]
-    d, dt, page, L, B = dm // hq, p["dtype"], p["page"], 3, 8
-    P = max(1, p["seq"] // page)
-    weights = {
-        "ln1_scale": km.sds((L, dm), dt),
-        "ln1_bias": km.sds((L, dm), dt),
-        "wqkv": km.sds((L, dm, (hq + 2 * hkv) * d), dt),
-        "wo": km.sds((L, hq * d, dm), dt),
-        "ln2_scale": km.sds((L, dm), dt),
-        "ln2_bias": km.sds((L, dm), dt),
-        "wup": km.sds((L, dm, 4 * dm), dt),
-        "wdown": km.sds((L, 4 * dm, dm), dt),
-    }
-    x = km.sds((B, dm), dt)
-    pool = km.sds((L * P + 1, hkv, page, d), dt)
-    table = km.sds((B, P), "int32")
-    rows = km.sds((B,), "int32")
+    d, dt, page, B, cols = dm // hq, p["dtype"], p["page"], 8, 2
+    q = km.sds((B, hq, d), dt)
+    pool = km.sds((B * cols + 1, hkv, page, d), dt)
+    row = km.sds((B, hkv, d), dt)
+    table = km.sds((B, cols), "int32")
+    vec = km.sds((B,), "int32")
 
     specs = km.harvest(
-        lambda: jax.eval_shape(
-            functools.partial(mega_decode_layers, page=page, n_pages=P,
-                              n_heads=hq, kv_heads=hkv, head_dim=d),
-            x, weights, pool, pool, table, rows, rows, rows),
+        lambda: jax.eval_shape(paged_append_attend, q, pool, pool, row,
+                               row, table, vec, vec),
         root=REPO)
-    assert len(specs) == 1
-    spec = specs[0]
-    assert spec.grid == (L,)
-    assert spec.num_scalar_prefetch == 4
-    # both KV pools alias their output pools (in-place append)
-    assert spec.aliases and len(spec.aliases) == 2
-    assert sorted(spec.aliases.values()) == [1, 2]
-    for gi in spec.aliases:
-        inp = next(op for op in spec.inputs if op.index == gi)
-        assert inp.space == "any" and inp.shape == pool.shape
-    # the wqkv slab streams ONE layer per grid step
-    wqkv = [op for op in spec.inputs
-            if op.shape == (L, dm, (hq + 2 * hkv) * d)]
-    assert len(wqkv) == 1
-    assert wqkv[0].block == (1, dm, (hq + 2 * hkv) * d)
-    assert wqkv[0].block_bytes() == dm * (hq + 2 * hkv) * d * 4
-    assert wqkv[0].deps == (0,)    # layer-indexed: re-read never flags
-    assert spec.path == "paddle_tpu/ops/pallas/decode_megakernel.py"
-    assert km.vmem_estimate(spec) <= km.vmem_budget_bytes()
+    assert [s.body for s in specs] == ["_write_kernel", "_kernel"]
+    write, attend = specs
+    view = ((B * cols + 1) * hkv, page, d)     # the pools, head-major
+    sub = 8                                    # float32 sublane tile
+    for spec in specs:
+        # both heads of a page fit VMEM: one program a row, lengths and
+        # the table (or the write's page ids) prefetched as scalars
+        assert spec.grid == (B,)
+        assert spec.num_scalar_prefetch == 2
+        assert spec.path == "paddle_tpu/ops/pallas/paged_attention.py"
+    # the write: each pool in once, aliased to its output (in place),
+    # and what moves is the sublane tile that holds the row
+    assert write.aliases == {4: 0, 5: 1}
+    for gi, go in write.aliases.items():
+        inp = next(op for op in write.inputs if op.index == gi)
+        out = next(op for op in write.outputs if op.index == go)
+        assert inp.shape == out.shape == view
+        assert inp.block == out.block == (hkv, sub, d)
+        assert inp.block_bytes() == hkv * sub * d * 4
+    # the attend: pools stay in HBM, unblocked and unaliased; a page's
+    # heads land in one of two buffers a pool
+    assert not attend.aliases
+    pools = [op for op in attend.inputs if op.shape == view]
+    assert len(pools) == 2
+    assert all(op.space == "any" and op.block is None for op in pools)
+    landing = [sc for sc in attend.scratch
+               if sc.shape == (2, hkv, page, d)]
+    assert len(landing) == 2
+    assert km.vmem_estimate(attend) <= _vmem_bytes(hkv, page, d, dt, 1)
+    assert km.vmem_estimate(attend) <= km.vmem_budget_bytes()
+
+
+@pytest.mark.parametrize("geom", sorted(km.LADDER))
+def test_paged_default_geometry_is_one_program_a_row(geom):
+    """With no head block named (what the engine and the benchmark's
+    serving cells run), both launches of `paged_append_attend` take all
+    of a page's KV heads a program at every rung of the ladder: 16
+    programs for 16 rows, two landing buffers a pool, inside the VMEM
+    budget. Nothing but the shapes decides it."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        _default_head_block, paged_append_attend)
+    p = km.LADDER[geom]
+    hkv, d, page, dt, B = (p["kv_heads"], p["dm"] // p["heads"], p["page"],
+                           p["dtype"], 16)
+    cols = max(1, p["seq"] // page)
+    q = km.sds((B, p["heads"], d), dt)
+    pool = km.sds((B * cols + 1, hkv, page, d), dt)
+    row = km.sds((B, hkv, d), dt)
+    table, vec = km.sds((B, cols), "int32"), km.sds((B,), "int32")
+    assert _default_head_block(page, hkv, d, dt,
+                               p["heads"] // hkv) == hkv
+    write, attend = km.harvest(
+        lambda: jax.eval_shape(paged_append_attend, q, pool, pool, row,
+                               row, table, vec, vec),
+        root=REPO)
+    assert write.grid == attend.grid == (B,)
+    assert [sc.shape for sc in attend.scratch[:2]] == [
+        (2, hkv, page, d)] * 2
+    assert km.vmem_estimate(attend) <= km.vmem_budget_bytes()
 
 
 # -- CLI ---------------------------------------------------------------------
@@ -361,16 +391,3 @@ def test_autotune_geom_check_refuses_before_building(tmp_path):
     with pytest.raises(ValueError, match="geometry-refused"):
         at.tune("k", "key2", [128], build_and_run, cache=cache,
                 geom_check=geom_check)
-
-
-def test_resolve_vb_clamped_by_vmem_budget(monkeypatch):
-    """The epilogue vocab tile self-clamps: a 2048-wide request at
-    r06 scale (dm=2048, bf16) resolves to the largest 128-multiple
-    whose double-buffered slab fits half the budget."""
-    monkeypatch.delenv("PT_VMEM_BUDGET_MB", raising=False)
-    from paddle_tpu.ops.pallas.decode_megakernel import _resolve_vb
-    import jax.numpy as jnp
-    assert _resolve_vb(2048, 2048, 50304, jnp.bfloat16, 24, 128) == 896
-    assert _resolve_vb(2048, 1024, 50304, jnp.bfloat16, 24, 128) == 1920
-    # small tiles pass through untouched
-    assert _resolve_vb(256, 2048, 50304, jnp.bfloat16, 24, 128) == 256

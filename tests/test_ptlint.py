@@ -556,6 +556,46 @@ class Engine:
                for f in findings)
 
 
+_MEMBER_CALL_SRC = """
+import jax
+import jax.numpy as jnp
+
+
+class Kind:
+    def step(self, x):
+        return jnp.sum(x){kind_sync}
+
+
+class Engine:
+    def _traced(self, x):
+        return self.kind.step(x)
+
+    def build(self):
+        return jax.jit(self._traced)
+
+    def step(self, x):
+        return jnp.sum(x){host_sync}
+"""
+
+
+@pytest.mark.parametrize("kind_sync,host_sync,flagged", [
+    ("", ".item()", None), (".item()", "", "Kind.step")])
+def test_member_call_is_not_the_callers_own_method(tmp_path, kind_sync,
+                                                   host_sync, flagged):
+    """``self.kind.step(x)`` inside traced code runs the MEMBER's
+    ``step``: the caller's own host-side ``step`` stays out of trace
+    scope (the decode step's layer scan calls ``self.kind.step`` beside
+    the engine's scheduler ``step``), and the member's, where the file
+    holds it, still enters."""
+    findings = _lint(tmp_path, {"mod.py": _MEMBER_CALL_SRC.format(
+        kind_sync=kind_sync, host_sync=host_sync)})
+    hits = [f.symbol for f in findings if f.rule == "PT001"]
+    if flagged is None:
+        assert not hits, hits
+    else:
+        assert hits and all(h.endswith(flagged) for h in hits), hits
+
+
 def test_module_level_alias_resolves(tmp_path):
     """``run = _impl`` at module level: jitting the alias roots _impl,
     and a same-named function elsewhere in the file stays host code."""

@@ -222,9 +222,11 @@ def test_make_engine_gives_the_default_engine_with_no_pages(model):
     eng.check_request(200, 8)                    # many chunks: admitted
     with pytest.raises(ValueError, match="max_seq_len"):
         eng.check_request(250, 8)
-    for kw in ({"mega": True}, {"fused": False}, {"speculative_k": 2}):
-        with pytest.raises(NotImplementedError, match="retention"):
-            PagedDecodeEngine(model, n_pages=0, max_slots=2, **kw)
+    with pytest.raises(NotImplementedError, match="retention"):
+        PagedDecodeEngine(model, n_pages=0, max_slots=2, speculative_k=2)
+    for removed in ({"mega": True}, {"fused": False}):
+        with pytest.raises(TypeError):
+            PagedDecodeEngine(model, n_pages=0, max_slots=2, **removed)
 
 
 def test_softmax_layers_keep_their_one_pass_cap():

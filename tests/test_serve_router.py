@@ -13,6 +13,7 @@ import time
 import numpy as np
 import pytest
 
+from _fleetobs import assert_flushed_trace_stitches
 from paddle_tpu import native, stats
 from paddle_tpu.serving import Router
 
@@ -100,16 +101,35 @@ def test_replica_death_redistributes_queued_work(tmp_path):
     try:
         router.wait_replicas(2, timeout=90)
         rs = np.random.RandomState(1)
-        # enough decode work that the victim dies mid-flight
-        ids = [router.submit(list(rs.randint(0, 96, size=9)),
-                             max_new_tokens=24) for _ in range(10)]
         victim = "rep0"
-        victim_reqs = [q for q, r in router._assigned.items()
-                       if r == victim]
-        assert victim_reqs, "least-outstanding never placed on rep0?"
-        # give the victim time to admit (and flush) before the kill —
-        # a SIGKILL mid-serve is exactly the case the flush exists for
-        time.sleep(1.0)
+        ids = []
+
+        def victim_open():
+            return [q for q, r in router._assigned.items()
+                    if r == victim and q not in router.results]
+
+        def victim_flushed_a_request():
+            try:
+                return bool(assert_flushed_trace_stitches(victim_trace,
+                                                          ids))
+            except (AssertionError, OSError, ValueError):
+                return False        # no file, or no span of ours, yet
+
+        # The kill lands on the first sign that the victim admitted a
+        # request of this run (its flushed trace stitches one) while it
+        # still holds unfinished ones: it is kept fed until then, as
+        # bench_fleet_churn defers its kill. A replica with a warm
+        # compile cache serves a fixed batch in well under a second,
+        # and a kill that loses nothing proves nothing.
+        deadline = time.monotonic() + 90
+        while not (victim_open() and victim_flushed_a_request()):
+            assert time.monotonic() < deadline, \
+                "the victim never showed an admitted request"
+            while len(victim_open()) < 4:
+                ids.append(router.submit(list(rs.randint(0, 96, size=9)),
+                                         max_new_tokens=64))
+            router.poll()
+        victim_reqs = victim_open()
         pid = router.directory.members()[victim]["pid"]
         os.kill(pid, signal.SIGKILL)
         results = router.drain(timeout=120)
@@ -128,7 +148,6 @@ def test_replica_death_redistributes_queued_work(tmp_path):
         _cleanup(router, procs)
     # the SIGKILLed replica left a complete (atomically flushed) trace
     # whose request-tagged spans still stitch
-    from _fleetobs import assert_flushed_trace_stitches
     assert_flushed_trace_stitches(victim_trace, ids)
 
 

@@ -71,8 +71,8 @@ _LOWER = ("_ms", "ttft", "tpot", "latency", "_tax_frac", "exposed_s",
 # checked BEFORE _HIGHER: rows whose name embeds a higher-is-better
 # fragment but measure a cost (the drain bench's goodput_dip_frac
 # contains "goodput" yet a bigger dip is a worse drain; the kernel
-# launch accounting — launches_per_token / launches_per_step, the
-# single-dispatch megakernel guard — regresses UP, ISSUE 19)
+# launch accounting — launches_per_token / launches_per_step —
+# regresses UP, ISSUE 19)
 _LOWER_FIRST = ("goodput_dip", "fallbacks", "migrate_failed",
                 "launches_per_")
 

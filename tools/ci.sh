@@ -102,15 +102,6 @@
 #                          fail on any non-baselined PT006–PT009
 #                          finding — a kernel whose worst autotune
 #                          geometry stops fitting VMEM fails in seconds
-#   tools/ci.sh mega       single-dispatch-decode smoke (~1 min):
-#                          tiny-model CPU run of profile_decode's
-#                          PD_SECTIONS=mega launches/step report — the
-#                          paged megakernel (plain AND speculative)
-#                          must step in <= 2 pallas launches while the
-#                          per-layer reference pays one per layer,
-#                          counted from the dispatch program's jaxpr
-#                          plus the AOT HLO custom-call count and the
-#                          serve/dispatch_launches window delta
 #   tools/ci.sh prof       device-time-attribution smoke (~1 min):
 #                          tiny-model CPU prompt-length sweep through
 #                          tools/profile_decode.py PD_SECTIONS=prof —
@@ -216,16 +207,6 @@ fi
 if [[ "${1:-}" == "geom" ]]; then
     shift
     exec python tools/ptgeom.py --error-on-new --stats "$@"
-fi
-
-if [[ "${1:-}" == "mega" ]]; then
-    shift
-    # the megakernel's VMEM geometry is statically gated before the
-    # runtime smoke: an over-budget slab/tile fails here by name
-    python tools/ptgeom.py --error-on-new \
-        --kernels mega_decode_layers,mega_logits_sample
-    PD_SIZE=tiny PD_SECTIONS=mega \
-        exec python tools/profile_decode.py "$@"
 fi
 
 if [[ "${1:-}" == "prof" ]]; then

@@ -2,9 +2,9 @@
 """The paged attend against its geometry and the live pages: the time of
 one `paged_append_attend` call (write + attend) and of the attend alone,
 in a scan over 24 layers at GPT-3 XL's shapes (16 slots, 16 KV heads of
-128, pages of 128, a 16-column table), for every head block and one or two
-pages in flight and for every count of heads folded at once, at three fills of
-the table; then what `tune_paged_attention` picks. Needs a TPU; run through ``chiprun``:
+128, pages of 128, a 16-column table), for every head block and for every
+count of heads folded at once, at three fills of the table. Needs a TPU; run
+through ``chiprun``:
 
     python3 tools/paged_attend_probe.py [--other <paged_attention.py>]
 
@@ -18,7 +18,6 @@ import json
 import os
 import statistics
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -29,7 +28,7 @@ FILLS = {"chat16": (83, 83), "decode-heavy": (64, 1088),
          "full": (COLUMNS * PAGE - 1, COLUMNS * PAGE - 1)}
 
 
-def per_call_ms(mod, fused, lengths, table, pools, ppp, hb, iters):
+def per_call_ms(mod, append, lengths, table, pools, hb, iters):
     """Median ms of one layer's call inside a jitted scan over the
     layers of the folded pools."""
     import jax
@@ -39,15 +38,14 @@ def per_call_ms(mod, fused, lengths, table, pools, ppp, hb, iters):
     def step(kp, vp, lengths):
         def layer(carry, i):
             h, kp, vp = carry
-            if fused:
+            if append:
                 o, kp, vp = mod.paged_append_attend(
                     q + h, kp, vp, q, q, i * POOL + table,
-                    i * POOL + table[:, 0], lengths,
-                    pages_per_program=ppp, head_block=hb)
+                    i * POOL + table[:, 0], lengths, head_block=hb)
             else:
                 o = mod.paged_decode_attention(
                     q + h, kp, vp, i * POOL + table, lengths,
-                    pages_per_program=ppp, head_block=hb)
+                    head_block=hb)
             return (o, kp, vp), None
         (h, kp, vp), _ = jax.lax.scan(layer, (jnp.zeros_like(q), kp, vp),
                                       jnp.arange(LAYERS))
@@ -82,13 +80,7 @@ def main(argv=None):
     import jax.numpy as jnp
     import numpy as np
     _require_tpu(jax)
-    from paddle_tpu.ops.pallas import autotune
     from paddle_tpu.ops.pallas import paged_attention as here
-    # a cache of this run's own: the sweep reads the code's defaults,
-    # not a tuned entry on this disk, and leaves none behind
-    scratch = tempfile.TemporaryDirectory()
-    autotune._GLOBAL = autotune.AutotuneCache(
-        os.path.join(scratch.name, "autotune.json"))
     mods = {"here": here}
     if args.other:
         spec = importlib.util.spec_from_file_location("_paged_other",
@@ -119,17 +111,15 @@ def main(argv=None):
         row = out["ms_a_call"][fill] = {
             "live_pages": int(np.sum(-(-(np.asarray(lengths) + 1) // PAGE)))}
         for name, mod in mods.items():
-            geoms = [(None, None)]
+            blocks = [None]
             if mod is here:
-                geoms += [(ppp, hb) for hb in (1, 2, 4, 8, 16)
-                          for ppp in (1, 2)]
-            for ppp, hb in geoms:
-                key = f"{name}.ppp{ppp}.hb{hb}"
-                row[key] = {
+                blocks += [1, 2, 4, 8, 16]
+            for hb in blocks:
+                row[f"{name}.hb{hb}"] = {
                     "append_attend": per_call_ms(mod, True, lengths, table,
-                                                 pools, ppp, hb, args.iters),
+                                                 pools, hb, args.iters),
                     "attend": per_call_ms(mod, False, lengths, table,
-                                          pools, ppp, hb, args.iters)}
+                                          pools, hb, args.iters)}
         # heads folded at once, at the default geometry (a constant of
         # the module: what it costs to compile is not read here)
         kept = here._HEAD_CHUNK
@@ -137,20 +127,13 @@ def main(argv=None):
             here._HEAD_CHUNK = chunk
             row[f"here.default.chunk{chunk}"] = {
                 "append_attend": per_call_ms(here, True, lengths, table,
-                                             pools, None, None, args.iters),
+                                             pools, None, args.iters),
                 "attend": per_call_ms(here, False, lengths, table, pools,
-                                      None, None, args.iters)}
+                                      None, args.iters)}
         here._HEAD_CHUNK = kept
         print(fill, json.dumps(row), file=sys.stderr, flush=True)
-    # what the tuner picks: as `PagedDecodeEngine.autotune` calls it
-    lengths = jnp.full((SLOTS,), COLUMNS * PAGE // 2, jnp.int32)
-    best, timings = here.tune_paged_attention(
-        q, pools[0], pools[1], table, lengths, fused=True)
-    autotune.get_cache().clear()
-    out["tuner"] = {"default": here._resolve_config(
-        None, None, PAGE, HEADS, HEAD_DIM, jnp.bfloat16, 1, COLUMNS, True),
-        "picked": list(best),
-        "ms": {f"ppp{k[0]}.hb{k[1]}": v * 1e3 for k, v in timings.items()}}
+    out["default_head_block"] = here._default_head_block(
+        PAGE, HEADS, HEAD_DIM, jnp.bfloat16, 1)
     print(json.dumps(out))
 
 

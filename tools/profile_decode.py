@@ -9,10 +9,7 @@ sweep (cold vs warm radix-cache admission, asserted — the `tools/ci.sh
 paged` smoke gate); PD_SECTIONS=prof runs the ISSUE 15 device-time
 attribution sweep (roofline fraction, launch tax, step decomposition
 per decode path across PD_LENGTHS prompt lengths — the `tools/ci.sh
-prof` gate); PD_SECTIONS=mega runs the ISSUE 19 launches/step report
-(jaxpr pallas-launch count, AOT HLO custom-call count and the
-serve/dispatch_launches window delta for the megakernel vs per-layer
-paged paths — the `tools/ci.sh mega` gate).
+prof` gate).
 
 Measurement notes:
 - ``jax.block_until_ready`` blocks; the engine's own host loop syncs
@@ -343,86 +340,6 @@ def prof_section(model, size):
     release_engine(donor)
 
 
-def mega_section(model, size):
-    """ISSUE 19 launches/step report: the single-dispatch decode claim
-    as numbers. For each paged path (megakernel, per-layer reference,
-    megakernel+spec) prints
-
-    - pallas launches per engine step, counted from the dispatch
-      program's jaxpr (scan-trip weighted — backend-independent, no
-      execution);
-    - the HLO custom-call count from the AOT lowering (on TPU each
-      pallas launch compiles to one custom-call; in CPU interpret mode
-      pallas lowers to inline HLO, so the count reads 0);
-    - the ``serve/dispatch_launches`` window delta over a short timed
-      drain (host dispatches actually issued).
-
-    The asserts are the `tools/ci.sh mega` CPU smoke gate: the
-    megakernel steps in <= 2 launches (layer-folded kernel + fused
-    sampling epilogue) on the plain AND speculative paths, while the
-    per-layer reference pays two paged launches per layer (the row
-    write, then the attend)."""
-    from paddle_tpu import stats
-    from paddle_tpu.observability import devprof
-    from paddle_tpu.inference.paged_engine import PagedDecodeEngine
-    cfg = model.cfg
-    if cfg.n_layers < 3:
-        # >= 3 layers (cheap at tiny dims) keep the per-layer count, two
-        # launches a layer, well clear of the megakernel's two a step
-        cfg = gpt.GPTConfig(vocab_size=cfg.vocab_size, max_seq_len=256,
-                            d_model=cfg.d_model, n_layers=3,
-                            n_heads=cfg.n_heads, dtype=cfg.dtype)
-        model = gpt.GPT(cfg, seed=0)
-        print("mega section: rebuilt at n_layers=3", flush=True)
-    tiny = size == "tiny" or cfg.d_model <= 64
-    slots, s_pf, n_new = (2, 16, 8) if tiny else (8, 128, 64)
-    chunk = 2 if tiny else 16
-    page = 128
-    n_pages = slots * ((s_pf + n_new + 4) // page + 2) + 2
-    rs = np.random.RandomState(5)
-    counts = {}
-    for label, kw in (("mega", dict(mega=True)),
-                      ("per_layer", dict(mega=False)),
-                      ("mega_spec", dict(mega=True, speculative_k=3))):
-        eng = PagedDecodeEngine(model, n_pages=n_pages, max_slots=slots,
-                                page_size=page, steps_per_call=chunk,
-                                **kw)
-        assert eng.fused, "mega section needs the fused paged path"
-        prompts = [list(rs.randint(0, cfg.vocab_size, s_pf))
-                   for _ in range(slots)]
-        for p in prompts:   # warm compiles + establish live geometry
-            eng.submit(p, max_new_tokens=2)
-        eng.run()
-        fn, fargs = eng.dispatch_fn_args()
-        lpc = devprof.count_pallas_launches(fn, *fargs)
-        per_step = lpc / chunk
-        hlo_cc = devprof.count_hlo_custom_calls(fn, *fargs)
-        d0 = int(stats.get("serve/dispatch_launches", 0))
-        reqs = [eng.submit(p, max_new_tokens=n_new) for p in prompts]
-        eng.run()
-        host_disp = int(stats.get("serve/dispatch_launches", 0)) - d0
-        toks = sum(len(r.tokens) for r in reqs)
-        counts[label] = per_step
-        print(f"mega {label}: launches/step={per_step:g} "
-              f"(jaxpr, {lpc} per {chunk}-step dispatch) "
-              f"hlo_custom_calls="
-              f"{'n/a' if hlo_cc is None else hlo_cc} "
-              f"dispatch_launches_delta={host_disp} "
-              f"({toks} tokens)", flush=True)
-        assert host_disp > 0 and toks > 0
-        release_engine(eng)
-        del eng
-    # the `tools/ci.sh mega` gate: single-dispatch decode, by count
-    assert counts["mega"] <= 2, counts
-    assert counts["mega_spec"] <= 2, counts
-    assert counts["per_layer"] == 2 * cfg.n_layers, (counts,
-                                                     cfg.n_layers)
-    print(f"mega gate: mega {counts['mega']:g} <= 2, spec "
-          f"{counts['mega_spec']:g} <= 2, per-layer reference "
-          f"{counts['per_layer']:g} == 2 x n_layers={cfg.n_layers}",
-          flush=True)
-
-
 def main():
     size = os.environ.get("PD_SIZE", "1p3b")
     cfg = (gpt.gpt3_1p3b(max_seq_len=2048) if size == "1p3b"
@@ -508,9 +425,6 @@ def main():
 
     if "prof" in sections:
         prof_section(model, size)
-
-    if "mega" in sections:
-        mega_section(model, size)
 
 
 if __name__ == "__main__":

@@ -3,7 +3,7 @@
 
     python tools/ptgeom.py                       # sweep + table + gate
     python tools/ptgeom.py --geoms r06           # one ladder rung
-    python tools/ptgeom.py --kernels mega_decode_layers,mega_logits_sample
+    python tools/ptgeom.py --kernels paged_fused,paged_plain
     python tools/ptgeom.py --extra my_kernels.py # off-tree registry
     python tools/ptgeom.py --write-baseline
 

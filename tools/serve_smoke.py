@@ -3,7 +3,7 @@ pipelined decode UNDER FAULT INJECTION on CPU and prove, end to end,
 
 - byte-identical survivor streams at in-flight depth 1 vs 3 on the
   plain, chunked and speculative paths (contiguous engine) and the
-  paged engine, plain and speculative (single-dispatch megakernel);
+  paged engine, plain and speculative;
 - a nan-poisoned request is evicted alone, at harvest, on every path;
 - a queued deadline_s=0 request is evicted without touching peers;
 - the pipeline actually pipelines (serve/host_gap_s samples recorded,
@@ -76,7 +76,7 @@ def main():
         "speculative": lambda d: DecodeEngine(
             model, max_slots=3, max_len=128, speculative_k=3,
             steps_per_call=2, inflight=d),
-        # the serving default (factory → paged, megakernel step)
+        # the serving default (factory → paged)
         "paged": lambda d: make_engine(
             model, n_pages=24, max_slots=3, steps_per_call=2,
             inflight=d),
