@@ -16,6 +16,13 @@ TPU design decisions:
   selects its pages at DMA-schedule time — no per-layer slicing of the
   pool (a lax.dynamic_slice there would copy the full layer pool every
   step).
+- **An admission is one program**: the jitted prefill (one-pass,
+  suffix-only, or a state kind's last chunk) also installs the slot's
+  decode state (``lengths``, ``last``, ``active``, ``remaining``,
+  ``eos_ids``; the speculative history row) through `_install_slot`,
+  and a retirement writes nothing, the program that sampled the last
+  token having cleared ``active``. No eager program runs between two
+  jitted dispatches of the steady state (docs/serving.md).
 - **One decode step**: each layer of a `lax.scan` calls its kind's
   ``step`` (`models/layer_kinds.py`) with the pools as the carry. A
   softmax layer calls `paged_append_attend`, two launches: a small
@@ -131,9 +138,9 @@ class PagedDecodeEngine(ResilientScheduler):
     scales with live tokens. On a chip (one v5e, GPT-3 XL; PERF.md has
     the step times) the decode step compiles and serves, agreeing with
     ``gpt.generate`` up to bf16 ties. The step copies no pool (PR 26)
-    and its attend walks only the pages a slot holds (PR 30); what
-    bounds it now is the weights' read and, between steps, the host's
-    admission work (PERF.md section 5)."""
+    and its attend walks only the pages a slot holds (PR 30), and an
+    admission is one program (PR 34); what bounds it now is the
+    weights' read (PERF.md section 5)."""
 
     def __init__(self, model, n_pages: int, max_slots: int = 8,
                  page_size: int = 128, steps_per_call: int = 1,
@@ -247,10 +254,12 @@ class PagedDecodeEngine(ResilientScheduler):
         self._waiting: collections.deque = collections.deque()
         self.steps = 0
         self.tokens_emitted = 0
+        # the pools, the slot vectors and (speculative only, else None)
+        # the token history: an admission's one program writes them all
         self._prefill_fn = jax.jit(self._prefill_impl,
-                                   donate_argnums=(2, 3))
+                                   donate_argnums=(2, 3, 4, 5))
         self._prefill_sfx_fn = jax.jit(self._prefill_suffix_impl,
-                                       donate_argnums=(2, 3))
+                                       donate_argnums=(2, 3, 4, 5))
         self._multi_fn = jax.jit(self._multi_impl,
                                  donate_argnums=(2, 3, 4))
         self._chunk_fn = jax.jit(self._prefill_chunk_impl,
@@ -576,15 +585,50 @@ class PagedDecodeEngine(ResilientScheduler):
             axis=-1)
         return kp, vp, toks, lengths, last, active, remaining, packed
 
-    def _prefill_impl(self, head, stacked, kp, vp, tokens, true_len,
-                      write_segments):
+    def _install_slot(self, vecs, slot, n, nxt, rem0, eos0, final=True):
+        """THE write of slot ``slot``'s decode state, traced inside the
+        program that sampled its first token ``nxt``: length ``n``, the
+        pending token, budget ``rem0``, eos ``eos0`` and the ``active``
+        flag, on ``vecs`` = (lengths, last, active, remaining,
+        eos_ids). A budget-of-one request (or one whose first token is
+        eos) never activates — the device analog of ``_emit`` retiring
+        it; a prefill-only engine never decodes. ``final`` (traced in
+        the chunked prefill) makes the write conditional: any chunk but
+        a prompt's last leaves the slot as it was, and inactive."""
+        lengths, last, active, remaining, eos_ids = vecs
+        alive = (final & (rem0 > 0) & ((eos0 < 0) | (nxt != eos0))
+                 & (not self.prefill_only))
+        put = lambda arr, new: arr.at[slot].set(
+            jnp.where(final, new, arr[slot]))
+        return (put(lengths, n), put(last, nxt),
+                active.at[slot].set(alive), put(remaining, rem0),
+                put(eos_ids, eos0))
+
+    @staticmethod
+    def _seed_history(toks, slot, row, n, nxt):
+        """Slot ``slot``'s prompt-lookup history (speculative only):
+        ``row`` (a static width) holds the prompt from position 0, its
+        first ``n`` entries real; the pending token goes to index
+        ``n``. What lies beyond is masked by ``lengths`` on read."""
+        width = row.shape[0]
+        old = lax.dynamic_slice(toks, (slot, 0), (1, width))
+        new = jnp.where(jnp.arange(width) < n, row, old[0])
+        toks = lax.dynamic_update_slice(toks, new[None], (slot, 0))
+        return toks.at[slot, n].set(nxt)
+
+    def _prefill_impl(self, head, stacked, kp, vp, vecs, toks, tokens,
+                      true_len, write_segments, slot, rem0, eos0):
         """One-pass prefill of ONE prompt (1, bucket): the prompt
         attends only to itself (causal), so no cache reads; the valid
         KV rows bulk-write into the sequence's pages per page-run.
         ``write_segments``: (n_seg, L, 3) int32 rows (dst_page_row,
         src_start, run) per layer — page-run copies resolved host-side
         (statically shaped per bucket: n_seg = ceil(bucket/page) + 1,
-        padded with run=0)."""
+        padded with run=0). The program also installs the slot's decode
+        state (`_install_slot`; ``slot``, ``rem0``, ``eos0`` traced, so
+        one program a bucket) and, where ``toks`` is not None, its
+        prompt-lookup history from the bucket row: the admission
+        dispatches nothing else."""
         _note_retrace("paged_prefill")
         cfg = self.cfg
         x = jnp.take(head["wte"], tokens, axis=0)
@@ -661,10 +705,15 @@ class PagedDecodeEngine(ResilientScheduler):
         logits = self._lm_head(head, x[:, idx][:, None])[:, 0]
         nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(
             jnp.int32)[0]
-        return kp, vp, nxt
+        vecs = self._install_slot(vecs, slot, true_len, nxt, rem0, eos0)
+        if toks is not None:
+            toks = self._seed_history(toks, slot, tokens[0], true_len,
+                                      nxt)
+        return kp, vp, vecs, toks, nxt
 
-    def _prefill_suffix_impl(self, head, stacked, kp, vp, tokens, sp,
-                             true_n, segs, cow_src, cow_dst, table_row):
+    def _prefill_suffix_impl(self, head, stacked, kp, vp, vecs, toks,
+                             tokens, sp, true_n, segs, cow_src, cow_dst,
+                             table_row, slot, rem0, eos0, prompt_row):
         """Suffix-only prefill over a CACHED prefix (one prompt whose
         first ``sp`` tokens' KV already sit in shared pages mapped into
         ``table_row``). The cached prefix's forward is never recomputed:
@@ -682,7 +731,11 @@ class PagedDecodeEngine(ResilientScheduler):
 
         tokens: (1, bucket) suffix zero-padded; sp/true_n scalars
         (suffix = prompt[sp:true_n]); table_row: (max_pages,) this
-        slot's UNFOLDED page table row."""
+        slot's UNFOLDED page table row. The slot's decode state is
+        installed here as in `_prefill_impl`; the history row comes
+        from ``prompt_row`` (the WHOLE prompt zero-padded to the largest
+        bucket, None unless speculative), the suffix row not holding
+        the cached prefix."""
         _note_retrace("paged_prefill_suffix")
         cfg = self.cfg
         bucket = tokens.shape[1]
@@ -771,7 +824,10 @@ class PagedDecodeEngine(ResilientScheduler):
         logits = self._lm_head(head, x[:, idx][:, None])[:, 0]
         nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(
             jnp.int32)[0]
-        return kp, vp, nxt
+        vecs = self._install_slot(vecs, slot, true_n, nxt, rem0, eos0)
+        if toks is not None:
+            toks = self._seed_history(toks, slot, prompt_row, true_n, nxt)
+        return kp, vp, vecs, toks, nxt
 
     def _prefill_chunk_impl(self, head, stacked, state, lengths, last,
                             active, remaining, eos_ids, tokens, pos0,
@@ -782,9 +838,9 @@ class PagedDecodeEngine(ResilientScheduler):
         position 0 starts from a zero state (that is how admission
         zeroes the slot), every other from the slot's own. ONE program
         for every chunk of every prompt: the scalars are traced. The
-        ``final`` chunk also samples the first token and flips the
-        slot's decode state on device (length, pending token, budget,
-        eos, active), so admission runs no eager program of its own."""
+        ``final`` chunk also samples the first token and installs the
+        slot's decode state on device (`_install_slot`), so admission
+        runs no eager program of its own."""
         _note_retrace("paged_prefill_chunk")
         x = jnp.take(head["wte"], tokens, axis=0)
         if head["wpe"] is not None:
@@ -807,15 +863,9 @@ class PagedDecodeEngine(ResilientScheduler):
             x, idx, 1, axis=1))[:, 0]
         nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(
             jnp.int32)[0]
-        # a budget-of-one request (or one whose first token is eos)
-        # never activates; a prefill-only engine never decodes
-        alive = (final & (rem0 > 0) & ((eos0 < 0) | (nxt != eos0))
-                 & (not self.prefill_only))
-        put = lambda arr, new: arr.at[slot].set(
-            jnp.where(final, new, arr[slot]))
-        return (state, put(lengths, pos0 + n_valid), put(last, nxt),
-                active.at[slot].set(alive), put(remaining, rem0),
-                put(eos_ids, eos0), nxt)
+        return (state, *self._install_slot(
+            (lengths, last, active, remaining, eos_ids), slot,
+            pos0 + n_valid, nxt, rem0, eos0, final), nxt)
 
     # -- scheduler ----------------------------------------------------------
 
@@ -1101,13 +1151,14 @@ class PagedDecodeEngine(ResilientScheduler):
                 jnp.asarray(out, self.kp.dtype))
 
     def _admit(self, req: Request, slot: int):
-        """Reserve pages, dispatch the one-pass (or suffix-only)
-        prefill, and flip the slot live — WITHOUT syncing on the
-        sampled first token: it stays on device (`.at[].set(nxt)`) and
-        rides the harvest queue as a 'prefill' record, so admission
-        enqueues behind in-flight decode dispatches instead of draining
-        them. With the prefix cache on, the longest cached prefix's
-        pages are mapped read-only and only the suffix is prefilled."""
+        """Reserve pages and dispatch the one-pass (or suffix-only)
+        prefill, which also flips the slot live (`_install_slot`): ONE
+        device program an admission, and no sync on the sampled first
+        token — it stays on device and rides the harvest queue as a
+        'prefill' record, so admission enqueues behind in-flight decode
+        dispatches instead of draining them. With the prefix cache on,
+        the longest cached prefix's pages are mapped read-only and only
+        the suffix is prefilled."""
         from paddle_tpu.observability import trace
         # ptlint: disable=PT001 -- req.prompt is a host int list
         # (submit coerced it); this is an upload, never a sync
@@ -1116,7 +1167,7 @@ class PagedDecodeEngine(ResilientScheduler):
         if self.kind.chunked_prefill:
             with trace.span("serve/admit", slot=slot, prompt=n,
                             bucket=self.prefill_chunk, cached=0,
-                            rid=req.rid):
+                            rid=req.rid, programs=0):
                 self._admit_chunked(req, slot, prompt)
             return
         sp, cow_src, chain = (self._match_prefix(prompt, slot)
@@ -1127,9 +1178,11 @@ class PagedDecodeEngine(ResilientScheduler):
         # the span opens once the reservation HELD (the MemoryError-
         # retried admission re-runs this method and must leave no
         # phantom span) and closes at the _pending.append: the host's
-        # preparation, the prefill enqueue, the slot-state updates
+        # preparation and the prefill enqueue, the one device program
+        # (`programs`) the admission dispatches
         with trace.span("serve/admit", slot=slot, prompt=n,
-                        bucket=bucket, cached=sp, rid=req.rid):
+                        bucket=bucket, cached=sp, rid=req.rid,
+                        programs=1):
             self._admit_reserved(req, slot, prompt, bucket, sp, cow_src,
                                  chain)
 
@@ -1230,6 +1283,13 @@ class PagedDecodeEngine(ResilientScheduler):
         stats.add("serve/dispatches/prefill")
         flight.record(req.rid, "admit", slot=slot, prompt=n,
                       bucket=bucket, cached=sp)
+        rem0 = req.max_new_tokens - 1
+        eos0 = -1 if req.eos_id is None else int(req.eos_id)
+        # numpy straight into the jitted call: the dispatch uploads the
+        # arguments itself, no eager program or `jnp` call per argument
+        slot_args = (np.int32(slot), np.int32(rem0), np.int32(eos0))
+        vecs = (self.lengths, self.last, self.active, self.remaining,
+                self.eos_ids)
         if sp:
             suffix = np.zeros((1, bucket), np.int32)
             suffix[0, :n - sp] = prompt[sp:]
@@ -1248,62 +1308,48 @@ class PagedDecodeEngine(ResilientScheduler):
             mx = (self.cfg.max_seq_len + self.page - 1) // self.page
             row = np.zeros((mx,), np.int32)
             row[:len(tab)] = tab
+            prompt_row = None
+            if self.spec_k:
+                prompt_row = np.zeros((self.buckets[-1],), np.int32)
+                prompt_row[:n] = prompt
             with trace.span("serve/dispatch", kind="prefill",
                             bucket=bucket):
-                self.kp, self.vp, nxt = self._prefill_sfx_fn(
-                    self._head, self._stacked, self.kp, self.vp,
-                    jnp.asarray(suffix), jnp.int32(sp), jnp.int32(n),
-                    jnp.asarray(segs), jnp.int32(cow_src),
-                    jnp.int32(cow_dst), jnp.asarray(row))
+                self.kp, self.vp, vecs, self.toks, nxt = \
+                    self._prefill_sfx_fn(
+                        self._head, self._stacked, self.kp, self.vp,
+                        vecs, self.toks, suffix, np.int32(sp),
+                        np.int32(n), segs, np.int32(cow_src),
+                        np.int32(cow_dst), row, *slot_args, prompt_row)
         else:
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :n] = prompt
-            # page-run copy plan: valid rows [0, n) at page boundaries
+            # page-run copy plan: valid rows [0, n) at page boundaries,
+            # every layer's row of a segment at once
             max_seg = bucket // self.page + 1
             segs = np.zeros((max_seg, self.cfg.n_layers, 3), np.int32)
+            layer_rows = np.arange(self.cfg.n_layers) * self.P
             t, i = 0, 0
             while t < n:
-                pid = tab[t // self.page]
                 run = min(n - t, self.page - (t % self.page))
-                for l in range(self.cfg.n_layers):
-                    segs[i, l] = (l * self.P + pid, t, run)
+                segs[i, :, 0] = layer_rows + tab[t // self.page]
+                segs[i, :, 1:] = (t, run)
                 t += run
                 i += 1
             with trace.span("serve/dispatch", kind="prefill",
                             bucket=bucket):
-                self.kp, self.vp, nxt = self._prefill_fn(
-                    self._head, self._stacked, self.kp, self.vp,
-                    jnp.asarray(padded), jnp.int32(n),
-                    jnp.asarray(segs))
+                self.kp, self.vp, vecs, self.toks, nxt = \
+                    self._prefill_fn(
+                        self._head, self._stacked, self.kp, self.vp,
+                        vecs, self.toks, padded, np.int32(n), segs,
+                        *slot_args)
+        (self.lengths, self.last, self.active, self.remaining,
+         self.eos_ids) = vecs
         if self.fleet is not None and self._prefix is not None \
                 and n >= self.page:
             # the prefill dispatch that fills the registered pages is
             # enqueued; publication reads them back (block_until_ready
             # implicit in the host transfer) — newly-canonical only
             self._fleet_publish()
-        rem0 = req.max_new_tokens - 1
-        eos0 = -1 if req.eos_id is None else int(req.eos_id)
-        # a budget-of-one request (or one whose first token is eos)
-        # never activates — the device analog of _emit retiring it
-        alive = jnp.logical_and(
-            rem0 > 0, jnp.logical_or(eos0 < 0, nxt != eos0))
-        if self.spec_k:
-            # seed the prompt-lookup history: prompt rows [0, n), the
-            # pending sampled token at index n (both uploads — nxt
-            # stays on device)
-            self.toks = self.toks.at[slot, :n].set(jnp.asarray(prompt))
-            self.toks = self.toks.at[slot, n].set(nxt)
-        self.lengths = self.lengths.at[slot].set(n)
-        self.last = self.last.at[slot].set(nxt)
-        if self.prefill_only:
-            # a prefill replica never decodes: the slot stays bound
-            # (its pages leave via detach_handoff) but device-inactive,
-            # and the dispatch loop skips it (_disp_rem stays 0)
-            self.active = self.active.at[slot].set(False)
-        else:
-            self.active = self.active.at[slot].set(alive)
-        self.remaining = self.remaining.at[slot].set(rem0)
-        self.eos_ids = self.eos_ids.at[slot].set(eos0)
         self._slot_req[slot] = req
         self._host_len[slot] = n
         self._proj_len[slot] = n
@@ -1318,10 +1364,13 @@ class PagedDecodeEngine(ResilientScheduler):
             self.on_token(req, token)
         if ((req.eos_id is not None and token == req.eos_id)
                 or len(req.tokens) >= req.max_new_tokens):
+            # nothing to write on the device: the program that sampled
+            # this token already cleared the slot's `active` (budget or
+            # eos: `_install_slot` at prefill, `_multi_impl` /
+            # `spec_accept` at decode)
             req.done = True
             self._slot_req[slot] = None
             self._release(slot)
-            self.active = self.active.at[slot].set(False)
             self._obs_request_end(req)
 
     # -- disaggregated handoff (docs/serving.md "Disaggregated serving") ----
@@ -1499,7 +1548,11 @@ class PagedDecodeEngine(ResilientScheduler):
         upload the page rows, register the prompt's full pages locally
         (future submits of the same prefix hit them — and publish to
         the fleet like any registration), then reconstruct the device
-        state the prefill replica's ``_admit`` would have left."""
+        state the prefill replica's ``_admit`` would have left. No
+        program runs here to carry the slot's install, and a hand-off
+        is no steady state: this path, like eviction (`_on_evict`,
+        `_fail`), `detach_handoff` and `_scrub_pages`, keeps its eager
+        writes."""
         import time
         from paddle_tpu.observability import flight
         n = req.kv_ntok
@@ -1757,43 +1810,53 @@ class PagedDecodeEngine(ResilientScheduler):
         kp, vp = jnp.zeros_like(self.kp), jnp.zeros_like(self.vp)
         state = jax.tree_util.tree_map(jnp.zeros_like, self.state)
         mx = (self.cfg.max_seq_len + self.page - 1) // self.page
+        # donated with the mirror pools: COPIES of the slot vectors (the
+        # live ones are left as they are) and a mirror of the history
+        copy = lambda a: a + 0
+        vecs = (copy(self.lengths), copy(self.last), self.active & False,
+                copy(self.remaining), copy(self.eos_ids))
+        toks = None if self.toks is None else jnp.zeros_like(self.toks)
         if self.kind.chunked_prefill:
-            # donated: copies of the slot vectors, and the mirror pools
-            copy = lambda a: a + 0
-            state = self._chunk_fn(
-                self._head, self._stacked, state, copy(self.lengths),
-                copy(self.last), self.active & False,
-                copy(self.remaining), copy(self.eos_ids),
+            state, *vecs, _ = self._chunk_fn(
+                self._head, self._stacked, state, *vecs,
                 jnp.zeros((1, self.prefill_chunk), jnp.int32),
                 jnp.int32(0), jnp.int32(1), jnp.int32(0),
-                jnp.bool_(False), jnp.int32(0), jnp.int32(-1))[0]
+                jnp.bool_(False), jnp.int32(0), jnp.int32(-1))
+            vecs = tuple(vecs)
+        # the arguments in the admission's own form (numpy, uploaded by
+        # the dispatch), so that the first request finds the very
+        # program: slot 0, a budget of one (never active), no eos
+        slot_args = (np.int32(0), np.int32(0), np.int32(-1))
         for b in self.buckets if self.kind.pages else ():
             segs = np.zeros((b // self.page + 1, self.cfg.n_layers, 3),
                             np.int32)
-            kp, vp, _ = self._prefill_fn(
-                self._head, self._stacked, kp, vp,
-                jnp.zeros((1, b), jnp.int32), jnp.int32(1),
-                jnp.asarray(segs))
+            kp, vp, vecs, toks, _ = self._prefill_fn(
+                self._head, self._stacked, kp, vp, vecs, toks,
+                np.zeros((1, b), np.int32), np.int32(1), segs,
+                *slot_args)
             if self._prefix is not None:
                 # the warm-hit admission path (suffix-only prefill)
                 # compiles per bucket too
                 sfx_segs = np.zeros((b // self.page + 2, 4), np.int32)
-                kp, vp, _ = self._prefill_sfx_fn(
-                    self._head, self._stacked, kp, vp,
-                    jnp.zeros((1, b), jnp.int32), jnp.int32(0),
-                    jnp.int32(1), jnp.asarray(sfx_segs), jnp.int32(-1),
-                    jnp.int32(-1), jnp.zeros((mx,), jnp.int32))
+                prompt_row = (np.zeros((self.buckets[-1],), np.int32)
+                              if self.spec_k else None)
+                kp, vp, vecs, toks, _ = self._prefill_sfx_fn(
+                    self._head, self._stacked, kp, vp, vecs, toks,
+                    np.zeros((1, b), np.int32), np.int32(0),
+                    np.int32(1), sfx_segs, np.int32(-1), np.int32(-1),
+                    np.zeros((mx,), np.int32), *slot_args, prompt_row)
+        # (the cached no-fault poison mask is made here, not by the
+        # first dispatch)
         if self.spec_k:
             out = self._verify_fn(
-                self._head, self._stacked, kp, vp, self._table(),
-                jnp.zeros_like(self.toks), self.lengths, self.last,
-                self.active, self.remaining, self.eos_ids,
-                jnp.zeros((self.S,), bool))
+                self._head, self._stacked, kp, vp, self._table(), toks,
+                self.lengths, self.last, self.active, self.remaining,
+                self.eos_ids, self._poison_mask())
         else:
             out = self._multi_fn(
                 self._head, self._stacked, kp, vp, state, self._table(),
                 self.lengths, self.last, self.active, self.remaining,
-                self.eos_ids, jnp.zeros((self.S,), bool))
+                self.eos_ids, self._poison_mask())
         jax.block_until_ready(out)
         stats.observe("serve/warmup_s", time.perf_counter() - t0)
 

@@ -174,6 +174,52 @@ def test_decode_dataflow_copies_no_pool(one_chip, pool_pages):
     assert not copies, copies
 
 
+def test_prefill_installs_the_slot_in_place(one_chip):
+    """An admission's one program (`PagedDecodeEngine._prefill_impl`,
+    the one-pass prefill at a chat-sized bucket) at XL widths: the two
+    pools AND the five slot vectors it installs the slot's decode state
+    into are aliased in to out, and the compiled program holds no
+    ``copy`` of a slot vector or of a whole pool. The engine is made of
+    shapes alone (built under ``eval_shape``: no weight, no pool); the
+    jitted body reads only its configuration."""
+    from paddle_tpu.inference.paged_engine import PagedDecodeEngine
+    from paddle_tpu.models import gpt
+    cfg = gpt.GPTConfig(vocab_size=VOCAB, max_seq_len=SEQ, d_model=DM,
+                        n_layers=LAYERS, n_heads=HEADS, dtype=BF16)
+    made = []
+
+    def build():
+        eng = PagedDecodeEngine(gpt.GPT(cfg), n_pages=POOL_PAGES,
+                                max_slots=PAGED_SLOTS, page_size=PAGE)
+        made.append(eng)
+        return (eng._head, eng._stacked, eng.kp, eng.vp,
+                (eng.lengths, eng.last, eng.active, eng.remaining,
+                 eng.eos_ids), eng.toks)
+
+    state = jax.eval_shape(build)
+    bucket, scalar = 32, _sds((), jnp.int32)
+    text = _compiled_text(
+        made[0]._prefill_impl,
+        (*state, _sds((1, bucket), jnp.int32), scalar,
+         _sds((bucket // PAGE + 1, LAYERS, 3), jnp.int32),
+         scalar, scalar, scalar),
+        one_chip, donate=(2, 3, 4, 5))
+    header = text.splitlines()[0]
+    aliased = {int(m) for m in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    entry, = [ln for ln in text.splitlines() if ln.startswith("ENTRY")]
+    params = re.findall(r"[\w.]+: (\w+\[[\d,]*\])",
+                        entry.split(") -> ")[0])
+    pool = f"bf16[{LAYERS * POOL_PAGES + 1},{HEADS},{PAGE},{HEAD_DIM}]"
+    vec, flag = f"s32[{PAGED_SLOTS}]", f"pred[{PAGED_SLOTS}]"
+    assert sorted(params[n] for n in aliased) \
+        == sorted([pool] * 2 + [vec] * 4 + [flag])
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(r"= \S+ copy\(", ln)
+              and any(f"= {sh}" in ln for sh in (pool, vec, flag))]
+    assert not copies, copies
+
+
 def test_paged_decode_attention(one_chip):
     from paddle_tpu.ops.pallas.paged_attention import (
         paged_decode_attention)

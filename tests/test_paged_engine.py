@@ -4,6 +4,9 @@ The invariants: greedy output BIT-IDENTICAL to gpt.generate whatever the
 page/chunk geometry; pages allocate on demand, free at retirement, and
 get reused; a too-small pool fails loudly instead of wedging."""
 
+import collections
+import contextlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -233,6 +236,208 @@ def test_paged_warmup_pretraces():
     assert eng._multi_fn._cache_size() == 1, "serving recompiled"
 
 
+@contextlib.contextmanager
+def _eager_primitives():
+    """Counts, by name, every primitive JAX evaluates EAGERLY while the
+    block runs: each is a device program of its own (an ``.at[].set``
+    is five of them). A warmed ``jax.jit`` call takes the C++ fast path
+    and does not come by here, one that has to trace does (as ``jit``);
+    an upload (``device_put``) is no program and no primitive here."""
+    from jax._src import core
+    seen = collections.Counter()
+    orig = core.EvalTrace.process_primitive
+
+    def counting(self, primitive, args, params):
+        seen[primitive.name] += 1
+        return orig(self, primitive, args, params)
+
+    core.EvalTrace.process_primitive = counting
+    try:
+        yield seen
+    finally:
+        core.EvalTrace.process_primitive = orig
+
+
+def test_eager_primitive_counter_sees_a_slot_write():
+    """The counter the tests below lean on: an eager slot write is
+    seen, a warmed jitted call and an upload are not."""
+    import jax
+    x = jnp.zeros((4,), jnp.int32)
+    bump = jax.jit(lambda a: a + 1)
+    bump(x)
+    with _eager_primitives() as eager:
+        bump(x)
+        jnp.asarray(np.zeros((3,), np.int32))
+    assert not eager, dict(eager)
+    with _eager_primitives() as eager:
+        x.at[1].set(3)
+    assert eager["scatter"] == 1
+
+
+def _count_dispatches(eng):
+    """Wraps every jitted program of ``eng``; returns the Counter of
+    calls by attribute name."""
+    calls = collections.Counter()
+    for name in ("_prefill_fn", "_prefill_sfx_fn", "_chunk_fn",
+                 "_multi_fn", "_verify_fn"):
+        def counted(*a, _fn=getattr(eng, name), _name=name):
+            calls[_name] += 1
+            return _fn(*a)
+        setattr(eng, name, counted)
+    return calls
+
+
+@pytest.mark.parametrize("spec", [0, 3])
+def test_admission_is_one_program_and_retirement_none(spec):
+    """ISSUE 34: on a warmed engine an admission dispatches exactly ONE
+    device program, the prefill that also installs the slot's decode
+    state, cold or through the prefix cache's suffix path, and a
+    retirement dispatches none: no eager program runs between two
+    jitted dispatches."""
+    model = _model()
+    eng = PagedDecodeEngine(model, n_pages=8, max_slots=2,
+                            buckets=(16, 256), warmup=True,
+                            speculative_k=spec)
+    live = [np.asarray(v).copy() for v in
+            (eng.lengths, eng.last, eng.active, eng.remaining,
+             eng.eos_ids)]
+    assert not any(v.any() for v in live[:4]) and (live[4] == -1).all(), \
+        "warm-up touched the live slot vectors"
+    calls = _count_dispatches(eng)
+    decode = "_verify_fn" if spec else "_multi_fn"
+    rs = np.random.RandomState(11)
+    shared = list(rs.randint(0, 96, size=128))          # one full page
+    prompts = [list(rs.randint(0, 96, size=9)),          # cold, bucket 16
+               shared + REPETITIVE,                      # cold, registers
+               shared + list(rs.randint(0, 96, size=5))]  # warm: suffix
+    want = ["_prefill_fn", "_prefill_fn", "_prefill_sfx_fn"]
+    for prompt, prefill in zip(prompts, want):
+        req = eng.submit(prompt, max_new_tokens=4)
+        calls.clear()
+        with _eager_primitives() as eager:
+            eng._admit_waiting()
+        assert not eager, dict(eager)
+        assert calls == {prefill: 1}
+        with _eager_primitives() as eager:
+            eng.run()
+        assert req.done and req.tokens == _reference(model, prompt, 4)
+        assert not eager, dict(eager)                # the retirement
+        assert set(calls) == {prefill, decode}
+    assert not np.asarray(eng.active).any()
+
+
+@pytest.mark.parametrize("spec", [0, 3])
+@pytest.mark.parametrize("ends", ["budget_at_prefill", "eos_at_prefill",
+                                  "budget_at_decode", "eos_at_decode"])
+def test_device_clears_active_at_every_end(ends, spec):
+    """However a request ends, the program that sampled its last token
+    has already cleared the slot's ``active`` on the device: the host
+    writes nothing at retirement (no eager primitive runs), and the
+    neighbour slot goes on decoding."""
+    model = _model()
+    rs = np.random.RandomState(1)
+    prompt = list(rs.randint(0, 96, size=40))
+    other = list(rs.randint(0, 96, size=9))
+    ref = _reference(model, prompt, 8)
+    # the first token the stream had not held before: an eos to stop at
+    k = next(i for i in range(1, 8) if ref[i] not in ref[:i])
+    n_new, eos = {"budget_at_prefill": (1, None),
+                  "eos_at_prefill": (8, ref[0]),
+                  "budget_at_decode": (k + 1, None),
+                  "eos_at_decode": (8, ref[k])}[ends]
+    eng = PagedDecodeEngine(model, n_pages=8, max_slots=2, buckets=(64,),
+                            warmup=True, speculative_k=spec)
+    with _eager_primitives() as eager:
+        long = eng.submit(other, max_new_tokens=60)
+        req = eng.submit(prompt, max_new_tokens=n_new, eos_id=eos)
+        eng._admit_waiting()
+        slot, neighbour = (eng._slot_req.index(req),
+                           eng._slot_req.index(long))
+        while not req.done:
+            eng.step()
+        eng.drain()
+    assert not eager, dict(eager)
+    assert req.tokens == (ref[:1] if "prefill" in ends else ref[:k + 1])
+    active = np.asarray(eng.active)
+    assert not active[slot]
+    assert not long.done and active[neighbour]
+    eng.run()
+    assert long.tokens == _reference(model, other, 60)
+    assert not np.asarray(eng.active).any()
+    _assert_pool_drained(eng, 8)
+
+
+def _serve_admission_case(model, case, depth):
+    """The generated tokens of ``case``'s requests at pipeline depth
+    ``depth``, and what `gpt.generate` gives for them."""
+    rs = np.random.RandomState(17)
+    kw = dict(n_pages=16, max_slots=2, steps_per_call=2, inflight=depth)
+    page = list(rs.randint(0, 96, size=128))
+    if case == "budget_one":
+        jobs = [(list(rs.randint(0, 96, size=n)), 1, None)
+                for n in (5, 23)]
+    elif case == "first_token_eos":
+        prompt = list(rs.randint(0, 96, size=19))
+        jobs = [(prompt, 6, _reference(model, prompt, 1)[0]),
+                (list(rs.randint(0, 96, size=7)), 6, None)]
+    elif case == "cow_prefix_hit":
+        # an exact-page-multiple prompt served twice: the second is a
+        # full match, its last page copied before the final row lands
+        both = page + list(rs.randint(0, 96, size=128))
+        jobs = [(both, 5, None), (both, 7, None)]
+        kw["max_slots"] = 1
+    elif case == "spec_prefix_hit":
+        # the history row of a warm hit comes from the whole prompt,
+        # not from the suffix the program prefills
+        jobs = [(page + REPETITIVE, 9, None),
+                (page + REPETITIVE[:7], 9, None)]
+        kw.update(max_slots=1, speculative_k=4)
+    else:
+        assert case == "prefill_only"
+        jobs = [(list(rs.randint(0, 96, size=n)), 6, None)
+                for n in (40, 130)]
+    want = [_reference(model, p, n, eos) for p, n, eos in jobs]
+    if case != "prefill_only":
+        eng = PagedDecodeEngine(model, **kw)
+        reqs = [eng.submit(p, max_new_tokens=n, eos_id=eos)
+                for p, n, eos in jobs]
+        eng.run()
+        assert not np.asarray(eng.active).any()
+        _assert_pool_drained(eng, 16)
+        return [list(r.tokens) for r in reqs], want
+    # a prefill replica samples the first token and never decodes; the
+    # stream continues on the replica that takes the pages over
+    pe = PagedDecodeEngine(model, prefill_only=True, **kw)
+    de = PagedDecodeEngine(model, **kw)
+    outs = []
+    for p, n, _ in jobs:
+        r = pe.submit(p, max_new_tokens=n)
+        while not r.tokens:
+            pe.step()
+        pe.drain()
+        slot = pe._slot_req.index(r)
+        assert not np.asarray(pe.active).any()
+        assert int(np.asarray(pe.lengths)[slot]) == len(p)
+        outs.append(de.submit_handoff(*pe.detach_handoff(r)))
+    de.run()
+    return [list(r.tokens) for r in outs], want
+
+
+@pytest.mark.parametrize("case", ["budget_one", "first_token_eos",
+                                  "cow_prefix_hit", "spec_prefix_hit",
+                                  "prefill_only"])
+def test_admission_cases_match_generate_at_both_depths(case):
+    """What the prefill program now installs (a budget that ends at the
+    first token, a first-token eos, a copy-on-write page under a warm
+    hit, the speculative history, a prefill-only slot that must stay
+    inactive) leaves every stream `gpt.generate`'s, synchronous and
+    pipelined."""
+    model = _model()
+    got, want = _serve_admission_case(model, case, 1)
+    assert got == want
+    assert _serve_admission_case(model, case, 2)[0] == got
+
+
 def _primitives(jaxpr, prefix):
     """(primitive name, operand shapes) of every equation of ``jaxpr``
     and the jaxprs inside it whose name starts with ``prefix``."""
@@ -328,21 +533,17 @@ def test_warm_prefix_hit_prefills_only_suffix():
     tail_b = list(rs.randint(0, 96, size=17))
     eng = PagedDecodeEngine(model, n_pages=16, max_slots=1,
                             steps_per_call=4)
-    calls = {"full": 0, "sfx": 0}
-    full_fn, sfx_fn = eng._prefill_fn, eng._prefill_sfx_fn
-    eng._prefill_fn = (lambda *a: (calls.__setitem__(
-        "full", calls["full"] + 1), full_fn(*a))[1])
-    eng._prefill_sfx_fn = (lambda *a: (calls.__setitem__(
-        "sfx", calls["sfx"] + 1), sfx_fn(*a))[1])
+    calls = _count_dispatches(eng)
+    prefills = lambda: (calls["_prefill_fn"], calls["_prefill_sfx_fn"])
 
     r1 = eng.submit(sys_prompt + tail_a, max_new_tokens=8)
     eng.run()
-    assert calls == {"full": 1, "sfx": 0}      # cold: full prefill
+    assert prefills() == (1, 0)                # cold: full prefill
     h0 = stats.get("serve/prefix_hit_tokens")
 
     r2 = eng.submit(sys_prompt + tail_b, max_new_tokens=8)
     eng.run()
-    assert calls == {"full": 1, "sfx": 1}      # warm: suffix ONLY
+    assert prefills() == (1, 1)                # warm: suffix ONLY
     # both full pages (256 tokens) served from cache
     assert stats.get("serve/prefix_hit_tokens") - h0 == 256
     assert r1.tokens == _reference(model, sys_prompt + tail_a, 8)

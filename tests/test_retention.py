@@ -362,6 +362,10 @@ def test_spans_of_the_chunked_prefill(model):
     assert [(c["tokens"], c["index"], c["last"]) for c in chunks
             if c["slot"] == 1] == [(CHUNK, 0, False), (CHUNK, 1, False),
                                    (3, 2, True)]
+    # an admission of a chunk-prefilled kind only binds the slot: its
+    # programs are the chunks, dispatched later
+    admits = [e[6] for e in events if e[0] == "serve/admit"]
+    assert [a["programs"] for a in admits] == [0, 0]
     steps = [e[6] for e in events if e[0] == "serve/step"]
     per_slot = sum(a.nbytes for a in eng.state.values()) // eng.S
     assert max(s["state_slots"] for s in steps) == 2

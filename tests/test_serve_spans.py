@@ -123,6 +123,12 @@ def test_serving_step_nesting_and_pool_attributes(model):
                 if e[6]["kind"] == "prefill"]
     assert len(prefills) == len(reqs)
     assert {parent[e[4]] for e in prefills} == {"serve/admit"}
+    # an admission is ONE device program: its span says so, and holds
+    # exactly one dispatch, the prefill
+    assert all(e[6]["programs"] == 1 for e in admits)
+    assert sorted(e[5] for e in named("serve/dispatch")
+                  if parent[e[4]] == "serve/admit") \
+        == sorted(e[4] for e in admits) == sorted(e[5] for e in prefills)
     decodes = [e for e in named("serve/dispatch") if e[6]["kind"] == "paged"]
     assert decodes and {parent[e[4]] for e in decodes} == {"serve/step"}
     waits = named("serve/device_wait")
