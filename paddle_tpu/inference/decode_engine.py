@@ -103,8 +103,15 @@ def resolve_engine_weights(model, share_weights_with):
     """The ONE donor-or-build protocol shared by the contiguous and the
     paged engines: returns (cfg, head dict, scan-stacked blocks). With a
     donor, weights alias the donor's (no second copy); otherwise they
-    are built from ``model`` (which must be a dense stack; its blocks
-    are stacked here unless the model carries them stacked)."""
+    are built from ``model`` (its blocks are stacked here unless the
+    model carries them stacked). A stack whose feed-forward changes
+    after ``cfg.leading_dense`` layers keeps those layers as blocks of
+    their own under ``head["lead"]`` (empty for every other model) and
+    stacks the rest. The routed experts' three matrices, stacked over
+    the layers, go under ``head["experts"]`` (None without any) and
+    leave empty leaves behind in the stack: the expert kernel takes the
+    stacks as they are and a layer's number, where a layer cut out by
+    the scan would be a copy of every expert."""
     if model is None:
         if share_weights_with is None:
             raise ValueError(
@@ -116,25 +123,34 @@ def resolve_engine_weights(model, share_weights_with):
         if any(model.blocks[i].moe is not None
                for i in range(cfg.n_layers)):
             raise NotImplementedError(
-                "engines serve dense stacks (MoE decode goes through "
-                "gpt.generate)")
+                "engines serve dense stacks and dropless experts (the "
+                "capacity form's decode goes through gpt.generate)")
     if share_weights_with is not None:
         if share_weights_with.cfg is not cfg:
             raise ValueError(
                 "share_weights_with engine serves a different model")
         return (cfg, share_weights_with._head,
                 share_weights_with._stacked)
+    lead = cfg.leading_dense
     head = {"wte": model.wte, "wpe": model.wpe,
             "lnf_scale": model.lnf_scale,
             "lnf_bias": model.lnf_bias,
-            "lm_head": model.lm_head}
+            "lm_head": model.lm_head,
+            "lead": tuple(model.blocks[i] for i in range(lead))}
     # a model whose blocks are already stacked (a state built by
     # init_train_state(stacked=True), or weights loaded that way) is
     # served from that stack: no second copy of every block weight
     stacked = getattr(model, "_stacked_blocks", None)
     if stacked is None:
         stacked = gpt_lib.stack_block_weights(
-            [model.blocks[i] for i in range(cfg.n_layers)])
+            [model.blocks[i] for i in range(lead, cfg.n_layers)])
+    head["experts"] = None
+    if cfg.routed_experts:
+        names = ("w_gate", "w_up", "w_down")
+        head["experts"] = tuple(getattr(stacked.experts, n) for n in names)
+        stacked = stacked.merge_params({
+            f"experts.{n}": jnp.zeros((a.shape[0], 0), a.dtype)
+            for n, a in zip(names, head["experts"])})
     return cfg, head, stacked
 
 
@@ -277,10 +293,11 @@ class _Inflight:
     first token), 'decode' (packed (3, chunk, S): tokens / emit flags /
     non-finite flags) or 'spec' (packed (chunk, S, K+2))."""
 
-    __slots__ = ("kind", "live", "payload", "t")
+    __slots__ = ("kind", "live", "payload", "t", "routing")
 
     def __init__(self, kind, live, payload, t):
         self.kind, self.live, self.payload, self.t = kind, live, payload, t
+        self.routing = None     # paged engine, a model with routed experts
 
 
 class _HandoffRequest(Request):

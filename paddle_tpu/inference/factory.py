@@ -44,10 +44,11 @@ def make_engine(model, engine: Optional[str] = None, *,
 
     Paged sizing default, from the layers that keep pages
     (`models/layer_kinds.py`): enough pages for every slot to hold a
-    full-length sequence (``max_slots * ceil(max_len / page_size)``),
-    and none for a model whose layers keep a state per sequence
-    instead (the engine then holds a state pool of ``max_slots``
-    slots and prefills prompts of any length in chunks) —
+    full-length sequence (``max_slots * ceil(max_len / page_size)``:
+    pages of keys and values, or of latent rows), and none for a model
+    whose layers keep a state per sequence instead (the engine then
+    holds a state pool of ``max_slots`` slots); a kind that prefills in
+    chunks takes prompts of any length —
     the no-surprises envelope; real deployments size the pool to the
     LIVE-token budget instead (that over-commit is the engine's whole
     point) and pass ``n_pages`` explicitly. The decode step's cost does

@@ -62,16 +62,35 @@ TPU design decisions:
   rows bulk-write into the sequence's pages per page-run. Prompts of
   key/value layers are therefore capped at the largest bucket (512).
 - **Layer kinds** (`models/layer_kinds.py`): the engine reads from the
-  model's kind what state a layer keeps. Softmax attention keeps pages;
-  a kind that keeps a state of fixed size per SEQUENCE (power
-  retention) gets a per-slot state pool beside the page pool, or in its
-  place (``n_pages`` 0 is legal when no layer keeps pages): bound with
-  the slot, zeroed by the first prefill chunk, freed with the slot.
-  Such a kind prefills prompts of ANY length up to the context in
-  chunks of ``prefill_chunk`` tokens that carry the state — one
-  compiled program, the last chunk padded and masked, at most one
-  chunk between two decode steps; a slot still in prefill is not
-  active in the decode step and its state is not touched by it.
+  model's kind what state a layer keeps, how many page pools and of
+  what row shape (``page_pools``: ``kp``/``vp`` of (Hkv, page, D) for
+  softmax attention; ONE pool of (576, page) latent rows for latent
+  attention, which lives in ``state`` with the kind's other pools).
+  Softmax attention keeps pages; a kind that keeps a state of fixed
+  size per SEQUENCE (power retention) gets a per-slot state pool beside
+  the page pool, or in its place (``n_pages`` 0 is legal when no layer
+  keeps pages): bound with the slot, zeroed by the first prefill chunk,
+  freed with the slot. A kind with ``chunked_prefill`` prefills prompts
+  of ANY length up to the context in chunks of ``prefill_chunk`` tokens
+  — one compiled program, the last chunk padded and masked, at most one
+  chunk between two decode steps; a slot still in prefill is not active
+  in the decode step. State kinds carry the state from chunk to chunk;
+  a kind that keeps PAGES (latent attention) has the whole prompt's
+  pages reserved at admission, and a chunk writes its rows into them
+  and attends to the pages written before it and to itself. Page
+  allocation, table, release and the ``kv_pages`` counters are the same
+  for every kind that keeps pages.
+- **A stack of more than one feed-forward** (``cfg.leading_dense``):
+  the leading dense layers run one by one and the rest as ONE scanned
+  body (`_over_layers`); routed experts' matrices reach their kernel as
+  the stacks they are (``head["experts"]``), never as a layer cut out.
+  Every decode step of such a model also leaves, on the device, what
+  the routers of ONE slot (``ROUTING_SLOT``) saw and chose, a row an
+  expert layer; ``on_routing(req, position, saw, chose)``, where set,
+  fetches them at harvest and is called for every token that slot's
+  request fed to a decode step (``saw`` (expert layers, d_model) in
+  the model's type, ``chose`` (expert layers, k) int32): what a check
+  of the served path's routing reads. Unset, nothing is fetched.
 - **Chunked device-side stepping**: like `DecodeEngine`, ``chunk``
   tokens per dispatch with per-slot eos/budget early-stop; pages for
   the whole chunk are reserved up front so the table is static inside
@@ -114,6 +133,10 @@ from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
 
 __all__ = ["PagedDecodeEngine"]
 
+# the slot whose routers' inputs and choices every decode step of a
+# model with routed experts brings back (`on_routing`)
+ROUTING_SLOT = 0
+
 
 class _HandoffRequest(Request):
     """A request whose KV state was built on another replica: carries
@@ -141,6 +164,8 @@ class PagedDecodeEngine(ResilientScheduler):
     and its attend walks only the pages a slot holds (PR 30), and an
     admission is one program (PR 34); what bounds it now is the
     weights' read (PERF.md section 5)."""
+
+    on_routing = None       # the module's docstring, "A stack of ..."
 
     def __init__(self, model, n_pages: int, max_slots: int = 8,
                  page_size: int = 128, steps_per_call: int = 1,
@@ -180,20 +205,49 @@ class PagedDecodeEngine(ResilientScheduler):
                     f"above it (bucket {b})")
         self._head, self._stacked = head, stacked
         L = cfg.n_layers
-        # layer-folded pools: page p of layer l lives at row l*P + p.
-        # ONE extra row at the very end is the scratch page: idle slots'
-        # step writes land there instead of corrupting pool page 0
-        # (their padded tables point at page id 0).
-        shape = (L * self.P + 1, cfg.kv_heads, self.page, cfg.head_dim)
-        self.kp = jnp.zeros(shape, cfg.dtype)
-        self.vp = jnp.zeros(shape, cfg.dtype)
-        self._scratch = L * self.P
-        # per-sequence state pools of the kind (none for softmax
-        # attention), every layer and slot in one array each; the
-        # chunked prefill and the decode step update them in place
-        self.state = {name: jnp.zeros(sds.shape, sds.dtype) for name, sds
-                      in self.kind.slot_state(cfg, self.S).items()}
         self.prefill_chunk = int(prefill_chunk)
+        if self.kind.pages and self.kind.chunked_prefill:
+            if self.prefill_chunk % self.page:
+                raise ValueError(
+                    f"prefill_chunk {self.prefill_chunk} must be whole "
+                    f"pages of {self.page}: a chunk fills its pages")
+        elif cfg.leading_dense:
+            raise NotImplementedError(
+                "a stack with leading dense layers is served through "
+                "the chunked prefill only")
+        # layer-folded pools, as the kind shapes them: page p of layer l
+        # lives at row l*P + p. ONE extra row at the very end is the
+        # scratch page: idle slots' step writes land there instead of
+        # corrupting pool page 0 (their padded tables point at page id
+        # 0). ``kp``/``vp`` are the pools of keys and values that the
+        # one-pass prefill, the prefix cache, the speculative verify and
+        # the hand-off address by name (a one-row placeholder for a kind
+        # without them); any other page pool is the kind's own and lives
+        # in ``state``.
+        pools = {name: jnp.zeros(sds.shape, sds.dtype) for name, sds in
+                 self.kind.page_pools(cfg, L * self.P + 1,
+                                      self.page).items()}
+        no_kv = lambda: jnp.zeros(
+            (1, cfg.kv_heads, self.page, cfg.head_dim), cfg.dtype)
+        self.kp = pools.pop("kp") if "kp" in pools else no_kv()
+        self.vp = pools.pop("vp") if "vp" in pools else no_kv()
+        self._own_page_pools = tuple(pools)
+        self._scratch = L * self.P
+        # the kind's own pools, which its chunked prefill and its step
+        # update in place: per-sequence state (none for attention),
+        # every layer and slot in one array each, and page pools other
+        # than kp/vp
+        slot_pools = {name: jnp.zeros(sds.shape, sds.dtype) for name, sds
+                      in self.kind.slot_state(cfg, self.S).items()}
+        self._slot_pools = tuple(slot_pools)
+        self.state = dict(slot_pools, **pools)
+        # a model with routed experts counts the experts its tokens
+        # touched: a prompt chunk's count waits here, on the device,
+        # for the next decode step's result to carry it back
+        self._experts = cfg.routed_experts > 0
+        self._touched = [0, 0]      # harvested this step: decode, chunks
+        if self._experts:
+            self.state["experts_touched"] = jnp.zeros((), jnp.int32)
         # slots mid-prompt, oldest admission first: slot -> [prompt,
         # tokens prefilled so far]
         self._prefilling: dict = {}
@@ -214,8 +268,10 @@ class PagedDecodeEngine(ResilientScheduler):
         prefix_on = (os.environ.get("PT_PAGED_PREFIX", "1") != "0"
                      if prefix is None else bool(prefix))
         # (a prefix of per-sequence state is not shared: ROADMAP M4)
+        # (nor are pages that a chunked prefill fills: ROADMAP M3)
         self._prefix = (PrefixCache(self._alloc, self.page)
-                        if prefix_on and self.kind.pages else None)
+                        if prefix_on and self.kind.pages
+                        and not self.kind.chunked_prefill else None)
         # disaggregated serving (docs/serving.md): a prefill-only
         # engine admits + prefills but never activates decode — the
         # finished pages leave via detach_handoff; fleet is an optional
@@ -291,9 +347,10 @@ class PagedDecodeEngine(ResilientScheduler):
         """Outstanding KV bytes (pages mapped by slots, both pools) —
         the decode-placement load gauge the disaggregated router reads
         from the heartbeat (membership.heartbeat(load=...))."""
-        per_page = (2 * self.cfg.n_layers * self.cfg.kv_heads
-                    * self.page * self.cfg.head_dim
-                    * np.dtype(self.kp.dtype).itemsize)
+        pools = ([self.state[n] for n in self._own_page_pools]
+                 or [self.kp, self.vp])
+        per_page = self.cfg.n_layers * sum(
+            a.nbytes // a.shape[0] for a in pools)
         return sum(len(t) for t in self._tables) * per_page
 
     @property
@@ -301,12 +358,13 @@ class PagedDecodeEngine(ResilientScheduler):
         """Sequences holding per-sequence state (bound slots, where
         the layers keep any)."""
         return (sum(r is not None for r in self._slot_req)
-                if self.state else 0)
+                if self._slot_pools else 0)
 
     @property
     def state_bytes(self) -> int:
         """Bytes of per-sequence state those sequences hold."""
-        per_slot = sum(a.nbytes for a in self.state.values()) // self.S
+        per_slot = sum(self.state[n].nbytes
+                       for n in self._slot_pools) // self.S
         return self.state_slots * per_slot
 
     def _update_pool_gauges(self):
@@ -378,6 +436,10 @@ class PagedDecodeEngine(ResilientScheduler):
         pid_rows = np.asarray(pids, np.int32)[None, :]
         ids = (np.arange(self.cfg.n_layers)[:, None] * self.P
                + pid_rows).ravel()
+        if self._own_page_pools:
+            for name in self._own_page_pools:
+                self.state[name] = self.state[name].at[ids].set(0)
+            return
         self.kp = self.kp.at[ids].set(0)
         self.vp = self.vp.at[ids].set(0)
 
@@ -391,6 +453,18 @@ class PagedDecodeEngine(ResilientScheduler):
         for s, t in enumerate(self._tables):
             out[s, :len(t)] = t
         return jnp.asarray(out)
+
+    def _table_row(self, slot: int):
+        """Slot ``slot``'s row of the page table, unfolded, at the
+        table's fixed width (numpy: the chunk's dispatch uploads it);
+        None where no layer keeps pages."""
+        if not self.kind.pages:
+            return None
+        mx = (self.cfg.max_seq_len + self.page - 1) // self.page
+        row = np.zeros((mx,), np.int32)
+        tab = self._tables[slot]
+        row[:len(tab)] = tab
+        return row
 
     def _table(self) -> jnp.ndarray:
         """The device page table, re-uploaded only when a reservation or
@@ -410,6 +484,41 @@ class PagedDecodeEngine(ResilientScheduler):
         w = (head["wte"].T if head["lm_head"] is None
              else head["lm_head"])
         return x @ w
+
+    def _over_layers(self, body, carry, head, stacked):
+        """``body(carry, (block, layer index)) -> (carry, None)`` over
+        the whole stack: the leading dense layers one by one
+        (``head["lead"]``: none for most models), then ONE scanned body
+        over the stacked rest."""
+        lead = head["lead"]
+        for i, blk in enumerate(lead):
+            carry, _ = body(carry, (blk, jnp.int32(i)))
+        carry, _ = lax.scan(
+            body, carry,
+            (stacked, jnp.arange(len(lead), self.cfg.n_layers)))
+        return carry
+
+    def _tail(self, head, blk, i, h, attn, pools):
+        """Layer ``i``'s second half on the carry ``(h, pools)``; a
+        model with routed experts hands the layer the experts' stacks
+        with its place in them, and adds the experts its tokens touched
+        to the count in ``pools``; where ``pools`` holds ``routing``
+        (the decode step's), the layer's row of it is filled."""
+        if not self._experts:
+            return blk._block_tail(h, attn), pools
+        at = i - self.cfg.leading_dense
+        h, touched, routed = blk._block_tail_touched(
+            h, attn, head["experts"] + (at,))
+        pools = dict(pools, experts_touched=pools["experts_touched"]
+                     + touched)
+        if routed is not None and "routing" in pools:
+            # the decode step keeps what ROUTING_SLOT's router saw and
+            # chose in this layer (`on_routing`)
+            saw, chose = pools["routing"]
+            pools["routing"] = (
+                saw.at[at].set(routed[0][ROUTING_SLOT, 0]),
+                chose.at[at].set(routed[1][ROUTING_SLOT]))
+        return h, pools
 
     def _one_token(self, head, stacked, kp, vp, state, table, lengths,
                    last, active, poison):
@@ -445,11 +554,10 @@ class PagedDecodeEngine(ResilientScheduler):
             blk, i = blk_i
             attn, pools = self.kind.step(blk, i, h, lengths, active,
                                          pools, view)
-            return (blk._block_tail(h, attn), pools), None
+            return self._tail(head, blk, i, h, attn, pools), None
 
-        (x, pools), _ = lax.scan(
-            layer_body, (x, dict(state, kp=kp, vp=vp)),
-            (stacked, jnp.arange(self.cfg.n_layers)))
+        x, pools = self._over_layers(
+            layer_body, (x, dict(state, kp=kp, vp=vp)), head, stacked)
         kp, vp = pools.pop("kp"), pools.pop("vp")
         state = pools
         logits = self._lm_head(head, x)[:, 0]
@@ -469,6 +577,15 @@ class PagedDecodeEngine(ResilientScheduler):
         one (3, chunk, S) int32 array — the lagged harvest pays exactly
         one device→host transfer."""
         _note_retrace("paged_multi")
+        touched0 = state.get("experts_touched")
+        if self._experts:
+            # what ROUTING_SLOT's routers see and choose, a row an
+            # expert layer, filled anew by every step
+            cfg = self.cfg
+            layers = cfg.n_layers - cfg.leading_dense
+            state = dict(state, routing=(
+                jnp.zeros((layers, cfg.d_model), cfg.dtype),
+                jnp.zeros((layers, cfg.experts_per_token), jnp.int32)))
 
         def one(carry, _):
             kp, vp, state, lengths, last, active, remaining = carry
@@ -480,14 +597,25 @@ class PagedDecodeEngine(ResilientScheduler):
             hit_eos = (nxt == eos) & (eos >= 0)
             active = active & ~bad & ~hit_eos & (remaining > 0)
             return (kp, vp, state, lengths, nxt, active, remaining), \
-                (nxt, emit, bad)
+                (nxt, emit, bad, state.get("routing"))
 
         (kp, vp, state, lengths, last, active, remaining), \
-            (toks, flags, bads) = lax.scan(
+            (toks, flags, bads, routing) = lax.scan(
                 one, (kp, vp, state, lengths, last, active, remaining),
                 None, length=self.chunk)
-        packed = jnp.stack([toks, flags.astype(jnp.int32),
-                            bads.astype(jnp.int32)])
+        rows = [toks, flags.astype(jnp.int32), bads.astype(jnp.int32)]
+        if self._experts:
+            # every step's rows leave with the state (the dispatch
+            # takes them off it again: they are a result, not a state)
+            state = dict(state, routing=routing)
+            # two more rows ride back with the tokens: the experts this
+            # dispatch's tokens touched, and those of the prompt chunks
+            # since the last dispatch (counted from zero again)
+            rows += [jnp.full_like(toks, state["experts_touched"]
+                                   - touched0),
+                     jnp.full_like(toks, touched0)]
+            state = dict(state, experts_touched=jnp.int32(0))
+        packed = jnp.stack(rows)
         return kp, vp, state, lengths, last, active, remaining, packed
 
     def _verify_paged(self, head, stacked, kp, vp, table, lengths,
@@ -831,16 +959,20 @@ class PagedDecodeEngine(ResilientScheduler):
 
     def _prefill_chunk_impl(self, head, stacked, state, lengths, last,
                             active, remaining, eos_ids, tokens, pos0,
-                            n_valid, slot, final, rem0, eos0):
+                            n_valid, slot, final, rem0, eos0, table_row):
         """One chunk (1, prefill_chunk) of slot ``slot``'s prompt
-        through layers that keep per-sequence state: positions from
-        ``pos0``, the first ``n_valid`` tokens real. The chunk at
-        position 0 starts from a zero state (that is how admission
-        zeroes the slot), every other from the slot's own. ONE program
-        for every chunk of every prompt: the scalars are traced. The
-        ``final`` chunk also samples the first token and installs the
-        slot's decode state on device (`_install_slot`), so admission
-        runs no eager program of its own."""
+        through layers that prefill in chunks: positions from ``pos0``,
+        the first ``n_valid`` tokens real. Layers that keep a state per
+        sequence start the chunk at position 0 from a zero state (that
+        is how admission zeroes the slot) and every other from the
+        slot's own; layers that keep pages write the chunk into the
+        slot's pages (``table_row``: the slot's row of the page table,
+        None for a kind without pages) and attend to the pages before it
+        and to itself. ONE program for every chunk of every prompt: the
+        scalars are traced. The ``final`` chunk also samples the first
+        token and installs the slot's decode state on device
+        (`_install_slot`), so admission runs no eager program of its
+        own."""
         _note_retrace("paged_prefill_chunk")
         x = jnp.take(head["wte"], tokens, axis=0)
         if head["wpe"] is not None:
@@ -848,24 +980,35 @@ class PagedDecodeEngine(ResilientScheduler):
                            head["wpe"].shape[0] - 1)
             x = x + jnp.take(head["wpe"], pos, axis=0)[None]
 
+        view = (None if table_row is None else
+                {"table_row": table_row, "n_pages": self.P,
+                 "scratch": self._scratch})
+
         def layer_body(carry, blk_i):
             h, pools = carry
             blk, i = blk_i
             attn, pools = self.kind.prefill(blk, i, h, pos0, n_valid,
-                                            slot, pos0 == 0, pools)
-            return (blk._block_tail(h, attn), pools), None
+                                            slot, pos0 == 0, pools, view)
+            return self._tail(head, blk, i, h, attn, pools), None
 
-        (x, state), _ = lax.scan(
-            layer_body, (x, state),
-            (stacked, jnp.arange(self.cfg.n_layers)))
+        x, state = self._over_layers(layer_body, (x, state), head,
+                                     stacked)
         idx = jnp.clip(n_valid - 1, 0, tokens.shape[1] - 1)
         logits = self._lm_head(head, lax.dynamic_slice_in_dim(
             x, idx, 1, axis=1))[:, 0]
         nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(
             jnp.int32)[0]
-        return (state, *self._install_slot(
+        vecs = self._install_slot(
             (lengths, last, active, remaining, eos_ids), slot,
-            pos0 + n_valid, nxt, rem0, eos0, final), nxt)
+            pos0 + n_valid, nxt, rem0, eos0, final)
+        if view is not None:
+            # until its last chunk the slot is not active, and the
+            # decode step walks an inactive slot's pages up to its
+            # length all the same: none, and not what the slot's last
+            # sequence left
+            vecs = (vecs[0].at[slot].set(
+                jnp.where(final, pos0 + n_valid, 0)),) + vecs[1:]
+        return (state, *vecs, nxt)
 
     # -- scheduler ----------------------------------------------------------
 
@@ -1165,6 +1308,10 @@ class PagedDecodeEngine(ResilientScheduler):
         prompt = np.asarray(req.prompt, np.int32)
         n = len(prompt)
         if self.kind.chunked_prefill:
+            if self.kind.pages:
+                # the prompt's pages, before the span opens (a
+                # MemoryError-retried admission leaves no phantom span)
+                self._reserve(slot, n)
             with trace.span("serve/admit", slot=slot, prompt=n,
                             bucket=self.prefill_chunk, cached=0,
                             rid=req.rid, programs=0):
@@ -1188,10 +1335,11 @@ class PagedDecodeEngine(ResilientScheduler):
 
     def _admit_chunked(self, req, slot, prompt):
         """Bind ``req`` to ``slot`` and queue its prompt for the chunked
-        prefill: no page is reserved and nothing is dispatched here.
-        The slot holds state from now on; its first chunk starts from
-        zero, and until its last chunk it is not active on the device,
-        so no decode step touches it."""
+        prefill: nothing is dispatched here (a kind that keeps pages
+        had the prompt's reserved by `_admit`). The slot holds state
+        from now on; its first chunk starts from zero, and until its
+        last chunk it is not active on the device, so no decode step
+        touches it."""
         from paddle_tpu.observability import flight, trace
         trace.complete("serve/queue", req.t_submit, rid=req.rid,
                        slot=slot)
@@ -1233,7 +1381,7 @@ class PagedDecodeEngine(ResilientScheduler):
                 self.last, self.active, self.remaining, self.eos_ids,
                 jnp.asarray(tokens), jnp.int32(done), jnp.int32(take),
                 jnp.int32(slot), jnp.bool_(final), jnp.int32(rem0),
-                jnp.int32(eos0))
+                jnp.int32(eos0), self._table_row(slot))
         self._step_prefill_tokens = take
         if not final:
             self._prefilling[slot][1] = done + take
@@ -1626,6 +1774,7 @@ class PagedDecodeEngine(ResilientScheduler):
         from paddle_tpu.observability import trace
         t0 = time.perf_counter()
         base = self.tokens_emitted
+        self._touched = [0, 0]
         with trace.span("serve/step") as sp:
             n_live = self._step_inner(sp)
             n = self.tokens_emitted - base
@@ -1653,6 +1802,20 @@ class PagedDecodeEngine(ResilientScheduler):
                 sp.attrs["prefilling"] = len(self._prefilling)
                 sp.attrs["prefill_tokens"] = self._step_prefill_tokens
                 sp.attrs["decode_tokens"] = self._step_decode_tokens
+                if self._own_page_pools:
+                    # cached tokens whose rows the step's attend reads
+                    sp.attrs["latent_rows"] = sum(live)
+                if self._experts:
+                    # token-expert pairs this step dispatched, and the
+                    # distinct experts (summed over the expert layers)
+                    # that the dispatches harvested in it had touched:
+                    # decode steps', and the prompt chunks' before them
+                    k = self.cfg.experts_per_token
+                    sp.attrs["expert_tokens"] = k * (
+                        self._step_prefill_tokens
+                        + self._step_decode_tokens)
+                    sp.attrs["experts_touched"] = self._touched[0]
+                    sp.attrs["experts_touched_prefill"] = self._touched[1]
         if n_live or n:
             # idle polls record nothing (matching DecodeEngine): zero
             # occupancy/queue samples from an empty engine would read
@@ -1779,6 +1942,7 @@ class PagedDecodeEngine(ResilientScheduler):
         for s, _ in live:
             self._proj_len[s] += self._disp_span
         self._finish_dispatch(kind, live, packed)
+        self._pending[-1].routing = self.state.pop("routing", None)
         return True
 
     def _resync_budgets(self, live, cover=None):
@@ -1791,6 +1955,29 @@ class PagedDecodeEngine(ResilientScheduler):
             self._proj_len[slot] = (self._host_len[slot]
                                     + self._disp_span
                                     * cover.get(slot, 0))
+
+    def _replay(self, rec, arr) -> int:
+        if self._experts and rec.kind == "decode":
+            # the expert counts that rode back with the tokens
+            self._touched[0] += int(arr[3, 0, 0])
+            self._touched[1] += int(arr[4, 0, 0])
+            if self.on_routing is not None:
+                self._report_routing(rec, arr)
+        return super()._replay(rec, arr)
+
+    def _report_routing(self, rec, arr):
+        """`on_routing` for every token that ROUTING_SLOT's request fed
+        to this dispatch (before the replay moves its length on)."""
+        slot = ROUTING_SLOT
+        req = next((r for s, r in rec.live if s == slot), None)
+        if req is None or req.done or self._slot_req[slot] is not req:
+            return
+        saw, chose = jax.device_get(rec.routing)
+        at = int(self._host_len[slot])
+        for j in range(self.chunk):
+            if arr[1, j, slot]:
+                self.on_routing(req, at, saw[j], chose[j])
+                at += 1
 
     def _apply_token(self, slot, req, token):
         """Harvested token (shared base replay): emit — which retires
@@ -1821,13 +2008,15 @@ class PagedDecodeEngine(ResilientScheduler):
                 self._head, self._stacked, state, *vecs,
                 jnp.zeros((1, self.prefill_chunk), jnp.int32),
                 jnp.int32(0), jnp.int32(1), jnp.int32(0),
-                jnp.bool_(False), jnp.int32(0), jnp.int32(-1))
+                jnp.bool_(False), jnp.int32(0), jnp.int32(-1),
+                self._table_row(0))
             vecs = tuple(vecs)
         # the arguments in the admission's own form (numpy, uploaded by
         # the dispatch), so that the first request finds the very
         # program: slot 0, a budget of one (never active), no eos
         slot_args = (np.int32(0), np.int32(0), np.int32(-1))
-        for b in self.buckets if self.kind.pages else ():
+        for b in (self.buckets if self.kind.pages
+                  and not self.kind.chunked_prefill else ()):
             segs = np.zeros((b // self.page + 1, self.cfg.n_layers, 3),
                             np.int32)
             kp, vp, vecs, toks, _ = self._prefill_fn(

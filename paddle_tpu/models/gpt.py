@@ -80,18 +80,72 @@ class GPTConfig:
     # per-head RMSNorm of q and k before the rotary positions
     qk_norm: bool = False
     # What mixes the tokens of a layer (`models/layer_kinds.py`):
-    # "softmax" attention over keys and values kept per token, or
-    # "retention" (power retention: a state kept per sequence)
+    # "softmax" attention over keys and values kept per token,
+    # "retention" (power retention: a state kept per sequence), or
+    # "latent" (multi-head latent attention: ONE compressed row kept per
+    # token, shared by the heads)
     mixer: str = "softmax"
+    # Latent attention (DeepSeek-V2/V3's MLA; all five set with
+    # mixer="latent"): the ranks of the query's and of the keys' and
+    # values' compressions, a head's key split into a part without
+    # positions and a rotary part shared by the heads, and a head's
+    # value size. ``rope_interleave`` pairs (2i, 2i+1) under the rotary
+    # positions instead of (i, i + half).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
+    # Dropless sparse experts (`models/expert_layer.py`): after
+    # ``leading_dense`` layers whose feed-forward is the dense one (of
+    # ``ffn_width``), every layer has ``routed_experts`` experts of
+    # ``expert_width``, ``experts_per_token`` of them a token, and
+    # ``shared_experts`` that every token goes through; the chosen
+    # scores (a sigmoid of each logit) are normalised and scaled by
+    # ``routed_scaling``.
+    # 0 routed experts = none. (``moe_experts`` above is the GShard
+    # capacity form, for training.)
+    routed_experts: int = 0
+    experts_per_token: int = 0
+    expert_width: int = 0
+    shared_experts: int = 0
+    routed_scaling: float = 1.0
+    leading_dense: int = 0
 
     def __post_init__(self):
         for name, value, allowed in (
                 ("norm", self.norm, ("layernorm", "rmsnorm")),
                 ("ffn", self.ffn, ("gelu", "swiglu")),
-                ("mixer", self.mixer, ("softmax", "retention"))):
+                ("mixer", self.mixer, ("softmax", "retention", "latent"))):
             if value not in allowed:
                 raise ValueError(f"{name} must be one of {allowed}, "
                                  f"got {value!r}")
+        if self.mixer == "latent":
+            if not (self.q_lora_rank and self.kv_lora_rank
+                    and self.qk_nope_head_dim and self.qk_rope_head_dim
+                    and self.v_head_dim):
+                raise ValueError("mixer='latent' needs q_lora_rank, "
+                                 "kv_lora_rank, qk_nope_head_dim, "
+                                 "qk_rope_head_dim and v_head_dim")
+            if not (self.rms_norm and self.rope and not self.use_bias
+                    and self.ffn == "swiglu"):
+                raise ValueError("latent attention is built with RMSNorm, "
+                                 "rotary positions, a gated feed-forward "
+                                 "and no bias")
+        if self.routed_experts:
+            if not 0 < self.experts_per_token <= self.routed_experts:
+                raise ValueError("experts_per_token must lie in "
+                                 "1..routed_experts")
+            if self.ffn != "swiglu" or self.moe_experts:
+                raise ValueError("dropless experts are SiLU-gated "
+                                 "(ffn='swiglu') and exclude moe_experts")
+            if not 0 <= self.leading_dense < self.n_layers:
+                raise ValueError("leading_dense must leave an expert "
+                                 "layer")
+        elif self.leading_dense:
+            raise ValueError("leading_dense is for stacks with "
+                             "routed_experts")
 
     @property
     def rms_norm(self) -> bool:
@@ -119,8 +173,33 @@ class GPTConfig:
         attn = 12 * self.n_layers * self.d_model * self.max_seq_len
         return 6 * n + attn
 
+    @property
+    def latent_row(self) -> int:
+        """What a latent layer caches a token: the compressed row and
+        the rotary part of the key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def _latent_num_params(self, non_embedding: bool) -> int:
+        d, L, H = self.d_model, self.n_layers, self.n_heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        attn = (d * self.q_lora_rank + self.q_lora_rank * (H * qk + 1)
+                + d * self.latent_row + self.kv_lora_rank
+                * (H * (self.qk_nope_head_dim + self.v_head_dim) + 1)
+                + H * self.v_head_dim * d + 2 * d)
+        dense = 3 * d * self.d_ffn
+        sparse = (3 * d * self.expert_width
+                  * (self.routed_experts + self.shared_experts)
+                  + (d + 1) * self.routed_experts)
+        lead = self.leading_dense if self.routed_experts else L
+        n = L * attn + lead * dense + (L - lead) * sparse + d
+        if not non_embedding:
+            n += self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        return n
+
     def num_params(self, non_embedding: bool = False) -> int:
         d, L = self.d_model, self.n_layers
+        if self.mixer == "latent":
+            return self._latent_num_params(non_embedding)
         kv_dim = self.kv_heads * self.head_dim
         per_layer = (d * (d + 2 * kv_dim)   # wqkv (GQA-sized kv)
                      + d * d                # wo
@@ -189,7 +268,8 @@ class GPTBlock(Module):
     """Pre-LN transformer decoder block with fused qkv (one (d,3d) matmul
     keeps the MXU busy vs three thin ones)."""
 
-    def __init__(self, cfg: GPTConfig, key: jax.Array, use_moe=False):
+    def __init__(self, cfg: GPTConfig, key: jax.Array, use_moe=False,
+                 use_experts=False):
         super().__init__()
         d, h = cfg.d_model, cfg.n_heads
         self.n_heads = h
@@ -228,12 +308,30 @@ class GPTBlock(Module):
         self.wg = (Parameter(_normal(jax.random.fold_in(key, 5),
                                      (d, self.kv_heads), std, dt))
                    if cfg.mixer == "retention" else None)
+        dense_ffn = not (use_moe or use_experts)
         self.wgate = (Parameter(_normal(jax.random.fold_in(key, 6),
                                         (d, cfg.d_ffn), std, dt))
-                      if cfg.ffn == "swiglu" and not use_moe else None)
-        self.wqkv = Parameter(_normal(ks[0], (d, d + 2 * kv_dim), std, dt))
-        self.wo = Parameter(_normal(ks[1], (d, d), resid_std, dt))
-        if use_moe:
+                      if cfg.ffn == "swiglu" and dense_ffn else None)
+        if cfg.mixer == "latent":
+            self._init_latent(cfg, jax.random.fold_in(key, 7), std,
+                              resid_std)
+        else:
+            self.wqkv = Parameter(_normal(ks[0], (d, d + 2 * kv_dim), std,
+                                          dt))
+            self.wo = Parameter(_normal(ks[1], (d, d), resid_std, dt))
+        # dropless sparse experts with a shared expert in the dense
+        # feed-forward's place (`models/expert_layer.py`)
+        self.experts = None
+        if use_experts:
+            from paddle_tpu.models.expert_layer import ExpertLayer
+            self.experts = ExpertLayer(
+                d, cfg.expert_width, cfg.routed_experts,
+                cfg.experts_per_token, ks[2],
+                n_shared=cfg.shared_experts, scale=cfg.routed_scaling,
+                dtype=dt, std=std,
+                down_std=resid_std)
+            self.moe = self.wup = self.wdown = None
+        elif use_moe:
             from paddle_tpu.incubate.moe import MoELayer
             self.moe = MoELayer(d, cfg.d_ffn, cfg.moe_experts,
                                 gate=cfg.moe_gate, dtype=dt,
@@ -248,13 +346,90 @@ class GPTBlock(Module):
         if cfg.use_bias:
             self.bqkv = Parameter(jnp.zeros((d + 2 * kv_dim,), dt))
             self.bo = Parameter(jnp.zeros((d,), dt))
-            if not use_moe:
+            if dense_ffn:
                 self.bup = Parameter(jnp.zeros((cfg.d_ffn,), dt))
                 self.bdown = Parameter(jnp.zeros((d,), dt))
             else:
                 self.bup = self.bdown = None
         else:
             self.bqkv = self.bo = self.bup = self.bdown = None
+
+    def _init_latent(self, cfg, key, std, resid_std):
+        """Latent attention's matrices in place of the fused ``wqkv``:
+        the query through a rank ``q_lora_rank`` (norm between), keys
+        and values through ONE row of ``kv_lora_rank`` a token (normed)
+        beside the rotary part of the key; ``wkv_b`` takes the row to a
+        head's key without positions and its value."""
+        d, h, dt = cfg.d_model, cfg.n_heads, cfg.dtype
+        self.nope, self.rope_dim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        self.v_dim, self.kv_rank = cfg.v_head_dim, cfg.kv_lora_rank
+        self.rope_interleave = cfg.rope_interleave
+        ks = jax.random.split(key, 5)
+        self.wqkv = None
+        self.wq_a = Parameter(_normal(ks[0], (d, cfg.q_lora_rank), std, dt))
+        self.q_a_norm = Parameter(jnp.ones((cfg.q_lora_rank,), jnp.float32))
+        self.wq_b = Parameter(_normal(
+            ks[1], (cfg.q_lora_rank, h * (self.nope + self.rope_dim)), std,
+            dt))
+        self.wkv_a = Parameter(_normal(ks[2], (d, cfg.latent_row), std, dt))
+        self.kv_a_norm = Parameter(jnp.ones((self.kv_rank,), jnp.float32))
+        self.wkv_b = Parameter(_normal(
+            ks[3], (self.kv_rank, h * (self.nope + self.v_dim)), std, dt))
+        self.wo = Parameter(_normal(ks[4], (h * self.v_dim, d), resid_std,
+                                    dt))
+
+    def _rope_pairs(self, x, positions):
+        """Rotary positions on (B, K, ..., D) at ``positions`` (B, K):
+        `_apply_rope`'s rotation over the last axis, whatever its size,
+        with the pairing the configuration names ((2i, 2i+1) where
+        ``rope_interleave``, else (i, i + D/2))."""
+        half = x.shape[-1] // 2
+        freqs = self.rope_theta ** (
+            -jnp.arange(0, half, dtype=jnp.float32) / half)
+        ang = positions.astype(jnp.float32)[..., None] * freqs
+        ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (half,))
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        x32 = x.astype(jnp.float32)
+        if self.rope_interleave:
+            x1, x2 = x32[..., 0::2], x32[..., 1::2]
+            out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                            axis=-1).reshape(x.shape)
+        else:
+            x1, x2 = x32[..., :half], x32[..., half:]
+            out = jnp.concatenate(
+                [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.astype(x.dtype)
+
+    def _latent_inputs(self, x, positions):
+        """Norm 1 and latent attention's projections of x (B, K, d) at
+        positions ``positions[b] + k``: a head's query without positions
+        (B, K, H, nope) and its rotary part (B, K, H, rope), and what the
+        cache keeps of a token, ``row = [c_kv | k_rope]``
+        (B, K, rank + rope): the normed compressed row and the key's
+        rotary part, rotated, ONE for all heads."""
+        b, K = x.shape[:2]
+        h = self._ln(x, self.ln1_scale, self.ln1_bias)
+        cq = final_ln(h @ self.wq_a, self.q_a_norm, None, self.norm_eps,
+                      True)
+        q = (cq @ self.wq_b).reshape(b, K, self.n_heads,
+                                     self.nope + self.rope_dim)
+        kv = h @ self.wkv_a
+        c_kv = final_ln(kv[..., :self.kv_rank], self.kv_a_norm, None,
+                        self.norm_eps, True)
+        pos2 = positions[:, None] + jnp.arange(K)[None, :]
+        q_rope = self._rope_pairs(q[..., self.nope:], pos2)
+        k_rope = self._rope_pairs(kv[..., self.kv_rank:], pos2)
+        return (q[..., :self.nope], q_rope,
+                jnp.concatenate([c_kv, k_rope], axis=-1))
+
+    @property
+    def latent_scale(self) -> float:
+        return 1.0 / math.sqrt(self.nope + self.rope_dim)
+
+    def _wkv_b_heads(self):
+        """``wkv_b`` as (rank, H, nope + v)."""
+        return self.wkv_b.reshape(self.kv_rank, self.n_heads,
+                                  self.nope + self.v_dim)
 
     def _split_qkv(self, qkv):
         """(B, L, d+2·kv_dim) fused projection → q (B, L, H, D),
@@ -333,16 +508,28 @@ class GPTBlock(Module):
     def _block_tail(self, x, attn):
         """Post-attention half of the block (out-proj + MLP), shared by
         every cached-decode variant — ONE definition."""
+        return self._block_tail_touched(x, attn)[0]
+
+    def _block_tail_touched(self, x, attn, expert_stacks=None):
+        """`_block_tail`; how many of the layer's routed experts some
+        token of ``x`` chose (0 for a layer without them); and, for a
+        layer with them, what its router saw and chose: ``(the normed
+        tokens, x's shape; experts (tokens, k))``, else None.
+        ``expert_stacks``: as `ExpertLayer.forward` takes them."""
         o = attn @ self.wo
         if self.bo is not None:
             o = o + self.bo
         x = x + o
-        h = self._ln(x, self.ln2_scale, self.ln2_bias)
-        if self.moe is not None:
-            h, _ = self.moe(h, None)
+        n = self._ln(x, self.ln2_scale, self.ln2_bias)
+        touched, routed = jnp.int32(0), None
+        if self.experts is not None:
+            h, touched, experts = self.experts(n, expert_stacks)
+            routed = (n, experts)
+        elif self.moe is not None:
+            h, _ = self.moe(n, None)
         else:
-            h = self._ffn(h)
-        return x + h
+            h = self._ffn(n)
+        return x + h, touched, routed
 
     def _mix_inputs(self, x, positions):
         """Norm 1 + fused QKV (+ per-head q/k norm, + rope at
@@ -521,7 +708,24 @@ class GPTBlock(Module):
             return self.decode_step(x, kv, positions)
         return self.verify_step(x, kv, positions)
 
+    def _latent_forward(self, x):
+        """Whole sequences (B, S, d) through latent attention in the
+        expanded form (every key and value made from its row), causal."""
+        from paddle_tpu.ops.pallas.latent_attention import (
+            expanded_attention)
+        b, s, _ = x.shape
+        q_nope, q_rope, row = self._latent_inputs(
+            x, jnp.zeros((b,), jnp.int32))
+        with jax.named_scope("mla_prefill"):
+            attn = jax.vmap(lambda qn, qr, r: expanded_attention(
+                qn, qr, r[:, :self.kv_rank], r[:, self.kv_rank:],
+                self._wkv_b_heads(), self.latent_scale, 0))(
+                    q_nope, q_rope, row)
+        return self._block_tail(x, attn.astype(x.dtype).reshape(b, s, -1))
+
     def forward(self, x, rng_key=None, aux_acc=None):
+        if self.mixer == "latent":
+            return self._latent_forward(x)
         b, s, d = x.shape
         q, k, v, g = self._mix_inputs(x, jnp.zeros((b,), jnp.int32))
         q = _shard_act(q, LAYOUT.activation("sp", "tp", None))
@@ -538,7 +742,9 @@ class GPTBlock(Module):
             o = o + self.bo
         x = x + _maybe_dropout(o, self.dropout, rng_key, 1)
         h = self._ln(x, self.ln2_scale, self.ln2_bias)
-        if self.moe is not None:
+        if self.experts is not None:
+            h = self.experts(h)[0]
+        elif self.moe is not None:
             h, aux = self.moe(h, rng_key)
             if aux_acc is not None:
                 aux_acc.append(aux)
@@ -636,7 +842,9 @@ class GPT(Module):
         self.blocks = LayerList([
             GPTBlock(cfg, jax.random.fold_in(kb, i),
                      use_moe=(cfg.moe_experts > 0
-                              and (i + 1) % cfg.moe_every == 0))
+                              and (i + 1) % cfg.moe_every == 0),
+                     use_experts=(cfg.routed_experts > 0
+                                  and i >= cfg.leading_dense))
             for i in range(cfg.n_layers)])
         self.lnf_scale = Parameter(jnp.ones((cfg.d_model,), jnp.float32))
         self.lnf_bias = (None if cfg.rms_norm else Parameter(
@@ -659,8 +867,12 @@ class GPT(Module):
         new = Module.merge_params(self, params)
         st = getattr(new, "_stacked_blocks", None)
         if st is not None:
-            for i in range(new.cfg.n_layers):
-                blk = jax.tree_util.tree_map(lambda x, i=i: x[i], st)
+            # (the stack holds the layers after the leading dense ones,
+            # which stay blocks of their own)
+            lead = new.cfg.leading_dense
+            for i in range(lead, new.cfg.n_layers):
+                blk = jax.tree_util.tree_map(lambda x, i=i: x[i - lead],
+                                             st)
                 object.__setattr__(new.blocks, f"item_{i}", blk)
         return new
 
@@ -702,8 +914,10 @@ class GPT(Module):
         ``scan_layers=False`` escape hatch keep the unrolled loop."""
         x = self.embed(tokens)
         L = self.cfg.n_layers
-        dense = all(self.blocks[i].moe is None for i in range(L))
-        prestacked = getattr(self, "_stacked_blocks", None)
+        dense = all(self.blocks[i].moe is None
+                    and self.blocks[i].experts is None for i in range(L))
+        prestacked = (getattr(self, "_stacked_blocks", None)
+                      if dense else None)
         use_scan = prestacked is not None or (dense and L > 1
                                               and _flag("scan_layers"))
         if use_scan:

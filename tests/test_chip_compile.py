@@ -9,7 +9,11 @@ paged engine's ``paged_append_attend`` (the decode step) and
 ``paged_decode_attention`` (suffix prefill), plus ``decode_attention``
 and ``int8_matmul`` (the contiguous engine) and, at Brumby-14B's widths,
 ``retention_step`` (the decode step of retention layers) with the
-dataflow that keeps its 4.4 GB state pool in place.
+dataflow that keeps its 4.4 GB state pool in place, and at
+JoyAI-LLM-Flash's ``latent_append_attend`` / ``latent_chunk_attend`` (the
+latent pages' decode step and prompt chunk) and ``moe_experts`` (the
+grouped product over the experts' stacks, which must stay where they
+are).
 
 The topology is described inside a module-scoped fixture of THIS file —
 only the xdist worker that is handed the file loads libtpu — and the
@@ -307,4 +311,99 @@ def test_retention_decode_dataflow_copies_no_pool(one_chip):
     copies = [ln.strip() for ln in text.splitlines()
               if re.search(r"= \S+ copy\(", ln)
               and any(f"= {sh}" in ln for sh in pools)]
+    assert not copies, copies
+
+
+# ------ latent attention over sparse experts at JoyAI-LLM-Flash's widths
+# (32 heads over rows of 512 + 64, 256 experts of 2048 x 768), the
+# benchmark's 32 slots x 72 pages over 5 layers and a 16k context
+L_LAYERS, L_SLOTS, L_HEADS, L_RANK, L_ROPE = 5, 32, 32, 512, 64
+L_PAGES, L_COLUMNS = 32 * 72, 128
+E_EXPERTS, E_WIDTH, E_LAYERS = 256, 768, 4
+
+
+def _latent_shapes():
+    w = L_RANK + L_ROPE
+    return (_sds((L_SLOTS, L_HEADS, w)),
+            _sds((L_LAYERS * L_PAGES + 1, w, PAGE)), _sds((L_SLOTS, w)),
+            _sds((L_SLOTS, L_COLUMNS), jnp.int32),
+            _sds((L_SLOTS,), jnp.int32), _sds((L_SLOTS,), jnp.int32))
+
+
+def test_latent_append_attend_copies_no_pool(one_chip):
+    """The absorbed decode step's two launches inside a scan over layers
+    with the donated pool of latent rows as carry: both kernels are
+    there, and no ``copy`` of the 1.7 GB pool."""
+    from paddle_tpu.ops.pallas.latent_attention import latent_append_attend
+    q, pool, row, table, wpids, lengths = _latent_shapes()
+
+    def step(pool, q, row, table, wpids, lengths):
+        def layer(carry, i):
+            h, pool = carry
+            o, pool = latent_append_attend(
+                q + h, pool, row, i * L_PAGES + table, wpids, lengths,
+                L_RANK, 192 ** -0.5, interpret=False)
+            return (jnp.pad(o, ((0, 0), (0, 0), (0, L_ROPE))), pool), None
+
+        return jax.lax.scan(layer, (jnp.zeros_like(q), pool),
+                            jnp.arange(L_LAYERS))[0]
+
+    text = _compiled_text(step, (pool, q, row, table, wpids, lengths),
+                          one_chip, donate=(0,))
+    assert "latent_attend_write" in text and "latent_attend" in text
+    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(r"= \S+ copy\(", ln) and f"= {shape}" in ln]
+    assert not copies, copies
+
+
+def test_latent_chunk_attend(one_chip):
+    """A 512-token chunk's expanded attention over the pages, and its
+    rows' way into them, at the served widths: it compiles, and keeps
+    the pool in place."""
+    from paddle_tpu.ops.pallas.latent_attention import latent_chunk_attend
+    _, pool, _, _, _, _ = _latent_shapes()
+    shapes = (pool, _sds((512, L_HEADS, 128)), _sds((512, L_HEADS, L_ROPE)),
+              _sds((512, L_RANK + L_ROPE)), _sds((L_RANK, L_HEADS, 256)),
+              _sds((L_COLUMNS,), jnp.int32), _sds((), jnp.int32),
+              _sds((), jnp.int32))
+
+    def chunk(pool, qn, qr, rows, w, table_row, pos0, n_valid):
+        return latent_chunk_attend(qn, qr, rows, pool, w, table_row,
+                                   2 * L_PAGES, L_LAYERS * L_PAGES, pos0,
+                                   n_valid, L_RANK, 192 ** -0.5)
+
+    text = _compiled_text(chunk, shapes, one_chip, donate=(0,))
+    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(r"= \S+ copy\(", ln) and f"= {shape}" in ln]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("tokens,tile", [(32, 16), (512, 32)])
+def test_moe_experts_reads_the_stacks_in_place(one_chip, tokens, tile):
+    """The grouped expert product for a decode step's 32 tokens and a
+    chunk's 512, inside a scan over the four expert layers that hands it
+    the 3 GB stacks whole with the layer's number: one kernel, and no
+    ``copy`` of a stack or of a layer of it."""
+    from paddle_tpu.ops.pallas import moe_experts as me
+    tiles = me.n_tiles(tokens * 8, E_EXPERTS, tile)
+    shapes = (_sds((tiles * tile, DM)),
+              _sds((E_LAYERS, E_EXPERTS, DM, E_WIDTH)),
+              _sds((E_LAYERS, E_EXPERTS, DM, E_WIDTH)),
+              _sds((E_LAYERS, E_EXPERTS, E_WIDTH, DM)),
+              _sds((tiles,), jnp.int32), _sds((), jnp.int32))
+
+    def layers(x, wg, wu, wd, tile_expert, used):
+        def layer(h, i):
+            return me.moe_experts(h, wg, wu, wd, tile_expert, used, tile,
+                                  layer=i, interpret=False), None
+
+        return jax.lax.scan(layer, x, jnp.arange(E_LAYERS))[0]
+
+    text = _compiled_text(layers, shapes, one_chip)
+    assert "moe_experts" in text
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(r"= \S+ copy\(", ln)
+              and re.search(rf"= bf16\[(?:{E_LAYERS},)?{E_EXPERTS},", ln)]
     assert not copies, copies
