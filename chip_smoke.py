@@ -168,9 +168,9 @@ def _train_one_chip(model, seed, steps):
     compiled = step.lower(params, opt_state, tokens, rng).compile()
     say(f"train: compile {time.perf_counter() - t0:.1f} s")
     _require_kernels(compiled.as_text(),
-                     ("flash_attention_fwd", "flash_attention_bwd_dq",
-                      "flash_attention_bwd_dkdv", "fused_ce_fwd",
-                      "fused_ce_bwd_dx", "fused_ce_bwd_dw"), "train step")
+                     ("flash_attention_fwd", "flash_attention_bwd",
+                      "fused_ce_fwd", "fused_ce_bwd_dx",
+                      "fused_ce_bwd_dw"), "train step")
     losses, ms = [], []
     for _ in range(steps):
         t0 = time.perf_counter()

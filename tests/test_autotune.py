@@ -83,7 +83,7 @@ def test_flash_attention_reads_tuned_blocks(cache, monkeypatch):
 
     monkeypatch.setattr(fa, "_flash", spy)
     flash_attention(q, q, q, causal=True)
-    # 256-length seq clamps the default (256, 512) → (256, 256)
+    # a 256-length seq clamps the default blocks to (256, 256)
     assert seen["blocks"] == (min(_DEFAULT_BLOCKS[0], 256),
                               min(_DEFAULT_BLOCKS[1], 256))
 

@@ -81,9 +81,22 @@ def _named(calls, name):
     return sum(name in ln for ln in calls)
 
 
-def test_flash_attention_fwd_bwd(one_chip):
+@pytest.mark.parametrize("shape,kernels", [
+    # the training cell, the parked head-64 shape, an 8k context: float32
+    # dQ for the sequence stays in VMEM and the backward is one kernel
+    ((BATCH, SEQ, HEADS, HEAD_DIM), ("flash_attention_bwd",)),
+    ((8, SEQ, HEADS, 64), ("flash_attention_bwd",)),
+    ((1, 8192, HEADS, HEAD_DIM), ("flash_attention_bwd",)),
+    # 16k: it cannot (a head of 64 is padded to the lanes there), and the
+    # dK/dV and dQ kernels run
+    ((1, 16384, 8, HEAD_DIM),
+     ("flash_attention_bwd_dkdv", "flash_attention_bwd_dq")),
+    ((1, 16384, HEADS, 64),
+     ("flash_attention_bwd_dkdv", "flash_attention_bwd_dq")),
+], ids=["cell", "head64", "8k", "16k-two-kernels", "16k-head64"])
+def test_flash_attention_fwd_bwd(one_chip, shape, kernels):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
-    q = _sds((BATCH, SEQ, HEADS, HEAD_DIM))
+    q = _sds(shape)
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True,
@@ -91,9 +104,10 @@ def test_flash_attention_fwd_bwd(one_chip):
 
     calls = _compile(jax.grad(loss, argnums=(0, 1, 2)), (q, q, q),
                      one_chip)
-    assert _named(calls, "flash_attention_fwd") == 1
-    assert _named(calls, "flash_attention_bwd_dkdv") == 1
-    assert _named(calls, "flash_attention_bwd_dq") == 1
+    # a call's line holds the operation's name and then the kernel's own
+    names = sorted(re.findall(r"flash_attention_\w+", ln)[-1]
+                   for ln in calls)
+    assert names == sorted(("flash_attention_fwd",) + kernels)
 
 
 def test_fused_ce_fwd_bwd(one_chip):

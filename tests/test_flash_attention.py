@@ -6,6 +6,9 @@ numpy/naive oracle checked against the fused kernel for output AND grads).
 Runs in Pallas interpret mode on the CPU test platform.
 """
 
+import re
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -286,3 +289,212 @@ def test_sdpa_fallback_honors_kv_lens():
     out = scaled_dot_product_attention(q, k, v, kv_lens=kv_lens)
     ref = attention_reference(q, k, v, mask=_padding_bias(kv_lens, 64))
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# PR 36: what a grid step does follows its block's position (block_plan):
+# pairs above the diagonal get no step, pairs under it no mask, and the
+# backward is one kernel where float32 dQ for the sequence fits VMEM
+# ---------------------------------------------------------------------------
+
+# the module: the package's attribute of that name is the function
+fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+
+
+@pytest.mark.parametrize("args,unmasked,masked,skipped", [
+    # the training cell: its forward, its backward, and the 8 x 4 pairs
+    # of the blocks before PR 36
+    ((2048, 2048, 1024, 1024, True), 1, 2, 1),
+    ((2048, 2048, 512, 512, True), 6, 4, 6),
+    ((2048, 2048, 256, 512, True), 12, 8, 12),
+    ((2048, 2048, 256, 256, True), 28, 8, 28),
+    ((2048, 2048, 128, 128, True), 120, 16, 120),
+    # all three kinds in one call, both ways round
+    ((1024, 1024, 128, 256, True), 12, 8, 12),
+    ((1024, 1024, 256, 128, True), 12, 8, 12),
+    # no mask at all without causal; only the ragged last key block
+    ((2048, 2048, 256, 512, False), 32, 0, 0),
+    ((64, 200, 128, 128, False), 1, 1, 0),
+    # kv_lens: lengths live on the device, every block is masked
+    ((384, 384, 128, 128, False, True), 0, 9, 0),
+    ((300, 300, 128, 128, True), 3, 3, 3),
+])
+def test_block_plan_counts(args, unmasked, masked, skipped):
+    plan = fa.block_plan(*args)
+    assert (plan.unmasked, plan.masked, plan.skipped) == (
+        unmasked, masked, skipped)
+    assert plan.kinds == tuple(
+        m for m in (False, True) if (masked if m else unmasked))
+
+
+@pytest.mark.parametrize("k_major", [False, True])
+@pytest.mark.parametrize("args", [(1024, 1024, 128, 256, True),
+                                  (1024, 1024, 256, 128, True),
+                                  (300, 300, 128, 128, True),
+                                  (64, 200, 128, 128, False)])
+def test_block_plan_table_is_the_mask(args, k_major):
+    """The table the index maps and bodies read agrees, pair by pair,
+    with the dense mask: a skipped pair has no visible cell, an unmasked
+    pair no hidden one, and FIRST/LAST bracket each accumulation."""
+    sq, sk, bq, bk, causal = args
+    plan = fa.block_plan(*args)
+    i, j, flags = plan.table(k_major)
+    row = np.arange(plan.nq * bq)[:, None]
+    col = np.arange(plan.nk * bk)[None, :]
+    visible = (col < sk) & ((row >= col) if causal else True)
+    seen = set()
+    for ii, jj, f in zip(i, j, flags):
+        tile = visible[ii * bq:(ii + 1) * bq, jj * bk:(jj + 1) * bk]
+        assert tile.any()
+        if not f & fa._MASKED:
+            assert tile.all()
+        seen.add((ii, jj))
+    for ii in range(plan.nq):
+        for jj in range(plan.nk):
+            if (ii, jj) not in seen:
+                assert not visible[ii * bq:(ii + 1) * bq,
+                                   jj * bk:(jj + 1) * bk][:sq].any()
+    run = j if k_major else i
+    first = np.r_[True, run[1:] != run[:-1]]
+    last = np.r_[run[1:] != run[:-1], True]
+    np.testing.assert_array_equal(flags & fa._FIRST != 0, first)
+    np.testing.assert_array_equal(flags & fa._LAST != 0, last)
+    assert len(set(run[first])) == first.sum()   # each run is contiguous
+
+
+def _kernel_names(fn, *args):
+    return set(re.findall(r"flash_attention_\w+", str(jax.make_jaxpr(fn)(
+        *args))))
+
+
+def _check_against_reference(b, s, h_q, h_kv, d, blocks, causal=True,
+                             kv_lens=None, bias=None, seed=30, tol=5e-5):
+    rs = np.random.RandomState(seed)
+    q = jnp.asarray(rs.normal(size=(b, s, h_q, d)), jnp.float32)
+    k = jnp.asarray(rs.normal(size=(b, s, h_kv, d)), jnp.float32)
+    v = jnp.asarray(rs.normal(size=(b, s, h_kv, d)), jnp.float32)
+    cot = jnp.asarray(rs.normal(size=q.shape), jnp.float32)
+    group = h_q // h_kv
+    mask = bias
+    if kv_lens is not None:
+        mask = _padding_bias(kv_lens, s)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=causal, kv_lens=kv_lens, bias=bias,
+            block_q=blocks[0], block_k=blocks[1], interpret=True) * cot)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(attention_reference(
+            q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+            is_causal=causal, mask=mask) * cot)
+
+    out = flash_attention(q, k, v, causal=causal, kv_lens=kv_lens,
+                          bias=bias, block_q=blocks[0], block_k=blocks[1],
+                          interpret=True)
+    ref = attention_reference(
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+        is_causal=causal, mask=mask)
+    np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b_, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(a, b_, atol=2 * tol, rtol=2 * tol,
+                                   err_msg=f"d{name} mismatch")
+    return _kernel_names(jax.grad(loss_flash, argnums=(0, 1, 2)), q, k, v)
+
+
+_BODIES = {
+    # unmasked, diagonal and skipped blocks in one call
+    "causal-1024-128x256": dict(b=1, s=1024, h_q=2, h_kv=2, d=32,
+                                blocks=(128, 256)),
+    "causal-1024-256x128": dict(b=1, s=1024, h_q=2, h_kv=2, d=32,
+                                blocks=(256, 128)),
+    # sq not a multiple of the block
+    "causal-300-ragged": dict(b=2, s=300, h_q=2, h_kv=2, d=32,
+                              blocks=(128, 128)),
+    "causal-300-ragged-128x256": dict(b=1, s=300, h_q=2, h_kv=2, d=32,
+                                      blocks=(128, 256)),
+    "gqa-4to1": dict(b=2, s=384, h_q=4, h_kv=1, d=32, blocks=(128, 128),
+                     tol=1e-4),
+    "kv_lens-noncausal": dict(b=3, s=384, h_q=2, h_kv=2, d=32,
+                              blocks=(128, 128), causal=False,
+                              kv_lens=[384, 200, 17]),
+    "noncausal-ragged-keys": dict(b=1, s=300, h_q=2, h_kv=2, d=32,
+                                  blocks=(128, 128), causal=False),
+    "bias-causal": dict(b=1, s=256, h_q=2, h_kv=2, d=32,
+                        blocks=(128, 128), bias=(1, 2, 256, 256)),
+    "key-bias-causal": dict(b=2, s=256, h_q=2, h_kv=2, d=32,
+                            blocks=(128, 128), bias=(2, 1, 1, 256)),
+}
+
+
+def _body_case(name):
+    case = dict(_BODIES[name])
+    if "kv_lens" in case:
+        case["kv_lens"] = jnp.asarray(case["kv_lens"], jnp.int32)
+    if "bias" in case:
+        case["bias"] = jnp.asarray(np.random.RandomState(31).normal(
+            size=case["bias"]), jnp.float32)
+    return case
+
+
+@pytest.mark.parametrize("name", sorted(_BODIES))
+def test_block_bodies_match_reference(name):
+    """Forward and gradients of the position-split bodies, through the
+    one-kernel backward."""
+    names = _check_against_reference(**_body_case(name))
+    assert names == {"flash_attention_fwd", "flash_attention_bwd"}
+
+
+@pytest.mark.parametrize("name", sorted(_BODIES))
+def test_two_kernel_backward_matches_reference(name, monkeypatch):
+    """Where float32 dQ for the whole sequence cannot stay in VMEM the
+    backward is the dK/dV and dQ kernels, over the same plan."""
+    monkeypatch.setattr(fa, "_BWD_VMEM_BYTES", 0)
+    names = _check_against_reference(**_body_case(name))
+    assert names == {"flash_attention_fwd", "flash_attention_bwd_dkdv",
+                     "flash_attention_bwd_dq"}
+
+
+@pytest.mark.parametrize("sq,d,fits", [(2048, 128, True), (2048, 64, True),
+                                       (8192, 128, True), (8192, 64, True),
+                                       (16384, 128, False),
+                                       # 64 wide is padded to the lanes
+                                       (16384, 64, False)])
+def test_backward_form_follows_the_shapes(sq, d, fits):
+    """Which backward runs is decided by what dQ for the sequence takes
+    in VMEM at the backward's blocks (the forward's cut to 512); the
+    geometry model's budget holds either way."""
+    from paddle_tpu.analysis import kernelmodel as km
+    assert fa._default_blocks(sq, sq, 0) == fa._DEFAULT_BLOCKS
+    assert (fa._one_kernel_bwd_bytes(sq, d, 512, 512, 2)
+            <= fa._BWD_VMEM_BYTES) == fits
+    q = km.sds((1, sq, 2, d), "bfloat16")
+
+    def run():
+        jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=True).astype(jnp.float32)),
+            argnums=(0, 1, 2)), q, q, q)
+    specs = {s.body: s for s in km.harvest(run)}
+    assert km.budget_reason(run) is None
+    assert ("_bwd_kernel" in specs) == fits
+    assert ("_bwd_dq_kernel" in specs) == (not fits)
+    # forward at the default blocks, backward at its own
+    assert specs["_fwd_kernel"].inputs[0].block == (1, 1024, d)
+    bwd = specs["_bwd_kernel" if fits else "_bwd_dkdv_kernel"]
+    assert bwd.inputs[0].block == (1, 512, d)
+
+
+@pytest.mark.parametrize("sq,sk,bias_sq,blocks", [
+    (2048, 2048, 0, (1024, 1024)),
+    (8192, 8192, 0, (1024, 1024)),
+    (2048, 2048, 1, (1024, 1024)),      # a key-only bias is one row
+    (2048, 2048, 2048, (512, 512)),     # a (block_q, block_k) bias block
+    (1500, 1500, 0, (512, 512)),        # 1,536 and not 2,048 rows
+    (2049, 2049, 0, (256, 256)),
+    (300, 300, 0, (1024, 1024)),        # clamped to one block of 384 later
+    (64, 5000, 0, (1024, 1024)),
+])
+def test_default_blocks_follow_the_shapes(sq, sk, bias_sq, blocks):
+    assert fa._default_blocks(sq, sk, bias_sq) == blocks
