@@ -291,13 +291,18 @@ class _Inflight:
     snapshot it covered, the packed on-device result array, and the
     dispatch timestamp. ``kind`` is 'prefill' (payload: the sampled
     first token), 'decode' (packed (3, chunk, S): tokens / emit flags /
-    non-finite flags) or 'spec' (packed (chunk, S, K+2))."""
+    non-finite flags), 'ride' (the paged engine's decode rows that rode
+    in a prompt chunk's program: replayed as 'decode') or 'spec'
+    (packed (chunk, S, K+2))."""
 
-    __slots__ = ("kind", "live", "payload", "t", "routing")
+    __slots__ = ("kind", "live", "payload", "t", "routing", "first")
 
     def __init__(self, kind, live, payload, t):
         self.kind, self.live, self.payload, self.t = kind, live, payload, t
         self.routing = None     # paged engine, a model with routed experts
+        # paged engine, a 'ride' that carried a prompt's last chunk: its
+        # (slot, request), whose first token the payload's last row holds
+        self.first = None
 
 
 class _HandoffRequest(Request):

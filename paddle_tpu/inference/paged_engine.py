@@ -72,9 +72,14 @@ TPU design decisions:
   keeps pages): bound with the slot, zeroed by the first prefill chunk,
   freed with the slot. A kind with ``chunked_prefill`` prefills prompts
   of ANY length up to the context in chunks of ``prefill_chunk`` tokens
-  — one compiled program, the last chunk padded and masked, at most one
-  chunk between two decode steps; a slot still in prefill is not active
-  in the decode step. State kinds carry the state from chunk to chunk;
+  — at most one chunk a step, the last chunk padded and masked; a slot
+  still in prefill is not active in the decode step. **A chunk rides in
+  the decode step**: ONE program (`_ride_impl`) runs the chunk's rows
+  and every slot's decode row through each layer, the kind's prefill
+  and step side by side, the feed-forward's weights and each touched
+  expert read once for both, so the engine has two programs, the ride
+  and the decode step. State
+  kinds carry the state from chunk to chunk;
   a kind that keeps PAGES (latent attention) has the whole prompt's
   pages reserved at admission, and a chunk writes its rows into them
   and attends to the pages written before it and to itself. Page
@@ -94,7 +99,8 @@ TPU design decisions:
 - **Chunked device-side stepping**: like `DecodeEngine`, ``chunk``
   tokens per dispatch with per-slot eos/budget early-stop; pages for
   the whole chunk are reserved up front so the table is static inside
-  the dispatch.
+  the dispatch. A kind that prefills in chunks takes one token a
+  dispatch: its prompt chunks ride in a step of one.
 - **Pipelined dispatch** (``PT_SERVE_INFLIGHT``, default 2): dispatch
   and harvest halves exactly as in `DecodeEngine` — each harvested
   dispatch costs ONE packed device→host transfer (the old `_step_inner`
@@ -206,6 +212,11 @@ class PagedDecodeEngine(ResilientScheduler):
         self._head, self._stacked = head, stacked
         L = cfg.n_layers
         self.prefill_chunk = int(prefill_chunk)
+        if self.kind.chunked_prefill and self.chunk != 1:
+            raise ValueError(
+                f"{self.kind.name} layers prefill in chunks that ride a "
+                f"decode step of ONE token: steps_per_call must be 1, got "
+                f"{self.chunk}")
         if self.kind.pages and self.kind.chunked_prefill:
             if self.prefill_chunk % self.page:
                 raise ValueError(
@@ -242,8 +253,8 @@ class PagedDecodeEngine(ResilientScheduler):
         self._slot_pools = tuple(slot_pools)
         self.state = dict(slot_pools, **pools)
         # a model with routed experts counts the experts its tokens
-        # touched: a prompt chunk's count waits here, on the device,
-        # for the next decode step's result to carry it back
+        # touched here, on the device; each program's result carries its
+        # count back and sets it to zero again
         self._experts = cfg.routed_experts > 0
         self._touched = [0, 0]      # harvested this step: decode, chunks
         if self._experts:
@@ -318,8 +329,8 @@ class PagedDecodeEngine(ResilientScheduler):
                                        donate_argnums=(2, 3, 4, 5))
         self._multi_fn = jax.jit(self._multi_impl,
                                  donate_argnums=(2, 3, 4))
-        self._chunk_fn = jax.jit(self._prefill_chunk_impl,
-                                 donate_argnums=(2, 3, 4, 5, 6, 7))
+        self._ride_fn = jax.jit(self._ride_impl,
+                                donate_argnums=(2, 3, 4, 5, 6, 7))
         # table (arg 4) is NEVER donated: the cached device copy
         # (_table_dev) is reused across dispatches
         self._verify_fn = jax.jit(self._spec_multi_impl,
@@ -499,26 +510,48 @@ class PagedDecodeEngine(ResilientScheduler):
         return carry
 
     def _tail(self, head, blk, i, h, attn, pools):
-        """Layer ``i``'s second half on the carry ``(h, pools)``; a
-        model with routed experts hands the layer the experts' stacks
-        with its place in them, and adds the experts its tokens touched
-        to the count in ``pools``; where ``pools`` holds ``routing``
-        (the decode step's), the layer's row of it is filled."""
+        """Layer ``i``'s second half on the carry ``(h, pools)``: the
+        one-set case of `_tail_sets`."""
+        (h,), pools = self._tail_sets(head, blk, i, (h,), (attn,), pools)
+        return h, pools
+
+    def _tail_sets(self, head, blk, i, hs, attns, pools):
+        """Layer ``i``'s second half on one or more sets of rows
+        (`GPTBlock._block_tail_sets`: the feed-forward's weights and each
+        touched expert read once for all sets); a model with routed
+        experts hands the layer the experts' stacks with its place in
+        them, and adds the experts its tokens touched to the count in
+        ``pools``; where ``pools`` holds ``routing`` (a decode step's),
+        the layer's row of it is filled with what ROUTING_SLOT's router
+        saw and chose in the FIRST set (`on_routing`)."""
         if not self._experts:
-            return blk._block_tail(h, attn), pools
+            return blk._block_tail_sets(hs, attns)[0], pools
         at = i - self.cfg.leading_dense
-        h, touched, routed = blk._block_tail_touched(
-            h, attn, head["experts"] + (at,))
+        hs, touched, routed = blk._block_tail_sets(
+            hs, attns, head["experts"] + (at,))
         pools = dict(pools, experts_touched=pools["experts_touched"]
                      + touched)
         if routed is not None and "routing" in pools:
-            # the decode step keeps what ROUTING_SLOT's router saw and
-            # chose in this layer (`on_routing`)
             saw, chose = pools["routing"]
             pools["routing"] = (
                 saw.at[at].set(routed[0][ROUTING_SLOT, 0]),
                 chose.at[at].set(routed[1][ROUTING_SLOT]))
-        return h, pools
+        return hs, pools
+
+    def _step_inputs(self, head, table, lengths, last):
+        """A decode step's input rows (S, 1, d), every slot's pending
+        token at its position, and the ``view`` of the page table the
+        kind's ``step`` reads (each slot's write page ``base``)."""
+        x = jnp.take(head["wte"], last, axis=0)
+        if head["wpe"] is not None:
+            x = x + jnp.take(head["wpe"], lengths, axis=0)
+        x = x[:, None, :]
+        pidx = jnp.minimum(lengths // self.page, table.shape[1] - 1)
+        view = {"table": table, "n_pages": self.P,
+                "scratch": self._scratch,
+                "base": jnp.take_along_axis(table, pidx[:, None],
+                                            axis=1)[:, 0]}
+        return x, view
 
     def _one_token(self, head, stacked, kp, vp, state, table, lengths,
                    last, active, poison):
@@ -539,15 +572,7 @@ class PagedDecodeEngine(ResilientScheduler):
         Retention layers call `retention_step`, which updates and
         reads the per-slot state pools the same way (each handed in
         once, aliased in to out) and skips inactive slots."""
-        x = jnp.take(head["wte"], last, axis=0)
-        if head["wpe"] is not None:
-            x = x + jnp.take(head["wpe"], lengths, axis=0)
-        x = x[:, None, :]
-        pidx = jnp.minimum(lengths // self.page, table.shape[1] - 1)
-        view = {"table": table, "n_pages": self.P,
-                "scratch": self._scratch,
-                "base": jnp.take_along_axis(table, pidx[:, None],
-                                            axis=1)[:, 0]}
+        x, view = self._step_inputs(head, table, lengths, last)
 
         def layer_body(carry, blk_i):
             h, pools = carry
@@ -560,13 +585,22 @@ class PagedDecodeEngine(ResilientScheduler):
             layer_body, (x, dict(state, kp=kp, vp=vp)), head, stacked)
         kp, vp = pools.pop("kp"), pools.pop("vp")
         state = pools
-        logits = self._lm_head(head, x)[:, 0]
+        lengths, nxt, bad = self._sample(self._lm_head(head, x)[:, 0],
+                                         poison, active, last, lengths)
+        return kp, vp, state, lengths, nxt, bad
+
+    @staticmethod
+    def _sample(logits, poison, active, last, lengths):
+        """Every slot's greedy token from its logits (S, V): an active
+        slot whose logits are not finite (or ``poison``ed) is ``bad``
+        and neither advances nor takes a token; the others keep
+        ``last`` and their length."""
         logits = jnp.where(poison[:, None], jnp.nan, logits)
         bad = active & ~jnp.all(jnp.isfinite(logits), axis=-1)
         nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(jnp.int32)
         nxt = jnp.where(active & ~bad, nxt, last)
         lengths = lengths + (active & ~bad).astype(jnp.int32)
-        return kp, vp, state, lengths, nxt, bad
+        return lengths, nxt, bad
 
     def _multi_impl(self, head, stacked, kp, vp, state, table, lengths,
                     last, active, remaining, eos, poison):
@@ -957,58 +991,114 @@ class PagedDecodeEngine(ResilientScheduler):
             toks = self._seed_history(toks, slot, prompt_row, true_n, nxt)
         return kp, vp, vecs, toks, nxt
 
-    def _prefill_chunk_impl(self, head, stacked, state, lengths, last,
-                            active, remaining, eos_ids, tokens, pos0,
-                            n_valid, slot, final, rem0, eos0, table_row):
-        """One chunk (1, prefill_chunk) of slot ``slot``'s prompt
-        through layers that prefill in chunks: positions from ``pos0``,
-        the first ``n_valid`` tokens real. Layers that keep a state per
-        sequence start the chunk at position 0 from a zero state (that
-        is how admission zeroes the slot) and every other from the
-        slot's own; layers that keep pages write the chunk into the
-        slot's pages (``table_row``: the slot's row of the page table,
-        None for a kind without pages) and attend to the pages before it
-        and to itself. ONE program for every chunk of every prompt: the
-        scalars are traced. The ``final`` chunk also samples the first
-        token and installs the slot's decode state on device
-        (`_install_slot`), so admission runs no eager program of its
-        own."""
-        _note_retrace("paged_prefill_chunk")
-        x = jnp.take(head["wte"], tokens, axis=0)
+    def _ride_impl(self, head, stacked, state, lengths, last, active,
+                   remaining, eos_ids, table, poison, tokens, pos0,
+                   n_valid, slot, final, rem0, eos0, table_row):
+        """One chunk (1, prefill_chunk) of slot ``slot``'s prompt and one
+        decode token of every active slot, in ONE scan over the layers:
+        each layer runs its kind's ``step`` on the (S, 1) decode rows and
+        its ``prefill`` on the chunk's rows, then its tail on both sets
+        (`_tail_sets`): each set's output projection, residual, norm,
+        routing, combine and shared expert as in its own program, the
+        feed-forward's weights, dense or the routed experts (ONE kernel
+        call over both sets' pairs), read once for ``prefill_chunk + S``
+        rows; the head runs once on the decode rows and the chunk's last
+        real row. (A tail on the two sets concatenated whole served
+        tokens further from the float32 reference on a TPU v5e: XLA made
+        the residual sum of the concatenation at float32 for the norm
+        and rounded it for the residual: PERF.md, section 7.)
+
+        The chunk: positions from ``pos0``, the first ``n_valid`` tokens
+        real. Layers that keep a state per sequence start it at
+        position 0 from a zero state (that is how admission zeroes the
+        slot) and every other from the slot's own; layers that keep
+        pages write the chunk into the slot's pages (``table_row``: the
+        slot's row of the page table, None for a kind without pages)
+        and attend to the pages before it and to itself. The ``final``
+        chunk also samples the first token and installs the slot's
+        decode state (`_install_slot`), so admission runs no eager
+        program of its own. The decode rows are `_multi_impl`'s one
+        step (``table``, ``poison`` as there); a slot that does not
+        decode is an inactive row, so ONE program serves every chunk
+        of every prompt, with or without slots decoding: the scalars
+        are traced. The chunk's slot is never active while it
+        prefills, so the two row sets touch disjoint slots. Returns
+        the state, the five slot vectors and the packed (rows, 1, S)
+        result: the decode rows' as `_multi_impl` packs them, and last
+        the chunk's sampled token."""
+        _note_retrace("paged_ride")
+        xc = jnp.take(head["wte"], tokens, axis=0)
         if head["wpe"] is not None:
             pos = jnp.clip(pos0 + jnp.arange(tokens.shape[1]), 0,
                            head["wpe"].shape[0] - 1)
-            x = x + jnp.take(head["wpe"], pos, axis=0)[None]
-
-        view = (None if table_row is None else
-                {"table_row": table_row, "n_pages": self.P,
-                 "scratch": self._scratch})
+            xc = xc + jnp.take(head["wpe"], pos, axis=0)[None]
+        chunk_view = (None if table_row is None else
+                      {"table_row": table_row, "n_pages": self.P,
+                       "scratch": self._scratch})
+        xd, step_view = self._step_inputs(head, table, lengths, last)
+        touched0 = state.get("experts_touched")
+        if self._experts:
+            cfg = self.cfg
+            layers = cfg.n_layers - cfg.leading_dense
+            state = dict(state, routing=(
+                jnp.zeros((layers, cfg.d_model), cfg.dtype),
+                jnp.zeros((layers, cfg.experts_per_token), jnp.int32)))
 
         def layer_body(carry, blk_i):
-            h, pools = carry
+            (hd, hc), pools = carry
             blk, i = blk_i
-            attn, pools = self.kind.prefill(blk, i, h, pos0, n_valid,
-                                            slot, pos0 == 0, pools, view)
-            return self._tail(head, blk, i, h, attn, pools), None
+            # (the step first: at Brumby's widths the program then holds
+            # 0.14 GB fewer temporaries for the v5e's compiler)
+            attn_d, pools = self.kind.step(blk, i, hd, lengths, active,
+                                           pools, step_view)
+            attn_c, pools = self.kind.prefill(blk, i, hc, pos0, n_valid,
+                                              slot, pos0 == 0, pools,
+                                              chunk_view)
+            # the decode rows first: the routing row filled is theirs
+            (hd, hc), pools = self._tail_sets(head, blk, i, (hd, hc),
+                                              (attn_d, attn_c), pools)
+            return ((hd, hc), pools), None
 
-        x, state = self._over_layers(layer_body, (x, state), head,
-                                     stacked)
+        (xd, xc), state = self._over_layers(layer_body, ((xd, xc), state),
+                                            head, stacked)
         idx = jnp.clip(n_valid - 1, 0, tokens.shape[1] - 1)
-        logits = self._lm_head(head, lax.dynamic_slice_in_dim(
-            x, idx, 1, axis=1))[:, 0]
-        nxt = jnp.argmax(logits.astype(jnp.float32), -1).astype(
-            jnp.int32)[0]
+        logits = self._lm_head(head, jnp.concatenate(
+            [xd, lax.dynamic_slice_in_dim(xc, idx, 1, axis=1)]))[:, 0]
+        nxt = jnp.argmax(logits[-1].astype(jnp.float32), -1).astype(
+            jnp.int32)
+        # the decode rows' step, as `_multi_impl` takes it
+        lengths, tok, bad = self._sample(logits[:-1], poison, active, last,
+                                         lengths)
+        emit = active & ~bad
+        remaining = remaining - emit.astype(jnp.int32)
+        hit_eos = (tok == eos_ids) & (eos_ids >= 0)
+        active = active & ~bad & ~hit_eos & (remaining > 0)
+        out = [tok[None], emit[None].astype(jnp.int32),
+               bad[None].astype(jnp.int32)]
+        if self._experts:
+            # as `_multi_impl`'s: the routing rows, a step of them, leave
+            # with the state; the experts touched ride back with the
+            # tokens and the count starts from zero again
+            state = dict(state, routing=jax.tree_util.tree_map(
+                lambda a: a[None], state["routing"]))
+            out += [jnp.full_like(out[0], state["experts_touched"]
+                                  - touched0),
+                    jnp.full_like(out[0], touched0)]
+            state = dict(state, experts_touched=jnp.int32(0))
+        # the chunk's sampled token rides back last (the first token of
+        # a prompt's last chunk)
+        out.append(jnp.full_like(out[0], nxt))
         vecs = self._install_slot(
-            (lengths, last, active, remaining, eos_ids), slot,
+            (lengths, tok, active, remaining, eos_ids), slot,
             pos0 + n_valid, nxt, rem0, eos0, final)
-        if view is not None:
+        if chunk_view is not None:
             # until its last chunk the slot is not active, and the
             # decode step walks an inactive slot's pages up to its
             # length all the same: none, and not what the slot's last
             # sequence left
             vecs = (vecs[0].at[slot].set(
                 jnp.where(final, pos0 + n_valid, 0)),) + vecs[1:]
-        return (state, *vecs, nxt)
+        return (state, *vecs, jnp.stack(out))
 
     # -- scheduler ----------------------------------------------------------
 
@@ -1350,18 +1440,16 @@ class PagedDecodeEngine(ResilientScheduler):
         self._disp_rem[slot] = 0
         self._prefilling[slot] = [prompt, 0]
 
-    def _prefill_one_chunk(self):
-        """Dispatch the next chunk of the oldest prompt still in
-        prefill (at most one chunk between two decode steps, so that a
-        long prompt does not stall the slots that decode). The final
-        chunk's sampled token rides the harvest queue as a 'prefill'
-        record, like a one-pass prefill's."""
-        import time
-        from paddle_tpu import stats
+    def _dispatch_ride(self, live):
+        """Dispatch the next chunk of the oldest prompt still in prefill
+        (at most one chunk a step, so that a long prompt does not stall
+        the slots that decode) with the decode token of every slot in
+        ``live`` riding in the same program (`_ride_impl`). Its result
+        is ONE 'ride' record, replayed as a decode step's, which for a
+        prompt's last chunk also brings back the first token (``first``):
+        a record of its own would make the harvest wait for the very
+        program just dispatched, and the device for the host."""
         from paddle_tpu.observability import trace
-        self._step_prefill_tokens = 0
-        if not self._prefilling:
-            return
         slot, (prompt, done) = next(iter(self._prefilling.items()))
         req = self._slot_req[slot]
         C, n = self.prefill_chunk, len(prompt)
@@ -1371,26 +1459,31 @@ class PagedDecodeEngine(ResilientScheduler):
         tokens[0, :take] = prompt[done:done + take]
         rem0 = req.max_new_tokens - 1
         eos0 = -1 if req.eos_id is None else int(req.eos_id)
-        stats.add("serve/dispatch_launches")
-        stats.add("serve/dispatches/prefill_chunk")
+        self.steps += 1
+        self._obs_host_gap()
         with trace.span("serve/prefill_chunk", slot=slot, rid=req.rid,
-                        tokens=take, index=done // C, last=final):
+                        tokens=take, index=done // C, last=final,
+                        rode=len(live)):
             (self.state, self.lengths, self.last, self.active,
-             self.remaining, self.eos_ids, nxt) = self._chunk_fn(
+             self.remaining, self.eos_ids, packed) = self._ride_fn(
                 self._head, self._stacked, self.state, self.lengths,
                 self.last, self.active, self.remaining, self.eos_ids,
-                jnp.asarray(tokens), jnp.int32(done), jnp.int32(take),
-                jnp.int32(slot), jnp.bool_(final), jnp.int32(rem0),
-                jnp.int32(eos0), self._table_row(slot))
+                self._table(), self._poison_mask(), jnp.asarray(tokens),
+                jnp.int32(done), jnp.int32(take), jnp.int32(slot),
+                jnp.bool_(final), jnp.int32(rem0), jnp.int32(eos0),
+                self._table_row(slot))
         self._step_prefill_tokens = take
-        if not final:
+        for s, _ in live:
+            self._proj_len[s] += self._disp_span
+        self._finish_dispatch("ride", live, packed)
+        self._pending[-1].routing = self.state.pop("routing", None)
+        if final:
+            del self._prefilling[slot]
+            self._host_len[slot] = self._proj_len[slot] = n
+            self._disp_rem[slot] = 0 if self.prefill_only else rem0
+            self._pending[-1].first = (slot, req)
+        else:
             self._prefilling[slot][1] = done + take
-            return
-        del self._prefilling[slot]
-        self._host_len[slot] = self._proj_len[slot] = n
-        self._disp_rem[slot] = 0 if self.prefill_only else rem0
-        self._pending.append(_Inflight("prefill", [(slot, req)], nxt,
-                                       time.perf_counter()))
 
     def _admit_reserved(self, req, slot, prompt, bucket, sp, cow_src,
                         chain):
@@ -1830,7 +1923,6 @@ class PagedDecodeEngine(ResilientScheduler):
         the obs hooks."""
         self._evict_expired()
         self._admit_waiting()
-        self._prefill_one_chunk()
         self._pump(self._dispatch_decode())
         live = sum(r is not None for r in self._slot_req)
         sp.attrs["active"] = live
@@ -1892,6 +1984,9 @@ class PagedDecodeEngine(ResilientScheduler):
             self._reserve(slot, need)
 
     def _dispatch_decode(self) -> bool:
+        """This step's one program: where a prompt chunk is due, the
+        ride (`_dispatch_ride`) with the live slots' token in it; else
+        the decode step of the live slots, if any."""
         from paddle_tpu.observability import trace
 
         def _live():
@@ -1899,11 +1994,8 @@ class PagedDecodeEngine(ResilientScheduler):
                     if r is not None and self._disp_rem[s] > 0]
 
         live = _live()
-        self._step_decode_tokens = len(live) * self.chunk
-        if not live:
-            return False
         try:
-            if self.kind.pages:
+            if self.kind.pages and live:
                 self._reserve_chunk(live)
         except MemoryError:
             # pool pressure: retired pages may sit in unharvested
@@ -1912,9 +2004,15 @@ class PagedDecodeEngine(ResilientScheduler):
                 raise
             self._drain()
             live = _live()
-            if not live:
-                return False
-            self._reserve_chunk(live)
+            if live:
+                self._reserve_chunk(live)
+        self._step_decode_tokens = len(live) * self.chunk
+        self._step_prefill_tokens = 0
+        if self._prefilling:
+            self._dispatch_ride(live)
+            return True
+        if not live:
+            return False
         self.steps += 1
         self._obs_host_gap()
         if self.spec_k:
@@ -1957,7 +2055,14 @@ class PagedDecodeEngine(ResilientScheduler):
                                     * cover.get(slot, 0))
 
     def _replay(self, rec, arr) -> int:
-        if self._experts and rec.kind == "decode":
+        if rec.first is not None:
+            # a prompt's last chunk rode in this program: its first
+            # token, as a one-pass prefill's record gives it
+            slot, req = rec.first
+            if not req.done and self._slot_req[slot] is req:
+                self._emit(slot, req, int(arr[-1, 0, 0]))
+            self._resync_budgets([rec.first])
+        if self._experts and rec.kind != "prefill":
             # the expert counts that rode back with the tokens
             self._touched[0] += int(arr[3, 0, 0])
             self._touched[1] += int(arr[4, 0, 0])
@@ -2004,12 +2109,15 @@ class PagedDecodeEngine(ResilientScheduler):
                 copy(self.remaining), copy(self.eos_ids))
         toks = None if self.toks is None else jnp.zeros_like(self.toks)
         if self.kind.chunked_prefill:
-            state, *vecs, _ = self._chunk_fn(
-                self._head, self._stacked, state, *vecs,
+            # the ride: a chunk of slot 0 with no slot decoding
+            state, *vecs, _ = self._ride_fn(
+                self._head, self._stacked, state, *vecs, self._table(),
+                self._poison_mask(),
                 jnp.zeros((1, self.prefill_chunk), jnp.int32),
                 jnp.int32(0), jnp.int32(1), jnp.int32(0),
                 jnp.bool_(False), jnp.int32(0), jnp.int32(-1),
                 self._table_row(0))
+            state.pop("routing", None)
             vecs = tuple(vecs)
         # the arguments in the admission's own form (numpy, uploaded by
         # the dispatch), so that the first request finds the very

@@ -71,15 +71,29 @@ class ExpertLayer(Module):
         several layers stacked and which of them this is, in place of
         the layer's own three (a scan over layers hands the kernel the
         stacks as they are: `ops/pallas/moe_experts.py`)."""
+        (out,), touched, (experts,) = self.forward_sets((x,), stacks)
+        return out, touched, experts
+
+    def forward_sets(self, xs, stacks=None):
+        """`forward` of several sets of tokens in one call: each set is
+        routed, combined and given the shared expert as it would be
+        alone, and the routed experts run as ONE kernel call over the
+        token-expert pairs of every set, so that each expert some token
+        chose is read once for all of them. Returns the sets' sums, how
+        many experts some token of any set chose, and each set's
+        experts."""
         w_gate, w_up, w_down, layer = (
             (self.w_gate, self.w_up, self.w_down, None) if stacks is None
             else stacks)
-        shape = x.shape
-        x = x.reshape(-1, shape[-1])
-        t, k = x.shape[0], self.per_token
+        flat = [x.reshape(-1, x.shape[-1]) for x in xs]
+        k = self.per_token
         with jax.named_scope("moe_route"):
-            experts, weights = route(x, self.w_router, self.router_bias, k,
-                                     self.scale)
+            routes = [route(x, self.w_router, self.router_bias, k,
+                            self.scale) for x in flat]
+            x = flat[0] if len(flat) == 1 else jnp.concatenate(flat)
+            experts = (routes[0][0] if len(flat) == 1 else
+                       jnp.concatenate([e for e, _ in routes]))
+            t = x.shape[0]
             # few rows a tile while every expert sees a handful of
             # tokens (the product is bound by the weights' bytes), more
             # once runs grow
@@ -94,13 +108,20 @@ class ExpertLayer(Module):
         with jax.named_scope("moe_experts"):
             y_rows = kernel.moe_experts(x_rows, w_gate, w_up, w_down,
                                         tile_expert, used, tile, layer)
-        with jax.named_scope("moe_combine"):
-            y = jnp.take(y_rows, dest, axis=0)
-            out = jnp.sum((y.astype(jnp.float32)
-                           * weights.reshape(-1)[:, None]).reshape(
-                t, k, -1), axis=1)
-        with jax.named_scope("moe_shared"):
-            h = jax.nn.silu(x @ self.ws_gate) * (x @ self.ws_up)
-            out = out + (h @ self.ws_down).astype(jnp.float32)
+        outs, at = [], 0
+        for x, (_, weights) in zip(flat, routes):
+            n = x.shape[0]
+            with jax.named_scope("moe_combine"):
+                y = jnp.take(y_rows, dest if len(flat) == 1
+                             else dest[at * k:(at + n) * k], axis=0)
+                out = jnp.sum((y.astype(jnp.float32)
+                               * weights.reshape(-1)[:, None]).reshape(
+                    n, k, -1), axis=1)
+            with jax.named_scope("moe_shared"):
+                h = jax.nn.silu(x @ self.ws_gate) * (x @ self.ws_up)
+                outs.append(out + (h @ self.ws_down).astype(jnp.float32))
+            at += n
         touched = jnp.sum(counts > 0).astype(jnp.int32)
-        return out.astype(x.dtype).reshape(shape), touched, experts
+        return ([out.astype(x.dtype).reshape(x.shape)
+                 for out, x in zip(outs, xs)], touched,
+                [e for e, _ in routes])

@@ -508,28 +508,52 @@ class GPTBlock(Module):
     def _block_tail(self, x, attn):
         """Post-attention half of the block (out-proj + MLP), shared by
         every cached-decode variant — ONE definition."""
-        return self._block_tail_touched(x, attn)[0]
+        return self._block_tail_sets((x,), (attn,))[0][0]
 
-    def _block_tail_touched(self, x, attn, expert_stacks=None):
-        """`_block_tail`; how many of the layer's routed experts some
-        token of ``x`` chose (0 for a layer without them); and, for a
-        layer with them, what its router saw and chose: ``(the normed
-        tokens, x's shape; experts (tokens, k))``, else None.
-        ``expert_stacks``: as `ExpertLayer.forward` takes them."""
-        o = attn @ self.wo
-        if self.bo is not None:
-            o = o + self.bo
-        x = x + o
-        n = self._ln(x, self.ln2_scale, self.ln2_bias)
+    def _block_tail_sets(self, xs, attns, expert_stacks=None):
+        """`_block_tail` of one or more sets of rows in one call: each
+        set's output projection, residual and norm as it would be alone,
+        and the feed-forward's weights read ONCE for all sets (the dense
+        matrices on the sets' rows together; routed experts through
+        `ExpertLayer.forward_sets`: each set routed and combined alone,
+        one kernel call over every set's pairs; capacity experts set by
+        set). Returns the sets' outputs; how many of the layer's routed
+        experts some token of any set chose (0 for a layer without
+        them); and, for a layer with them, what its router saw and chose
+        for the FIRST set: ``(the normed tokens, the set's shape;
+        experts (tokens, k))``, else None. ``expert_stacks``: as
+        `ExpertLayer.forward` takes them. Of several sets the normed
+        tokens are made once (`lax.optimization_barrier`), so that the
+        router and what is reported of it read the very same array: XLA
+        may otherwise make the two copies at different precisions."""
+        res, ns = [], []
+        for x, attn in zip(xs, attns):
+            o = attn @ self.wo
+            if self.bo is not None:
+                o = o + self.bo
+            res.append(x + o)
+            ns.append(self._ln(res[-1], self.ln2_scale, self.ln2_bias))
+        if len(ns) > 1:
+            ns = lax.optimization_barrier(ns)
         touched, routed = jnp.int32(0), None
         if self.experts is not None:
-            h, touched, experts = self.experts(n, expert_stacks)
-            routed = (n, experts)
+            hs, touched, experts = self.experts.forward_sets(ns,
+                                                             expert_stacks)
+            routed = (ns[0], experts[0])
         elif self.moe is not None:
-            h, _ = self.moe(n, None)
+            # capacity experts drop by the tokens a call holds: each set
+            # as alone
+            hs = [self.moe(n, None)[0] for n in ns]
+        elif len(ns) == 1:
+            hs = [self._ffn(ns[0])]
         else:
-            h = self._ffn(n)
-        return x + h, touched, routed
+            d = ns[0].shape[-1]
+            h = self._ffn(jnp.concatenate([n.reshape(-1, d) for n in ns]))
+            hs, at = [], 0
+            for n in ns:
+                hs.append(h[at:at + n.size // d].reshape(n.shape))
+                at += n.size // d
+        return [x + h for x, h in zip(res, hs)], touched, routed
 
     def _mix_inputs(self, x, positions):
         """Norm 1 + fused QKV (+ per-head q/k norm, + rope at

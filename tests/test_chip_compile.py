@@ -421,3 +421,72 @@ def test_moe_experts_reads_the_stacks_in_place(one_chip, tokens, tile):
               if re.search(r"= \S+ copy\(", ln)
               and re.search(rf"= bf16\[(?:{E_LAYERS},)?{E_EXPERTS},", ln)]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("config,engine", [
+    ("brumby-14b", dict(n_pages=0, max_slots=16)),
+    ("joyai-llm-flash", dict(n_pages=2304, max_slots=32))],
+    ids=["retention", "latent-moe"])
+def test_ride_keeps_the_pools_in_place(one_chip, monkeypatch, config,
+                                       engine):
+    """The ride (`PagedDecodeEngine._ride_impl`: a 512-token prompt chunk
+    and the decode rows of every slot in ONE program) at the benchmark
+    cells' sizes, with the chip's kernels: it compiles beside the weights
+    and the pools, every pool is aliased in to out, and no ``copy`` of a
+    whole pool is made (Brumby's 4.4 GB state would not fit twice). The
+    engine is made of shapes alone."""
+    import json
+    from paddle_tpu.inference.paged_engine import PagedDecodeEngine
+    from paddle_tpu.models import gpt
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           f"{config}.json")) as f:
+        model = json.load(f)["model"]
+    if config == "brumby-14b":
+        from benchmark.program_retention import model_config
+    else:
+        from benchmark.program_latent_moe import model_config
+    # the kernels choose interpret mode by the default backend at trace
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    made = []
+
+    def build():
+        eng = PagedDecodeEngine(gpt.GPT(model_config(model)),
+                                prefill_chunk=512, **engine)
+        made.append(eng)
+        return (eng._head, eng._stacked, eng.state, eng.lengths, eng.last,
+                eng.active, eng.remaining, eng.eos_ids, eng._table_array(),
+                eng.active)
+
+    state = jax.eval_shape(build)
+    eng, scalar = made[0], _sds((), jnp.int32)
+    row = (_sds((state[8].shape[1],), jnp.int32) if eng.kind.pages
+           else None)
+    text = _compiled_text(
+        eng._ride_impl,
+        (*state, _sds((1, 512), jnp.int32), scalar, scalar, scalar,
+         _sds((), jnp.bool_), scalar, scalar, row),
+        one_chip, donate=(2, 3, 4, 5, 6, 7))
+    kernels = (("retention_step",) if config == "brumby-14b"
+               else ("latent_attend", "moe_experts"))
+    assert all(k in text for k in kernels)
+    pools = [f"{'f32' if a.dtype == jnp.float32 else 'bf16'}["
+             + ",".join(map(str, a.shape)) + "]"
+             for a in jax.tree_util.tree_leaves(state[2]) if a.ndim > 2]
+    header = text.splitlines()[0]
+    aliased = re.findall(r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                         header)
+    assert len(aliased) >= len(pools) + 5
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(r"= \S+ copy\(", ln)
+              and any(f"= {p}" in ln for p in pools)]
+    assert not copies, copies
+    if config == "joyai-llm-flash":
+        # what the ride reports of ROUTING_SLOT's routing is a row of the
+        # array the router read, not the norm made again from the
+        # residual (at another precision than the router's copy)
+        saw = [b for b in re.findall(r"\n(%fused_computation[^\n]*\n.*?\n})",
+                                     text, flags=re.S)
+               if f"bf16[{E_LAYERS},{DM}]" in b.split("\n")[0]
+               and b.split("\n")[0].endswith(f"-> bf16[1,{DM}] {{")]
+        assert saw and not any("multiply(" in b for b in saw), saw

@@ -96,14 +96,92 @@ def test_two_slots_interleave_chunks_and_steps(model, weights):
 
 
 def test_engine_is_one_chunk_program_and_one_decode_program(model):
+    """The ride (a prompt chunk with the decoding slots' token in it)
+    is the engine's ONE chunk program: the benchmark kinds' warm-up, one
+    request of a chunk and a few tokens, traces it and the decode
+    program, and a run that mixes long prompts with decoding slots
+    traces nothing more."""
     from paddle_tpu import stats
-    before = {k: stats.get(f"compile/retrace/{k}") or 0
-              for k in ("paged_prefill_chunk", "paged_multi")}
+    names = ("paged_ride", "paged_multi")
+    count = lambda: {k: stats.get(f"compile/retrace/{k}") or 0
+                     for k in names + ("",)}
+    before = count()
+    eng = inference.make_engine(model, max_slots=2, n_pages=16,
+                                prefill_chunk=CHUNK)
+    fe = serving.FrontEnd(eng)
     rng = np.random.default_rng(3)
-    _serve(model, [rng.integers(0, 512, n).tolist() for n in (60, 400)], 5)
-    after = {k: stats.get(f"compile/retrace/{k}") or 0 for k in before}
-    assert {k: after[k] - before[k] for k in before} == {
-        "paged_prefill_chunk": 1, "paged_multi": 1}
+    fe.submit(rng.integers(0, 512, CHUNK + 7).tolist(), max_new_tokens=3)
+    fe.run()
+    warm = count()
+    assert {k: warm[k] - before[k] for k in names} == {
+        "paged_ride": 1, "paged_multi": 1}
+    reqs = [fe.submit(rng.integers(0, 512, n).tolist(), max_new_tokens=5)
+            for n in (60, 400, 200)]
+    fe.run()
+    assert all(len(r.tokens) == 5 for r in reqs)
+    assert count() == warm
+
+
+def test_decode_step_lowers_to_the_pinned_program(model):
+    """The decode step's program (`PagedDecodeEngine._multi_impl`, what
+    `dispatch_fn_args` gives) lowers to the text pinned here: the digest
+    is of the program before prompt chunks rode in decode steps, and the
+    ride was to leave every decode-only step as it was. A change that
+    alters the decode program on purpose pins its new digest."""
+    import hashlib
+    eng = inference.make_engine(model, max_slots=2, n_pages=16,
+                                prefill_chunk=CHUNK)
+    fn, args = eng.dispatch_fn_args()
+    text = fn.lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "ad0e103f4ea6171f"
+
+
+# (prompts, tokens asked, each request's chunks as (index, last, decoding
+# rows that rode in them)); the chunks of 128 are dispatched one a step,
+# the oldest prompt's first: a prompt alone rides with no decoding slot;
+# a prompt of three chunks rides beside a decoding slot (non-final and
+# final chunks); a neighbour retires in a step whose chunk it rides in
+# (its three tokens: one from its own chunk, two from the first two
+# rides), and a third request takes its slot while the long prompt
+# prefills, its chunks after the long prompt's
+RIDES = {
+    "alone": ((300,), (6,), [[(0, False, 0), (1, False, 0),
+                              (2, True, 0)]]),
+    "beside_decoders": ((20, 300), (12, 6), [
+        [(0, True, 0)], [(0, False, 1), (1, False, 1), (2, True, 1)]]),
+    "neighbour_retires": ((20, 400, 150), (3, 5, 4), [
+        [(0, True, 0)],
+        [(0, False, 1), (1, False, 1), (2, False, 0), (3, True, 0)],
+        [(0, False, 1), (1, True, 1)]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RIDES))
+def test_a_chunk_rides_with_the_decoding_slots(model, weights, case):
+    from paddle_tpu.observability import trace
+    lengths, asked, rides = RIDES[case]
+    rng = np.random.default_rng(len(lengths) * 1000 + sum(lengths))
+    prompts = [rng.integers(0, TINY["vocab_size"], n).tolist()
+               for n in lengths]
+    eng = inference.make_engine(model, max_slots=2, n_pages=16,
+                                prefill_chunk=CHUNK)
+    fe = serving.FrontEnd(eng)
+    trace.enable()
+    try:
+        reqs = [fe.submit(p, max_new_tokens=k)
+                for p, k in zip(prompts, asked)]
+        fe.run()
+        chunks = [a for name, *_, a in trace.events()[0]
+                  if name == "serve/prefill_chunk"]
+    finally:
+        trace.disable()
+        trace.clear()
+    for prompt, k, req in zip(prompts, asked, reqs):
+        assert len(req.tokens) == k
+        assert _gap(weights, prompt, list(req.tokens)) <= 1e-4
+    assert eng.free_pages == eng.P
+    assert [[(c["index"], c["last"], c["rode"]) for c in chunks
+             if c["rid"] == req.id] for req in reqs] == rides
 
 
 def test_refusals_name_what_is_missing(model):
@@ -113,6 +191,9 @@ def test_refusals_name_what_is_missing(model):
     with pytest.raises(NotImplementedError, match="plain decode step"):
         inference.make_engine(model, max_slots=2, n_pages=8,
                               prefill_chunk=CHUNK, speculative_k=3)
+    with pytest.raises(ValueError, match="steps_per_call must be 1"):
+        inference.make_engine(model, max_slots=2, n_pages=8,
+                              prefill_chunk=CHUNK, steps_per_call=4)
     eng = inference.make_engine(model, max_slots=2, n_pages=8,
                                 prefill_chunk=CHUNK)
     assert eng._prefix is None
@@ -386,10 +467,22 @@ def test_config_counts_its_parameters(model):
         type(cfg)(mixer="latent")
 
 
-def test_step_span_counts_rows_pairs_and_experts(model):
+def _steps_and_chunks(events):
+    """``serve/step`` attributes in order, each with the attributes of
+    the ``serve/prefill_chunk`` dispatched inside it (None if none)."""
+    chunk_of = {e[5]: e[6] for e in events if e[0] == "serve/prefill_chunk"}
+    return [(e[6], chunk_of.get(e[4])) for e in events
+            if e[0] == "serve/step"]
+
+
+def test_step_span_counts_rows_pairs_and_experts(model, weights):
     """`serve/step`'s attributes for this kind: live latent rows,
     token-expert pairs dispatched, and the distinct experts the harvested
-    dispatches touched, each step's own (not a running total)."""
+    dispatches touched, each step's own (not a running total). A step
+    with a chunk dispatches ONE program: its ``prefill_tokens`` are the
+    chunk's ``tokens``, its ``decode_tokens`` the decoding rows that
+    ``rode`` in it, and that program's rows, chunk and decode alike,
+    count each expert they chose once a layer."""
     from paddle_tpu.observability import trace
     rng = np.random.default_rng(9)
     eng = inference.make_engine(model, max_slots=2, n_pages=16,
@@ -401,10 +494,11 @@ def test_step_span_counts_rows_pairs_and_experts(model):
     trace.enable()
     try:
         fe.run()
-        steps = [a for name, *_, a in trace.events()[0]
-                 if name == "serve/step"]
+        events = trace.events()[0]
     finally:
         trace.disable()
+        trace.clear()
+    steps = [a for name, *_, a in events if name == "serve/step"]
     sparse = TINY["n_layers"] - TINY["leading_dense"]
     k, e = TINY["experts_per_token"], TINY["n_experts"]
     assert steps and all("latent_rows" in a for a in steps)
@@ -420,3 +514,34 @@ def test_step_span_counts_rows_pairs_and_experts(model):
     # one decoding slot chooses k distinct experts a layer
     assert min(a["experts_touched"] for a in decoded) >= sparse * k
     assert max(a["latent_rows"] for a in steps) >= 150
+    rides = [(a, c) for a, c in _steps_and_chunks(events) if c]
+    assert any(c["rode"] for _, c in rides)
+    for a, c in rides:
+        assert (a["prefill_tokens"], a["decode_tokens"]) == (
+            c["tokens"], c["rode"])
+
+    # every token of every layer to the same k experts: a program's rows
+    # touch k experts a layer, however many rows ride in it; at depth 1
+    # each step harvests the program it dispatched
+    planted = jnp.zeros((sparse, e)).at[:, jnp.asarray([1, 4, 9, 30])].set(
+        10.0)
+    forced = build_model(TINY, dict(weights, layers=dict(
+        weights["layers"], **{"experts.router_bias": planted})))
+    eng = inference.make_engine(forced, max_slots=2, n_pages=16,
+                                prefill_chunk=CHUNK, inflight=1)
+    fe = serving.FrontEnd(eng)
+    trace.enable()
+    try:
+        for n in (20, 300):
+            fe.submit(rng.integers(0, 512, n).tolist(), max_new_tokens=6)
+        fe.run()
+        events = trace.events()[0]
+    finally:
+        trace.disable()
+        trace.clear()
+    pairs = _steps_and_chunks(events)
+    assert sum(1 for _, c in pairs if c and c["rode"]) == 3
+    for a, c in pairs:
+        ran = a["prefill_tokens"] + a["decode_tokens"] > 0
+        assert a["experts_touched"] == (sparse * k if ran else 0), (a, c)
+        assert a["experts_touched_prefill"] == 0

@@ -105,6 +105,20 @@ def test_paged_spec_stops_at_eos_like_generate():
     assert req.tokens == ref
 
 
+def test_decode_step_lowers_to_the_pinned_program():
+    """The decode step's program (`PagedDecodeEngine._multi_impl`, what
+    `dispatch_fn_args` gives) lowers to the text pinned here: the digest
+    is of the program before prompt chunks rode in decode steps, and the
+    ride was to leave every decode-only step as it was. A change that
+    alters the decode program on purpose pins its new digest."""
+    import hashlib
+    eng = PagedDecodeEngine(_model(), n_pages=8, max_slots=2,
+                            steps_per_call=2)
+    fn, args = eng.dispatch_fn_args()
+    text = fn.lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "b9102bd2604170d7"
+
+
 def test_paged_pages_allocated_on_demand_and_reused():
     model = _model()
     rs = np.random.RandomState(1)
@@ -278,7 +292,7 @@ def _count_dispatches(eng):
     """Wraps every jitted program of ``eng``; returns the Counter of
     calls by attribute name."""
     calls = collections.Counter()
-    for name in ("_prefill_fn", "_prefill_sfx_fn", "_chunk_fn",
+    for name in ("_prefill_fn", "_prefill_sfx_fn", "_ride_fn",
                  "_multi_fn", "_verify_fn"):
         def counted(*a, _fn=getattr(eng, name), _name=name):
             calls[_name] += 1

@@ -224,6 +224,8 @@ def test_make_engine_gives_the_default_engine_with_no_pages(model):
         eng.check_request(250, 8)
     with pytest.raises(NotImplementedError, match="retention"):
         PagedDecodeEngine(model, n_pages=0, max_slots=2, speculative_k=2)
+    with pytest.raises(ValueError, match="steps_per_call must be 1"):
+        PagedDecodeEngine(model, n_pages=0, max_slots=2, steps_per_call=2)
     for removed in ({"mega": True}, {"fused": False}):
         with pytest.raises(TypeError):
             PagedDecodeEngine(model, n_pages=0, max_slots=2, **removed)
@@ -253,6 +255,79 @@ def test_chunked_prefill_then_decode_is_the_reference(model, weights,
     assert req.done and not req.failed and len(req.tokens) == 8
     gap, greedy = _reference_gap(weights, prompt, list(req.tokens))
     assert greedy and gap == 0.0
+
+
+def test_engine_is_one_ride_program_and_one_decode_program(model):
+    """The benchmark kinds' warm-up (one request of a chunk and a few
+    tokens) traces the ride and the decode program; a run that mixes
+    long prompts with decoding slots traces nothing more."""
+    from paddle_tpu import stats
+    names = ("paged_ride", "paged_multi")
+    count = lambda: {k: stats.get(f"compile/retrace/{k}") or 0
+                     for k in names + ("",)}
+    before = count()
+    eng = _engine(model, slots=2)
+    fe = serving.FrontEnd(eng)
+    fe.submit(_prompt(CHUNK + 7, 31), max_new_tokens=3)
+    fe.run()
+    warm = count()
+    assert {k: warm[k] - before[k] for k in names} == {
+        "paged_ride": 1, "paged_multi": 1}
+    reqs = [fe.submit(_prompt(n, 32 + n), max_new_tokens=6)
+            for n in (5, 4 * CHUNK + 1, 2 * CHUNK)]
+    fe.run()
+    assert all(len(r.tokens) == 6 for r in reqs)
+    assert count() == warm
+
+
+def test_decode_step_lowers_to_the_pinned_program(model):
+    """The decode step's program (`PagedDecodeEngine._multi_impl`, what
+    `dispatch_fn_args` gives) lowers to the text pinned here: the digest
+    is of the program before prompt chunks rode in decode steps, and the
+    ride was to leave every decode-only step as it was. A change that
+    alters the decode program on purpose pins its new digest."""
+    import hashlib
+    fn, args = _engine(model).dispatch_fn_args()
+    text = fn.lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == "e8179900f5085330"
+
+
+# as tests/test_latent_moe.py's: (prompts, tokens asked, each request's
+# chunks as (index, last, decoding rows that rode in them))
+RIDES = {
+    "alone": ((3 * CHUNK - 5,), (6,), [[(0, False, 0), (1, False, 0),
+                                        (2, True, 0)]]),
+    "beside_decoders": ((5, 3 * CHUNK - 5), (12, 6), [
+        [(0, True, 0)], [(0, False, 1), (1, False, 1), (2, True, 1)]]),
+    "neighbour_retires": ((5, 4 * CHUNK - 3, CHUNK + 4), (3, 5, 4), [
+        [(0, True, 0)],
+        [(0, False, 1), (1, False, 1), (2, False, 0), (3, True, 0)],
+        [(0, False, 1), (1, True, 1)]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RIDES))
+def test_a_chunk_rides_with_the_decoding_slots(model, weights, case):
+    lengths, asked, rides = RIDES[case]
+    prompts = [_prompt(n, 40 + i) for i, n in enumerate(lengths)]
+    eng = _engine(model, slots=2)
+    fe = serving.FrontEnd(eng)
+    trace.enable()
+    try:
+        reqs = [fe.submit(p, max_new_tokens=k)
+                for p, k in zip(prompts, asked)]
+        fe.run()
+        chunks = [e[6] for e in trace.events()[0]
+                  if e[0] == "serve/prefill_chunk"]
+    finally:
+        trace.disable()
+        trace.clear()
+    for prompt, k, req in zip(prompts, asked, reqs):
+        assert len(req.tokens) == k
+        gap, greedy = _reference_gap(weights, prompt, list(req.tokens))
+        assert greedy and gap == 0.0
+    assert [[(c["index"], c["last"], c["rode"]) for c in chunks
+             if c["rid"] == req.id] for req in reqs] == rides
 
 
 def test_neighbouring_slots_do_not_touch_each_other(model):
@@ -359,9 +434,22 @@ def test_spans_of_the_chunked_prefill(model):
         trace.disable()
         trace.clear()
     chunks = [e[6] for e in events if e[0] == "serve/prefill_chunk"]
-    assert [(c["tokens"], c["index"], c["last"]) for c in chunks
-            if c["slot"] == 1] == [(CHUNK, 0, False), (CHUNK, 1, False),
-                                   (3, 2, True)]
+    # the short prompt's one chunk with nothing decoding, then the long
+    # one's three, each with the short prompt's token riding in it
+    assert [(c["slot"], c["tokens"], c["index"], c["last"], c["rode"])
+            for c in chunks] == [(0, 4, 0, True, 0),
+                                 (1, CHUNK, 0, False, 1),
+                                 (1, CHUNK, 1, False, 1),
+                                 (1, 3, 2, True, 1)]
+    # a step with a chunk dispatches that one program: what it says it
+    # enqueued is the chunk's tokens and the rows that rode in it
+    chunk_of = {e[5]: e[6] for e in events if e[0] == "serve/prefill_chunk"}
+    with_chunk = [(e[6], chunk_of[e[4]]) for e in events
+                  if e[0] == "serve/step" and e[4] in chunk_of]
+    assert len(with_chunk) == 4
+    for step, chunk in with_chunk:
+        assert (step["prefill_tokens"], step["decode_tokens"]) == (
+            chunk["tokens"], chunk["rode"])
     # an admission of a chunk-prefilled kind only binds the slot: its
     # programs are the chunks, dispatched later
     admits = [e[6] for e in events if e[0] == "serve/admit"]
